@@ -1,18 +1,18 @@
 #!/usr/bin/env python
 """End-to-end warm timing of bench queries through the real engine."""
+import os
+import shutil
 import sys
+import tempfile
 import time
 
-import numpy as np
-
-import sys, os
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from bench import make_tables, write_parquet_input, queries
-import tempfile, shutil, os
+from bench import make_tables, queries, require_tpu, write_parquet_input
 
 
 def main():
     which = sys.argv[1:] or ["agg"]
+    require_tpu()
     fact, dim = make_tables(1_000_000)
     root = tempfile.mkdtemp(prefix="probe_e2e_")
     try:

@@ -188,8 +188,11 @@ DEVICE_KERNELS: Dict[str, Dict[str, str]] = {
     "ops/carry.py": {
         "sort_rows": "multi-operand stable carry sort (lax.sort); host "
                      "path uses np.argsort + gather instead",
-        "_sort_rows_lean": "compile-lean variant of sort_rows sharing "
-                           "one lax.sort across key widths",
+        "lean_argsort": "compile-lean radix argsort: every pass is one "
+                        "2-operand (uint32, int32) lax.sort",
+        "stable_argsort": "the device argsort in the session's sort "
+                          "mode (lean_argsort or a multi-operand "
+                          "lax.sort); host paths use np.argsort",
     },
     "ops/join_kernels.py": {
         "count_matches": "sort-based hash-match counting rides "

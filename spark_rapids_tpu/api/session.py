@@ -190,13 +190,12 @@ class TpuSession:
             max_samples=conf.get(cfg.HBM_TIMELINE_MAX_SAMPLES),
             budget_bytes=self.spill_catalog.device_budget
             if self.spill_catalog is not None else 0)
-        # after plugin init: the cold-cache probe reads the persistent
-        # compile cache dir the plugin just configured
-        self._init_sort_mode(conf)
+        from ..ops.carry import set_compile_lean
+        set_compile_lean(conf.get(cfg.SORT_COMPILE_LEAN) == "on")
         # warm-start tier: replay the costliest ledger recipes so first
         # queries dispatch to ready programs.  Ordered after plugin and
-        # sort-mode init — the replay compiles through the persistent
-        # disk cache and must not flip the cold-cache probe's verdict.
+        # sort-mode init: the replay traces in the session's sort mode
+        # and compiles through the persistent disk cache.
         self._prewarm_thread = None
         if ledger_path and conf.get(cfg.JIT_PREWARM_ENABLED) and \
                 conf.get(cfg.COMPILE_OBSERVATORY_ENABLED):
@@ -205,37 +204,6 @@ class TpuSession:
                 ledger_path,
                 top_k=conf.get(cfg.JIT_PREWARM_TOP_K),
                 background=conf.get(cfg.JIT_PREWARM_BACKGROUND))
-
-    _auto_sort_mode_decided = False
-
-    def _init_sort_mode(self, conf: RapidsConf) -> None:
-        """Pick the sort kernel structure (ops/carry.py module doc):
-        'auto' = compile-lean exactly while the persistent XLA compile
-        cache is cold, throughput carry-sorts once it is warm.  The
-        auto probe decides ONCE per process — this process's own cache
-        writes must not flip kernel structure between sessions."""
-        import os
-        from ..ops.carry import set_compile_lean
-        mode = conf.get(cfg.SORT_COMPILE_LEAN)
-        if mode in ("on", "off"):
-            set_compile_lean(mode == "on")
-            TpuSession._auto_sort_mode_decided = True
-            return
-        if TpuSession._auto_sort_mode_decided:
-            return
-        try:
-            import jax
-            d = jax.config.jax_compilation_cache_dir
-            if not d:
-                # no persistent cache configured yet (plugin runs only
-                # for device sessions) — leave the decision to a later
-                # session that actually compiles device kernels
-                return
-            cold = not os.path.isdir(d) or not any(os.scandir(d))
-        except Exception:
-            cold = False
-        set_compile_lean(cold)
-        TpuSession._auto_sort_mode_decided = True
 
     # -- conf ---------------------------------------------------------------
     @property

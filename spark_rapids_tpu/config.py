@@ -293,17 +293,20 @@ SINGLE_CHIP_FUSE = conf("spark.rapids.tpu.singleChipFuse").string() \
     .create_with_default("auto")
 
 SORT_COMPILE_LEAN = conf("spark.rapids.tpu.sort.compileLean").string() \
-    .doc("Sort-kernel structure tradeoff.  'off' (throughput): payload "
-         "lanes ride the sort as extra lax.sort operands — fastest warm, "
-         "but a cache-cold novel shape pays minutes of XLA compile at "
-         "1M rows.  'on' (compile-lean): every sort lowers as iterated "
-         "2-operand (uint64, iota) passes plus payload gathers — an "
-         "order of magnitude cheaper to compile, ~20ms/lane slower "
-         "warm.  'auto' picks lean exactly when the persistent compile "
-         "cache is cold (fresh deployments' first queries) and "
-         "throughput kernels once it is warm.") \
-    .check_values(["auto", "on", "off"]) \
-    .create_with_default("auto")
+    .doc("Sort-kernel structure tradeoff.  'on' (compile-lean, the "
+         "default): every device sort is built from passes of one "
+         "2-operand (uint32 digit, int32 position) sort plus payload "
+         "gathers, the signature the TPU compiler takes least time "
+         "over (about 23 s at 4M rows, against 87 s for one stable "
+         "64-bit sort and 320 s for a carry-sort with two payload "
+         "lanes; v5e compiler, PR 21).  'off' (throughput): payload "
+         "lanes ride the sort as extra lax.sort operands, which a "
+         "cache-cold query pays for in minutes of compile per program.  "
+         "Which is faster warm has not been measured on the chip.  The "
+         "mode never follows the compile cache's warmth: programs "
+         "cached under one mode would all miss under the other.") \
+    .check_values(["on", "off"]) \
+    .create_with_default("on")
 
 JOIN_SPECULATIVE_SIZING = conf(
     "spark.rapids.tpu.join.speculativeSizing").boolean() \
@@ -524,24 +527,11 @@ OPTIMIZER_EXPLAIN = conf("spark.rapids.sql.optimizer.explain").string() \
 COMPILATION_CACHE_ENABLED = conf(
     "spark.rapids.tpu.compilationCache.enabled").boolean() \
     .doc("Persist XLA executables across queries and sessions so "
-         "re-planned queries skip compilation (keyed by platform + XLA "
-         "flags fingerprint).") \
+         "re-planned queries skip compilation.  The directory is "
+         "JAX_COMPILATION_CACHE_DIR where that variable is set, and "
+         "<checkout>/.jax_cache otherwise; disk hits and misses are "
+         "counted as tpu_jit_persistent_cache_{hits,misses}_total.") \
     .create_with_default(True)
-
-COMPILATION_CACHE_DIR = conf("spark.rapids.tpu.compilationCache.dir") \
-    .string() \
-    .doc("Directory for the persistent XLA compilation cache.") \
-    .create_with_default("~/.cache/spark_rapids_tpu_xla")
-
-JIT_PERSISTENT_CACHE_DIR = conf("spark.rapids.tpu.jit.persistentCacheDir") \
-    .string() \
-    .doc("Explicit directory for JAX's built-in persistent compilation "
-         "cache (jax_compilation_cache_dir), wired at session init.  "
-         "Overrides spark.rapids.tpu.compilationCache.dir when set; the "
-         "platform/XLA-flags/host fingerprint subdirectory scoping "
-         "still applies.  Disk hits and misses are counted as "
-         "tpu_jit_persistent_cache_{hits,misses}_total.") \
-    .create_optional()
 
 JIT_THRASH_WARN_RATIO = conf("spark.rapids.tpu.jit.cacheThrashWarnRatio") \
     .double() \
@@ -1142,9 +1132,8 @@ DECLARED_ENV_KEYS = (
     "SPARK_RAPIDS_TPU_JIT_CACHE_MAX",
     # disable the persistent XLA compile cache (plugin.py startup)
     "SPARK_RAPIDS_TPU_DISABLE_COMPILE_CACHE",
-    # hard deadline (seconds) on TPU device discovery before the
-    # single-chip/skip fallback (parallel/mesh.py; the MULTICHIP rc=124
-    # hang guard) — read before any conf exists
+    # hard deadline (seconds) on TPU device discovery, after which
+    # parallel/mesh.py raises — read before any conf exists
     "SPARK_RAPIDS_TPU_DEVICE_PROBE_TIMEOUT_S",
     # seed for shuffle/digest.py's process-wide digest switch: lets
     # session-less subprocesses (serve_map, the --dist bench child)

@@ -215,8 +215,8 @@ def _group_reduce(xp, key_cols: List[DeviceColumn],
             # at a segment's first row, the PREVIOUS row closes the
             # previous segment — compacting the shifted lane puts each
             # segment's total at slot+1
-            shifted = xp.concatenate([xp.zeros((1,), sseg.dtype),
-                                      sseg[:-1]])
+            from ..ops.scan import shift_right
+            shifted = shift_right(xp, sseg)
             job["kind"] = "sum_seg"
             job["lane"] = enlane(shifted)
             job["total"] = sseg[-1]
@@ -246,7 +246,8 @@ def _group_reduce(xp, key_cols: List[DeviceColumn],
         """Per-slot value from the NEXT slot's compacted lane entry; the
         last live slot reads the whole-array closing value."""
         E = comp[lane_idx]
-        nxt = xp.concatenate([E[1:], xp.zeros((1,), E.dtype)])
+        from ..ops.scan import shift_left
+        nxt = shift_left(xp, E)
         last = iota_slots == (num_groups - 1)
         return xp.where(last, xp.asarray(total, dtype=E.dtype), nxt)
 
@@ -330,11 +331,8 @@ def _collect_update(xp, vc: DeviceColumn, seg_ids, contrib, cap: int,
     if xp is np:
         order3 = np.argsort(~keep, kind="stable").astype(np.int32)
     else:
-        from jax import lax
-        iota = xp.arange(cap, dtype=xp.int32)
-        order3 = lax.sort(  # tpulint: allow[TPU-R017] group-compaction sort inline in the aggregate update/merge; host branch above uses np.argsort
-            ((~keep).astype(xp.int32), iota), num_keys=1,
-            is_stable=True)[1]
+        from ..ops.carry import stable_argsort
+        order3 = stable_argsort(xp, [(~keep).astype(xp.int32)], cap)
     child = gather_column(xp, perm, order3, keep[order3])
     cnt, _ = seg.segment_reduce(xp, "sum", keep.astype(np.int32), sids,
                                 cap, keep, sorted_ids=True)
@@ -381,11 +379,9 @@ def _collect_merge(xp, vc: DeviceColumn, order, seg_ids, contrib, cap: int,
     if xp is np:
         order3 = np.argsort(~keep, kind="stable").astype(np.int32)
     else:
-        from jax import lax
-        iota = xp.arange(child_cap, dtype=xp.int32)
-        order3 = lax.sort(  # tpulint: allow[TPU-R017] group-compaction sort inline in the aggregate update/merge; host branch above uses np.argsort
-            ((~keep).astype(xp.int32), iota), num_keys=1,
-            is_stable=True)[1]
+        from ..ops.carry import stable_argsort
+        order3 = stable_argsort(xp, [(~keep).astype(xp.int32)],
+                                child_cap)
     final_child = gather_column(xp, child_s, order3, keep[order3])
     cseg_s = cseg[order2]
     cnt, _ = seg.segment_reduce(xp, "sum", keep.astype(np.int64), cseg_s,

@@ -57,7 +57,8 @@ def _run_end_positions(xp, new_run):
     n = new_run.shape[0]
     pos = xp.arange(n, dtype=xp.int32)
     # reversed cummin of next-run starts == next run-start after each row
-    nxt = xp.concatenate([new_run[1:], xp.ones((1,), dtype=bool)])
+    from ..ops.scan import shift_left
+    nxt = shift_left(xp, new_run, True)
     ends = xp.where(nxt, pos, xp.int32(n - 1))
     # running min from the right: reverse, cummin (== -cummax of negation)
     rev = -ends[::-1]

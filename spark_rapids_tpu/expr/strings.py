@@ -755,7 +755,8 @@ def _eval_initcap(e, ctx: EvalContext):
     col = _string_input(ctx, v)
     c = col.data
     n = c.shape[0]
-    prev = xp.concatenate([xp.full((1,), np.uint8(32)), c[:-1]])
+    from ..ops.scan import shift_right
+    prev = shift_right(xp, c, np.uint8(32))
     # word start: previous byte is space, or byte is at a row start
     row_start = xp.zeros((n,), dtype=bool)
     starts = xp.clip(col.offsets[:-1], 0, n - 1)

@@ -1,8 +1,8 @@
 """Compile observatory: attribute, classify and persist every XLA
 compilation the engine pays for.
 
-BENCH_r05_builder measured the join suite at 68.6 s of compile against
-0.372 s of device time — the engine is compile-bound, and until this
+A cold query is compile-bound (the TPU compiler takes tens of seconds
+over every program that sorts; docs/performance.md), and until this
 module the only record of a compilation was an unlabeled ``jit.build``
 instant event with no duration, no cause and no cross-session memory.
 The observatory sits at the single ``process_jit`` seam
@@ -212,11 +212,21 @@ def _erase_sharding(sig: tuple) -> tuple:
     """A dispatch key with leaf shardings dropped.  Prewarmed programs
     are compiled from ShapeDtypeStruct skeletons (no sharding), while
     concrete query calls carry committed-device shardings — the
-    warm-start lookup matches on shapes/dtypes and lets the executable
-    itself reject a true sharding mismatch (caught, falls back to a
-    cold build)."""
+    warm-start lookup matches on shapes/dtypes, for calls whose
+    arguments all sit on the default device (``_on_default_device``)."""
     treedef, leaf_sigs = sig
     return (treedef, tuple((d, s, None) for d, s, _ in leaf_sigs))
+
+
+def _on_default_device(sig: tuple) -> bool:
+    """True when every leaf of a dispatch key is a host value or lives on
+    the default device alone: what an executable compiled from a
+    skeleton without shardings accepts.  Anything else (a mesh-sharded
+    stage input, another chip) is a different program and builds cold."""
+    import jax
+    home = {jax.devices()[0]}
+    return all(sh is None or sh.device_set == home
+               for _, _, sh in sig[1])
 
 
 def _aval_dispatch_key(args) -> Optional[tuple]:
@@ -714,15 +724,10 @@ class _ProfiledJit:
         fn = self._compiled.get(sig)
         if fn is not None:
             return fn(*args)
-        if self._prewarmed:
+        if self._prewarmed and _on_default_device(sig):
             fn = self._prewarmed.get(_erase_sharding(sig))
             if fn is not None:
-                try:
-                    out = fn(*args)
-                except Exception:
-                    # sharding/layout mismatch with the skeleton-compiled
-                    # executable: cold-build honestly instead
-                    return self._build_and_call(sig, args)
+                out = fn(*args)
                 with self._lock:
                     self._compiled.setdefault(sig, fn)
                 self._obs.note_prewarm_hit(
@@ -765,30 +770,24 @@ class _ProfiledJit:
 
     def _build(self, sig, args):
         t0 = time.perf_counter()
-        trace_s = compile_s = None
         hlo_bytes = 0
-        hlo_hash = cost = None
+        hlo_hash = None
+        # a lower or compile failure is the compiler's refusal of this
+        # program: it surfaces here, once, with its message
+        lowered = self._jitted.lower(*args)
+        t1 = time.perf_counter()
+        trace_s = t1 - t0
         try:
-            lowered = self._jitted.lower(*args)
-            t1 = time.perf_counter()
-            trace_s = t1 - t0
-            try:
-                text = lowered.as_text()
-                hlo_bytes = len(text)
-                hlo_hash, _ = self._obs.save_hlo(text)
-            except Exception:
-                hlo_bytes = 0
-            fn = lowered.compile()
-            compile_s = time.perf_counter() - t1
-            cost = cost_summary(fn)
-            self._obs.save_recipe_for(self._key, self._key_hash,
-                                      self._fn, args)
+            text = lowered.as_text()
+            hlo_bytes = len(text)
+            hlo_hash, _ = self._obs.save_hlo(text)
         except Exception:
-            # the AOT path is an observation vehicle: any lower/compile
-            # surprise falls back to plain jit dispatch (which recompiles
-            # internally and raises its own honest error if the program
-            # itself is broken)
-            fn = self._jitted
+            hlo_bytes = 0
+        fn = lowered.compile()
+        compile_s = time.perf_counter() - t1
+        cost = cost_summary(fn)
+        self._obs.save_recipe_for(self._key, self._key_hash,
+                                  self._fn, args)
         total_s = time.perf_counter() - t0
         self._obs.record_build(self._exec, self._key_hash,
                                self._canon_key, sig, trace_s,

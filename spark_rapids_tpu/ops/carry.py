@@ -24,18 +24,18 @@ from .gather import gather_column
 # ---------------------------------------------------------------------------
 # Compile-lean mode
 # ---------------------------------------------------------------------------
-# XLA's lowering of a many-operand 64-bit lax.sort costs MINUTES of
-# compile at 1M rows (docs/performance.md:44-52) — the dominant cost of
-# a cache-cold novel query.  In lean mode every sort call site traces
-# the SAME tiny shape instead: an iterated 2-operand (uint64 key, int32
-# iota) stable sort per key word, then gathers move the payload.  Warm
-# cost rises (one ~20ms gather per payload lane at 1M rows); compile
-# drops by an order of magnitude.  The session picks the mode from
-# spark.rapids.tpu.sort.compileLean: 'auto' = lean exactly when the
-# persistent XLA compile cache is cold (a fresh deployment's first
-# queries), throughput kernels once the cache is warm.
+# The TPU compiler's time for a `lax.sort` is set by the sort's signature,
+# not by how often a program repeats it (asked of the v5e compiler at
+# 4,194,304 rows, PR 21: one stable (uint64, int32) sort 87 s, three of
+# them in one program 90 s; a stable 2-key sort with two more payload
+# operands 320 s; an unstable 2-key (uint32, int32) sort 23 s).  In lean
+# mode every device sort in the engine is therefore built from passes of
+# that ONE cheapest signature: `lean_argsort` below.  Gathers then move
+# the payload, so warm cost rises by a gather per lane and per pass; what
+# that costs on the chip has not been measured on this code.  The session
+# picks the mode from spark.rapids.tpu.sort.compileLean.
 
-_LEAN = False
+_LEAN = True
 
 
 def set_compile_lean(enabled: bool) -> None:
@@ -47,17 +47,56 @@ def compile_lean_enabled() -> bool:
     return _LEAN
 
 
-def _sort_rows_lean(xp, key_words, cols, cap, extras):
-    """Iterated-pass lexicographic sort: one (uint64, iota) stable sort
-    per key word, least-significant first, then gather everything by the
-    final order.  Same results as the carry path, radically cheaper to
-    compile (every pass lowers the same 2-operand sort)."""
-    import jax
+def _u32_digits(xp, w) -> list:
+    """The order-preserving uint32 digits of one integer key word, least
+    significant first: one for words of up to 32 bits, two for 64."""
+    dt = np.dtype(w.dtype)
+    if dt == np.bool_:
+        return [w.astype(xp.uint32)]
+    if dt.kind not in "iu":
+        raise TypeError(f"lean sort key words are integers, not {dt}")
+    if dt.kind == "i":
+        # two's complement orders like unsigned once the sign bit flips
+        u = np.dtype(f"u{dt.itemsize}")
+        w = w.astype(u) ^ u.type(1 << (8 * dt.itemsize - 1))
+    if dt.itemsize <= 4:
+        return [w.astype(xp.uint32)]
+    return [w.astype(xp.uint32), (w >> np.uint64(32)).astype(xp.uint32)]
+
+
+def lean_argsort(xp, key_words, cap: int):
+    """Stable ascending lexicographic argsort (int32[cap]) by integer key
+    words, most significant first: a least-significant-digit radix sort
+    whose every pass is the same 2-operand (uint32 digit, int32 position)
+    sort.  The position is the second key, which makes each pass stable
+    without asking the compiler for a stable sort."""
     from jax import lax
-    order = xp.arange(cap, dtype=xp.int32)
+    iota = xp.arange(cap, dtype=xp.int32)
+    order = None
     for w in reversed(list(key_words)):
-        kw = w.astype(xp.uint64)[order]
-        _, order = lax.sort((kw, order), num_keys=1, is_stable=True)
+        for digit in _u32_digits(xp, w):
+            kw = digit if order is None else digit[order]
+            _, p = lax.sort((kw, iota), num_keys=2, is_stable=False)
+            order = p if order is None else order[p]
+    return iota if order is None else order
+
+
+def stable_argsort(xp, key_words, cap: int):
+    """Stable ascending lexicographic argsort (int32[cap]) on the device,
+    in the session's sort mode: `lean_argsort`, or one multi-operand
+    stable `lax.sort`."""
+    if _LEAN or not key_words:
+        return lean_argsort(xp, key_words, cap)
+    from jax import lax
+    iota = xp.arange(cap, dtype=xp.int32)
+    return lax.sort(tuple(key_words) + (iota,), num_keys=len(key_words),
+                    is_stable=True)[-1]
+
+
+def _sort_rows_lean(xp, key_words, cols, cap, extras):
+    """`sort_rows` by `lean_argsort`, then gather everything by the final
+    order.  Same results as the carry path, far cheaper to compile."""
+    order = lean_argsort(xp, key_words, cap)
     ones = xp.ones((cap,), dtype=bool)
     out_cols = [gather_column(xp, c, order, ones) for c in cols]
     out_extras = [e[order] for e in extras]

@@ -96,18 +96,25 @@ def count_matches(xp, build_hash, build_live, probe_hash, probe_live):
         counts = np.where(probe_live, hi - lo, 0).astype(np.int64)
         return order, lo, counts
     from jax import lax
+    from .carry import compile_lean_enabled, lean_argsort
     from .scan import cummax_i32, cumsum_fast
     cap_p = probe_hash.shape[0]
     iota_b = xp.arange(cap_b, dtype=xp.int32)
-    _, order = lax.sort((bh, iota_b), num_keys=1, is_stable=True)
     allh = xp.concatenate([bh, probe_hash])
     side = xp.concatenate([xp.zeros((cap_b,), xp.uint8),
                            xp.ones((cap_p,), xp.uint8)])
     idx = xp.concatenate([iota_b, xp.arange(cap_p, dtype=xp.int32)])
-    sh, ss, si = lax.sort((allh, side, idx), num_keys=2, is_stable=True)
+    if compile_lean_enabled():
+        order = lean_argsort(xp, [bh], cap_b)
+        both = lean_argsort(xp, [allh, side], cap_b + cap_p)
+        sh, ss, si = allh[both], side[both], idx[both]
+    else:
+        _, order = lax.sort((bh, iota_b), num_keys=1, is_stable=True)
+        sh, ss, si = lax.sort((allh, side, idx), num_keys=2,
+                              is_stable=True)
     is_b = (ss == 0).astype(xp.int32)
-    prev = xp.concatenate([sh[:1], sh[:-1]])
-    nb = (sh != prev)
+    from .scan import differs_from_prev
+    nb = differs_from_prev(xp, sh)
     n_all = cap_b + cap_p
     if n_all > 0:
         nb = nb | (xp.arange(n_all) == 0)

@@ -12,6 +12,29 @@ from __future__ import annotations
 import numpy as np
 
 
+# One-place shifts.  The obvious `concatenate([fill, v[:-1]])` is not used
+# on the device: inside a fused program the v5e compiler miscompiled it for
+# a 64-bit lane of 16,777,216 rows — every 197,632nd element was compared
+# with a wrong neighbour, so a grouped aggregate cut 83 groups in two
+# (chip run, PR 21; `jnp.roll` and an optimization barrier in front did no
+# better).  Comparing the two slices directly, and pad + slice (the form
+# the scans below are built from), came out right.
+
+def differs_from_prev(xp, w):
+    """w[i] != w[i-1], and False at 0."""
+    return xp.concatenate([xp.zeros((1,), dtype=bool), w[1:] != w[:-1]])
+
+
+def shift_right(xp, v, fill=0):
+    """v one place toward higher indices, `fill` at index 0."""
+    return xp.pad(v, (1, 0), constant_values=fill)[:v.shape[0]]
+
+
+def shift_left(xp, v, fill=0):
+    """v one place toward lower indices, `fill` at the end."""
+    return xp.pad(v, (0, 1), constant_values=fill)[1:]
+
+
 def cumsum_fast(xp, v, dtype=None, axis=None):
     """Inclusive prefix sum via pad-shift doubling.  On TPU this lowers
     to log2(n) elementwise adds (no reduce-window / scan HLO), which both

@@ -51,14 +51,41 @@ def encode_int_ordered(xp, data):
 
 def encode_float_ordered(xp, data):
     """float64 -> uint64 with Spark's total order (NaN last; Spark treats
-    -0.0 == 0.0 in comparisons — normalize first)."""
+    -0.0 == 0.0 in comparisons — normalize first).
+
+    The host and XLA:CPU read the IEEE bits.  The TPU has no bit view of
+    a float64 (its compiler refuses every 64-bit float bitcast: doubles
+    live there as pairs of float32), so the program lowered for it orders
+    by that pair instead: the nearest float32 and the float32 remainder,
+    which is the whole value there and orders as the value does."""
     d = data.astype(xp.float64)
+    if xp is np:
+        return _float_bits_ordered(xp, d)
+    from jax import lax
+    return lax.platform_dependent(d, tpu=_float_split_ordered,
+                                  default=lambda x: _float_bits_ordered(
+                                      xp, x))
+
+
+def _float_bits_ordered(xp, d):
     d = xp.where(d == 0.0, xp.zeros_like(d), d)          # -0.0 -> +0.0
     d = xp.where(xp.isnan(d), xp.full_like(d, xp.nan), d)  # canonical NaN
     bits = d.view(xp.int64) if hasattr(d, "view") else d.view(np.int64)
     neg = bits < 0
     enc = xp.where(neg, ~bits, bits | np.int64(-(2**63)))
     return enc.astype(xp.uint64)
+
+
+def _float_split_ordered(d):
+    import jax.numpy as jnp
+    hi = d.astype(jnp.float32)
+    # rounding to float32 is monotonic, so the pair orders as the value
+    # does; an infinite or NaN head has no remainder
+    lo = jnp.where(jnp.isfinite(hi), d - hi.astype(jnp.float64),
+                   0.0).astype(jnp.float32)
+    return (encode_float_ordered32(jnp, hi).astype(jnp.uint64)
+            << np.uint64(32)) | \
+        encode_float_ordered32(jnp, lo).astype(jnp.uint64)
 
 
 def encode_int_ordered32(xp, data):
@@ -132,16 +159,13 @@ def key_words_for_column(xp, col: DeviceColumn, live_mask,
 
 def lexsort(xp, key_words, capacity: int):
     """Stable ascending lexicographic argsort over key word lists
-    (most-significant first).  Uses lax.sort's multi-operand lexicographic
-    mode on TPU, np.lexsort on CPU."""
+    (most-significant first): carry.stable_argsort on the device,
+    np.lexsort on the host."""
     if xp is np:
         # np.lexsort: last key is primary
         return np.lexsort(tuple(reversed(key_words))).astype(np.int32)
-    from jax import lax
-    iota = xp.arange(capacity, dtype=xp.int32)
-    out = lax.sort(tuple(key_words) + (iota,), num_keys=len(key_words),
-                   is_stable=True)
-    return out[-1]
+    from .carry import stable_argsort
+    return stable_argsort(xp, key_words, capacity)
 
 
 # ---------------------------------------------------------------------------
@@ -153,10 +177,9 @@ def segment_boundaries(xp, sorted_words, live_sorted):
     differs from the previous row's."""
     n = sorted_words[0].shape[0]
     diff = xp.zeros((n,), dtype=bool)
+    from .scan import differs_from_prev
     for w in sorted_words:
-        prev = xp.concatenate([w[:1], w[:-1]])
-        d = w != prev
-        diff = diff | d
+        diff = diff | differs_from_prev(xp, w)
     first = xp.zeros((n,), dtype=bool)
     if n > 0:
         first = xp.arange(n) == 0

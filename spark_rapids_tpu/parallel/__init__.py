@@ -6,17 +6,8 @@ from .alltoall import (allgather_batch, allgather_supported,
                        exchange_by_pid, exchange_supported)
 from .mesh import DATA_AXIS, build_mesh, mesh_sharding
 
-try:
-    from .distributed import (DistributedAggregate, DistributedExchange,
-                              shards_to_table, stack_shards,
-                              unstack_shards)
-except ImportError:  # pragma: no cover
-    # jax builds without the stable shard_map API cannot run the SPMD
-    # stages; the admission gates and kernels above stay importable so
-    # planning, lint, and the capability table keep working (queries
-    # simply never take the ICI path on such builds)
-    DistributedAggregate = DistributedExchange = None
-    shards_to_table = stack_shards = unstack_shards = None
+from .distributed import (DistributedAggregate, DistributedExchange,
+                          shards_to_table, stack_shards, unstack_shards)
 
 __all__ = [
     "DATA_AXIS", "DistributedAggregate", "DistributedExchange",

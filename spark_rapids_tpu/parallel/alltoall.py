@@ -35,6 +35,7 @@ import jax.numpy as jnp
 
 from .. import types as t
 from ..columnar.device import DeviceBatch, DeviceColumn
+from ..ops.carry import stable_argsort
 from ..ops.scan import cumsum_fast
 
 
@@ -243,7 +244,7 @@ def exchange_by_pid(batch: DeviceBatch, pids, n_parts: int, axis_name: str,
     slot = slot or cap
     live = batch.row_mask()
     pid_key = jnp.where(live, pids.astype(jnp.int32), n_parts)
-    order = jnp.argsort(pid_key, stable=True)
+    order = stable_argsort(jnp, [pid_key], cap)
     counts, starts = _counts_starts(pid_key, n_parts)
 
     j = jnp.arange(slot, dtype=jnp.int32)
@@ -257,7 +258,7 @@ def exchange_by_pid(batch: DeviceBatch, pids, n_parts: int, axis_name: str,
     recv_valid = a2a(send_valid)
     flat_rows = n_parts * slot
     valid_flat = recv_valid.reshape(flat_rows)
-    ord2 = jnp.argsort(~valid_flat, stable=True)
+    ord2 = stable_argsort(jnp, [~valid_flat], flat_rows)
     out_total = jnp.sum(valid_flat.astype(jnp.int32))
     out_live = jnp.arange(flat_rows, dtype=jnp.int32) < out_total
 
@@ -330,7 +331,7 @@ def allgather_batch(batch: DeviceBatch, axis_name: str,
     live = batch.row_mask()
     flat_rows = n_parts * cap
     valid_flat = ag(live)
-    ord2 = jnp.argsort(~valid_flat, stable=True)
+    ord2 = stable_argsort(jnp, [~valid_flat], flat_rows)
     total = jnp.sum(valid_flat.astype(jnp.int32))
     out_live = jnp.arange(flat_rows, dtype=jnp.int32) < total
 

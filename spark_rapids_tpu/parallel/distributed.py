@@ -21,22 +21,8 @@ import jax
 import jax.numpy as jnp
 import pyarrow as pa
 
-try:
-    from jax import shard_map
-except ImportError:
-    # pre-0.6 jax ships shard_map under experimental with the replica
-    # check named check_rep instead of check_vma; adapt the call shape
-    # so the SPMD stages run on both API generations
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f=None, *, mesh, in_specs, out_specs, check_vma=True):
-        if f is None:
-            return lambda g: shard_map(g, mesh=mesh, in_specs=in_specs,
-                                       out_specs=out_specs,
-                                       check_vma=check_vma)
-        return _shard_map_exp(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
-from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import types as t
 from ..columnar.device import (DEFAULT_ROW_BUCKETS, DeviceBatch,
@@ -63,24 +49,38 @@ class _SchemaSource:
         raise RuntimeError("schema-only node is never executed")
 
 
-def stack_shards(tables: Sequence[pa.Table], capacity: Optional[int] = None):
-    """Upload one Arrow table per device and stack them on a leading
-    device axis (the host->mesh transfer; each shard then lives on its
-    device under `jax.device_put` with a row sharding)."""
+def stack_shards(tables: Sequence[pa.Table], capacity: Optional[int] = None,
+                 mesh: Optional[Mesh] = None, axis: str = DATA_AXIS):
+    """Upload one Arrow table per mesh device and assemble the shards
+    into arrays row-sharded on a leading device axis: shard ``i`` is
+    uploaded to, and stays on, device ``i`` of ``mesh`` (default: the
+    first ``len(tables)`` devices)."""
+    mesh = mesh or build_mesh(len(tables))
+    devices = list(mesh.devices.flat)
+    if len(devices) != len(tables):
+        raise ValueError(
+            f"{len(tables)} shards for a {len(devices)}-device mesh")
     n_rows = max(max((tb.num_rows for tb in tables), default=1), 1)
     cap = capacity or bucket_for(n_rows, DEFAULT_ROW_BUCKETS)
     batches = []
-    for tb in tables:
+    for tb, dev in zip(tables, devices):
         rbs = tb.combine_chunks().to_batches()
         rb = rbs[0] if rbs else pa.RecordBatch.from_pydict(
             {f.name: pa.array([], type=f.type) for f in tb.schema},
             schema=tb.schema)
-        batches.append(batch_to_device(rb, capacity=cap))
+        with jax.default_device(dev):
+            # committed, so the padding below stays on the shard's device
+            batches.append(jax.device_put(
+                batch_to_device(rb, capacity=cap), dev))
     # equalize char capacities across shards so stacking is legal
     batches = _equalize_char_caps(batches)
-    stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs, axis=0),
-                                     *batches)
-    return stacked
+    sharding = NamedSharding(mesh, P(axis))
+
+    def stack(*xs):
+        parts = [x[None] for x in xs]
+        return jax.make_array_from_single_device_arrays(
+            (len(parts),) + parts[0].shape[1:], sharding, parts)
+    return jax.tree_util.tree_map(stack, *batches)
 
 
 def _equalize_char_caps(batches: List[DeviceBatch]) -> List[DeviceBatch]:
@@ -142,10 +142,29 @@ def _equalize_char_caps(batches: List[DeviceBatch]) -> List[DeviceBatch]:
             for bi, b in enumerate(batches)]
 
 
-def unstack_shards(stacked: DeviceBatch) -> List[DeviceBatch]:
-    n_dev = int(jax.tree_util.tree_leaves(stacked)[0].shape[0])
-    return [jax.tree_util.tree_map(lambda x, i=i: x[i], stacked)
-            for i in range(n_dev)]
+def _split_device_axis(x) -> list:
+    """Slices of ``x`` along its leading device axis.  A row-sharded
+    array hands out each device's own buffer; indexing it instead would
+    run one SPMD gather (with its collectives) per shard."""
+    n = int(x.shape[0])
+    shards = x.addressable_shards
+    if len(shards) == n and all(s.data.shape[0] == 1 for s in shards):
+        by_row = sorted(shards, key=lambda s: s.index[0].start or 0)
+        return [s.data[0] for s in by_row]
+    return [x[i] for i in range(n)]
+
+
+def unstack_shards(stacked: DeviceBatch, device=None) -> List[DeviceBatch]:
+    """Per-device batches of a stacked batch, each left on the device
+    that holds it, or moved to ``device`` (for single-device operators
+    downstream of a mesh stage)."""
+    leaves, treedef = jax.tree_util.tree_flatten(stacked)
+    per_leaf = [_split_device_axis(x) for x in leaves]
+    out = [jax.tree_util.tree_unflatten(treedef, [pl[i] for pl in per_leaf])
+           for i in range(len(per_leaf[0]))]
+    if device is not None:
+        out = [jax.device_put(b, device) for b in out]
+    return out
 
 
 def shards_to_table(stacked: DeviceBatch) -> pa.Table:
@@ -242,7 +261,7 @@ class DistributedAggregate:
         """tables: one scan shard per device."""
         assert len(tables) == self.n_dev, \
             f"need {self.n_dev} shards, got {len(tables)}"
-        stacked = stack_shards(tables)
+        stacked = stack_shards(tables, mesh=self.mesh, axis=self.axis)
         out = self._compiled(stacked)
         result = shards_to_table(out)
         if not self.partial.grouping and result.num_rows:
@@ -299,7 +318,8 @@ class DistributedExchange:
 
     def run(self, tables: Sequence[pa.Table]) -> List[pa.Table]:
         assert len(tables) == self.n_dev
-        out = self.run_stacked(stack_shards(tables))
+        out = self.run_stacked(
+            stack_shards(tables, mesh=self.mesh, axis=self.axis))
         return [pa.Table.from_batches([batch_to_arrow(b)])
                 for b in unstack_shards(out)]
 
@@ -366,7 +386,10 @@ class DistributedSort:
         w0, live = self._first_key_word(b)
         cap = b.capacity
         maxw = jnp.uint64(0xFFFFFFFFFFFFFFFF)
-        sorted_w0 = jnp.sort(jnp.where(live, w0, maxw))
+        from ..ops import carry
+        parked = jnp.where(live, w0, maxw)
+        sorted_w0 = parked[carry.lean_argsort(jnp, [parked], cap)] \
+            if carry.compile_lean_enabled() else jnp.sort(parked)
         n_live = jnp.sum(live.astype(jnp.int32))
         # local splitter candidates at the n_dev-quantiles
         q = (jnp.arange(1, n_dev, dtype=jnp.int32) * n_live) // n_dev
@@ -406,7 +429,8 @@ class DistributedSort:
         """tables: one shard per device; returns the totally-ordered
         concatenation (shard 0's range first)."""
         assert len(tables) == self.n_dev
-        out = self._compiled(stack_shards(tables))
+        out = self._compiled(
+            stack_shards(tables, mesh=self.mesh, axis=self.axis))
         return shards_to_table(out)
 
 
@@ -535,8 +559,9 @@ class DistributedHashJoin:
             right_tables: Sequence[pa.Table]) -> pa.Table:
         assert len(left_tables) == self.n_dev
         assert len(right_tables) == self.n_dev
-        return self.run_stacked(stack_shards(left_tables),
-                                stack_shards(right_tables))
+        return self.run_stacked(
+            stack_shards(left_tables, mesh=self.mesh, axis=self.axis),
+            stack_shards(right_tables, mesh=self.mesh, axis=self.axis))
 
     def run_stacked(self, ls: DeviceBatch, rs: DeviceBatch) -> pa.Table:
         """Join pre-stacked per-device shards (the device-resident

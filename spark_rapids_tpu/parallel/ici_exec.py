@@ -42,6 +42,8 @@ class IciAggregateExec(Exec):
     final ← exchange ← partial; one XLA program, rows ride ICI)."""
 
     placement = TPU
+    #: distinct devices that held the last stacked stage input
+    stage_input_devices = 0
 
     def __init__(self, final_agg, mesh=None):
         from .mesh import build_mesh
@@ -82,9 +84,10 @@ class IciAggregateExec(Exec):
         source = self.children[0]
         stacked = _gather_source_stacked(
             source, ctx, source.output_names, source.output_types,
-            self._dagg.n_dev)
+            self.mesh)
         if stacked is not None:
             _note_stage("aggregate", "stacked", self._dagg.n_dev)
+            self.stage_input_devices = _device_count(stacked)
             with MetricTimer(self.metrics[OP_TIME]):
                 out = self._dagg._compiled(stacked)
             yield from _emit_stacked(self, out)
@@ -96,6 +99,15 @@ class IciAggregateExec(Exec):
         with MetricTimer(self.metrics[OP_TIME]):
             out = self._dagg.run(shards)
         yield from _emit_table(self, out)
+
+
+def _device_count(stacked) -> int:
+    """Distinct devices holding a stacked stage input's lanes."""
+    import jax
+    devs = set()
+    for leaf in jax.tree_util.tree_leaves(stacked):
+        devs |= leaf.devices()
+    return len(devs)
 
 
 def _gather_source_table(source: Exec, ctx, names, dtypes) -> pa.Table:
@@ -142,12 +154,13 @@ def _stackable_schema(dtypes) -> bool:
     return all(flat(dt) or spannable(dt) for dt in dtypes)
 
 
-def _gather_source_stacked(source: Exec, ctx, names, dtypes, n_dev: int):
+def _gather_source_stacked(source: Exec, ctx, names, dtypes, mesh):
     """Device-resident scan->mesh edge: collect the source's DEVICE
-    batches, concatenate on device, and reshape every lane to
-    (n_dev, shard_cap) with ONE jitted program — rows never stage
-    through host Arrow (ref RapidsShuffleInternalManagerBase.scala:74:
-    shuffle input stays device-resident end-to-end).  String/binary
+    batches, concatenate on device, reshape every lane to
+    (n_dev, shard_cap) with ONE jitted program, and hand shard ``i`` to
+    mesh device ``i`` (device-to-device) — rows never stage through
+    host Arrow (ref RapidsShuffleInternalManagerBase.scala:74: shuffle
+    input stays device-resident end-to-end).  String/binary
     lanes rebase: each shard slices its char range at the source's char
     capacity (conservative static shape; a balanced shard holds ~1/n of
     the bytes) and rewrites offsets relative to its slice.  Returns the
@@ -158,12 +171,13 @@ def _gather_source_stacked(source: Exec, ctx, names, dtypes, n_dev: int):
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from ..columnar.device import (DEFAULT_ROW_BUCKETS, DeviceBatch,
-                                   DeviceColumn, batch_to_device,
-                                   bucket_for)
+    from ..columnar.device import (DeviceBatch, DeviceColumn,
+                                   batch_to_device)
     from ..exec.concat import concat_batches
-    from ..exec.base import process_jit, schema_sig
+    from ..exec.base import process_jit
+    from .mesh import DATA_AXIS, mesh_sharding
 
+    n_dev = mesh.shape[DATA_AXIS]
     batches = []
     for spid in range(source.num_partitions):
         for b in source.execute_partition(spid, ctx):
@@ -256,14 +270,15 @@ def _gather_source_stacked(source: Exec, ctx, names, dtypes, n_dev: int):
                       tuple(repr(d) for d in dtypes), in_cap, n_dev, per,
                       char_caps),
                      make)
-    return fn(merged)
+    return jax.device_put(fn(merged), mesh_sharding(mesh))
 
 
 def _emit_stacked(self, stacked) -> Iterator[Batch]:
-    """Yield per-shard device batches (mesh order) without host staging."""
-    import jax
+    """Yield per-shard device batches (mesh order) without host staging,
+    gathered device-to-device onto the mesh's first chip, where the
+    single-device operators downstream run."""
     from .distributed import unstack_shards
-    for b in unstack_shards(stacked):
+    for b in unstack_shards(stacked, device=self.mesh.devices.flat[0]):
         n = int(np.asarray(b.num_rows))
         if n == 0:
             continue
@@ -296,6 +311,8 @@ class IciSortExec(Exec):
     GpuSortExec)."""
 
     placement = TPU
+    #: distinct devices that held the last stacked stage input
+    stage_input_devices = 0
 
     def __init__(self, sort_exec, mesh=None):
         from .mesh import build_mesh
@@ -324,9 +341,10 @@ class IciSortExec(Exec):
         source = self.children[0]
         stacked = _gather_source_stacked(
             source, ctx, source.output_names, source.output_types,
-            self._dsort.n_dev)
+            self.mesh)
         if stacked is not None:
             _note_stage("sort", "stacked", self._dsort.n_dev)
+            self.stage_input_devices = _device_count(stacked)
             # shard i holds globally-ordered range i: emit in mesh order
             with MetricTimer(self.metrics[OP_TIME]):
                 out = self._dsort._compiled(stacked)
@@ -348,6 +366,8 @@ class IciJoinExec(Exec):
     UCXShuffleTransport)."""
 
     placement = TPU
+    #: distinct devices that held the last stacked stage input
+    stage_input_devices = 0
 
     def __init__(self, join_exec, mesh=None):
         from .mesh import build_mesh
@@ -381,12 +401,14 @@ class IciJoinExec(Exec):
         # device-resident edge first: both sides reshard on device and
         # the join consumes the stacked shards without host staging
         ls = _gather_source_stacked(lsrc, ctx, lsrc.output_names,
-                                    lsrc.output_types, n_dev)
+                                    lsrc.output_types, self.mesh)
         rs = _gather_source_stacked(rsrc, ctx, rsrc.output_names,
-                                    rsrc.output_types, n_dev) \
+                                    rsrc.output_types, self.mesh) \
             if ls is not None else None
         if ls is not None and rs is not None:
             _note_stage("join", "stacked", n_dev)
+            self.stage_input_devices = min(_device_count(ls),
+                                           _device_count(rs))
             with MetricTimer(self.metrics[OP_TIME]):
                 out = self._djoin.run_stacked(ls, rs)
             yield from _emit_table(self, out)
@@ -477,7 +499,7 @@ class IciExchangeExec(Exec):
             source = self.children[0]
             stacked = _gather_source_stacked(
                 source, ctx, source.output_names, source.output_types,
-                self._dex.n_dev)
+                self.mesh)
             _note_stage("exchange",
                         "stacked" if stacked is not None else "host",
                         self._dex.n_dev)
@@ -485,7 +507,8 @@ class IciExchangeExec(Exec):
                 if stacked is not None:
                     out = self._dex.run_stacked(stacked)
                     from .distributed import unstack_shards
-                    shards = unstack_shards(out)
+                    shards = unstack_shards(
+                        out, device=self.mesh.devices.flat[0])
                 else:
                     tbl = _gather_source_table(source, ctx,
                                                source.output_names,
@@ -525,12 +548,10 @@ def install_ici_stages(root: Exec, conf: cfg.RapidsConf) -> Exec:
     alike); this pass is the plan-level equivalent."""
     if conf.get(cfg.SHUFFLE_TRANSPORT) != "ici":
         return root
-    # deadline-bounded discovery: a hung multichip topology exchange
-    # (the MULTICHIP rc=124 shape) degrades to the single-chip path —
-    # counted in tpu_device_probe_failures_total + a tracer event —
-    # instead of hanging the planner
+    # the ICI transport was asked for: a failed device discovery
+    # raises here rather than quietly planning the single-chip path
     from .mesh import device_count
-    if device_count(default=1) < 2:
+    if device_count() < 2:
         return root
     from ..exec.aggregate import TpuHashAggregateExec
     from ..exec.join import HashJoinExec

@@ -25,42 +25,47 @@ from . import config as cfg
 log = logging.getLogger("spark_rapids_tpu.plugin")
 
 
-def _host_cpu_fingerprint() -> str:
-    """Identify the host machine instance for the compilation-cache key.
+def default_cache_dir() -> str:
+    """``<checkout>/.jax_cache``: a fixed path, because the path is part
+    of how a later process finds the cache again.  JAX's own key covers
+    version, backend and flags, so nothing else goes into it."""
+    import os
+    return os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
 
-    CPU feature flags alone are NOT enough: two VM instances can report
-    identical cpuinfo flags while their pCPUs differ in ways XLA:CPU's
-    AOT executables bake in — loading a stale instance's entry then
-    SIGILLs/SEGVs inside the cache read (observed: a suite run crashing
-    in get_executable_and_time on an entry a previous instance wrote).
-    Scoping by machine-id/boot-id keeps the cache warm for the whole
-    life of an instance (what repeated queries and CI runs need) while
-    making cross-instance AOT reuse — the only unsafe case — a miss."""
-    import hashlib
-    import platform
 
-    flags = ""
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                if line.startswith(("flags", "Features")):
-                    flags = " ".join(sorted(line.split(":", 1)[1].split()))
-                    break
-                if line.startswith("model name") and not flags:
-                    flags = line.split(":", 1)[1].strip()
-    except OSError:
-        flags = platform.processor()
-    instance = ""
-    for p in ("/etc/machine-id", "/proc/sys/kernel/random/boot_id"):
-        try:
-            with open(p) as f:
-                instance = f.read().strip()
-            if instance:
-                break
-        except OSError:
-            continue
-    return platform.machine() + "|" + \
-        hashlib.sha1(f"{flags}|{instance}".encode()).hexdigest()[:12]
+def compilation_cache_dir() -> str:
+    """Where the persistent compilation cache lives:
+    ``JAX_COMPILATION_CACHE_DIR`` places it from outside; only when that
+    is unset does code choose, ``default_cache_dir()``."""
+    import os
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or \
+        default_cache_dir()
+
+
+def init_compilation_cache() -> str:
+    """Switch on JAX's persistent compilation cache and return its
+    directory (``compilation_cache_dir()``).  JAX reads the variable
+    itself, so the directory is set in code only when it is unset."""
+    import os
+
+    import jax
+    cache_dir = compilation_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        # a compile before this point would have initialized the cache
+        # with no directory, for good
+        from jax.experimental.compilation_cache import compilation_cache
+        compilation_cache.reset_cache()
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # count disk hits/misses so the observatory can tell whether the
+    # persistent cache actually absorbs backend compiles
+    from .obs.compileprof import install_persistent_cache_metrics
+    install_persistent_cache_metrics()
+    return cache_dir
 
 
 class PluginInitError(RuntimeError):
@@ -193,11 +198,7 @@ class TpuExecutorPlugin:
     @staticmethod
     def check_runtime_versions() -> List[str]:
         problems = []
-        import jax
         import pyarrow
-        jv = tuple(int(x) for x in jax.__version__.split(".")[:2])
-        if jv < (0, 4):
-            problems.append(f"jax {jax.__version__} is too old (need 0.4+)")
         pv = tuple(int(x) for x in pyarrow.__version__.split(".")[:1])
         if pv < (8,):
             problems.append(
@@ -300,43 +301,12 @@ class TpuExecutorPlugin:
         if not self.conf.get(cfg.COMPILATION_CACHE_ENABLED):
             return
         if os.environ.get("SPARK_RAPIDS_TPU_DISABLE_COMPILE_CACHE"):
-            # escape hatch for environments running many engine
-            # processes against one cache dir concurrently: XLA:CPU AOT
-            # loads from a dir under concurrent write have been observed
-            # to segfault inside the cache read (tests/conftest.py sets
-            # this — the hermetic suite relies on the in-process jit
-            # table, and must never crash on a cache race)
+            # for environments that run many engine processes side by
+            # side (tests/conftest.py, the serve_map children): XLA:CPU
+            # AOT loads from a directory under concurrent write have
+            # segfaulted inside the cache read
             return
-        # the explicit per-deployment key wins; the legacy key is the
-        # default location (ROADMAP item 1: the cheapest first bite of
-        # cross-session compile reuse is jax's own disk cache)
-        cache_dir = os.path.expanduser(
-            self.conf.get(cfg.JIT_PERSISTENT_CACHE_DIR)
-            or self.conf.get(cfg.COMPILATION_CACHE_DIR))
-        try:
-            import hashlib
-            import jax
-            # scope by platform + XLA flags + host CPU features: AOT
-            # executables compiled under one CPU-feature set must not
-            # load under another (XLA warns about possible SIGILL on
-            # mismatch), so a cache dir shared across heterogeneous
-            # hosts or a migrated home dir must miss, not crash
-            fp = hashlib.sha1(
-                f"{jax.__version__}|{jax.default_backend()}|"
-                f"{os.environ.get('XLA_FLAGS', '')}|"
-                f"{_host_cpu_fingerprint()}".encode()).hexdigest()[:12]
-            cache_dir = os.path.join(cache_dir, fp)
-            os.makedirs(cache_dir, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.0)
-            # count disk hits/misses so the observatory can tell whether
-            # the persistent cache actually absorbs backend compiles
-            from .obs.compileprof import install_persistent_cache_metrics
-            install_persistent_cache_metrics()
-        except Exception as ex:  # cache is an optimization, never fatal
-            log.warning("compilation cache unavailable: %s", ex)
+        init_compilation_cache()
 
     def shutdown(self):
         if self.shuffle_server is not None:

@@ -83,11 +83,78 @@ def _sort_key(row):
     return tuple(k(v) for v in row)
 
 
+def _flat(table: pa.Table) -> bool:
+    """Only fixed-width scalar columns: what the column-at-a-time
+    comparison below can hold to the row-at-a-time rules."""
+    return all(pa.types.is_integer(f.type) or pa.types.is_floating(f.type)
+               or pa.types.is_boolean(f.type) for f in table.schema)
+
+
+def _assert_flat_tables_equal(cpu: pa.Table, tpu: pa.Table,
+                              ignore_order: bool, approx: float):
+    """`assert_tables_equal` for flat tables, a column at a time with
+    Arrow and NumPy: the same rules as `_val_equal` (nulls match nulls,
+    NaN matches NaN, infinities exactly, floats within `approx`), at a
+    cost that lets a result of tens of millions of rows be checked."""
+    import numpy as np
+    import pyarrow.compute as pc
+    if ignore_order:
+        # exact columns first, so that floats which differ in their
+        # last digits cannot reorder rows that the other columns tell
+        # apart
+        names = sorted(cpu.schema.names,
+                       key=lambda n: pa.types.is_floating(
+                           cpu.schema.field(n).type))
+        keys = [(n, "ascending") for n in names]
+        cpu = cpu.take(pc.sort_indices(cpu, sort_keys=keys))
+        tpu = tpu.take(pc.sort_indices(tpu, sort_keys=keys))
+    bad = np.zeros(cpu.num_rows, dtype=bool)
+    for name in cpu.schema.names:
+        a, b = (t.column(name).combine_chunks() for t in (cpu, tpu))
+        a_null = np.asarray(a.is_null())
+        bad |= a_null != np.asarray(b.is_null())
+        is_float = pa.types.is_floating(a.type) or \
+            pa.types.is_floating(b.type)
+        fill = 0.0 if is_float else 0
+        if pa.types.is_boolean(a.type):
+            fill = False
+        av = a.fill_null(fill).to_numpy(zero_copy_only=False)
+        bv = b.fill_null(fill).to_numpy(zero_copy_only=False)
+        if not is_float:
+            bad |= (av != bv) & ~a_null
+            continue
+        av, bv = av.astype(np.float64), bv.astype(np.float64)
+        with np.errstate(invalid="ignore"):
+            diff = np.abs(av - bv)
+            if approx > 0:
+                denom = np.maximum(np.maximum(np.abs(av), np.abs(bv)),
+                                   1e-12)
+                close = (diff <= approx * denom) | (diff < 1e-11)
+            else:
+                close = av == bv
+        same = np.where(np.isnan(av) | np.isnan(bv),
+                        np.isnan(av) & np.isnan(bv),
+                        np.where(np.isinf(av) | np.isinf(bv), av == bv,
+                                 close))
+        bad |= ~same & ~a_null
+    if bad.any():
+        i = int(np.flatnonzero(bad)[0])
+        raise AssertionError(
+            f"row {i} differs ({int(bad.sum())} rows differ):\n"
+            f"  cpu: {tuple(cpu.slice(i, 1).to_pylist()[0].values())}\n"
+            f"  tpu: {tuple(tpu.slice(i, 1).to_pylist()[0].values())}")
+
+
 def assert_tables_equal(cpu: pa.Table, tpu: pa.Table,
                         ignore_order: bool = True,
                         approximate_float: float = 0.0):
     assert cpu.schema.names == tpu.schema.names, \
         f"schema mismatch: {cpu.schema.names} vs {tpu.schema.names}"
+    assert cpu.num_rows == tpu.num_rows, \
+        f"row count: cpu={cpu.num_rows} tpu={tpu.num_rows}"
+    if _flat(cpu) and _flat(tpu):
+        return _assert_flat_tables_equal(cpu, tpu, ignore_order,
+                                         approximate_float)
     crows = [tuple(r.values()) for r in cpu.to_pylist()]
     trows = [tuple(r.values()) for r in tpu.to_pylist()]
     assert len(crows) == len(trows), \

@@ -13,7 +13,7 @@
     python -m spark_rapids_tpu.tools tail-report    --ledger PATH [--top N] [--json]
     python -m spark_rapids_tpu.tools estimator-report --ledger PATH [--top N] [--json]
     python -m spark_rapids_tpu.tools kernel-report  --compile-ledger PATH --estimator-ledger PATH [--top N] [--json]
-    python -m spark_rapids_tpu.tools prewarm        --ledger DIR [--top K] [--cache-dir DIR]
+    python -m spark_rapids_tpu.tools prewarm        --ledger DIR [--top K]
     python -m spark_rapids_tpu.tools postmortem     <bundle.json|dir> [--json] [--last N]
     python -m spark_rapids_tpu.tools top            [--url HOST:PORT] [--watch] [--json]
 
@@ -341,7 +341,7 @@ def _run_regress(history_dir, record_logs, check, wall_threshold,
     return 0
 
 
-def _run_prewarm(ledger, top, cache_dir):
+def _run_prewarm(ledger, top):
     import os
 
     path = ledger
@@ -351,25 +351,11 @@ def _run_prewarm(ledger, top, cache_dir):
     if not os.path.exists(path):
         sys.stderr.write(f"{ledger}: no compile ledger found\n")
         return 2
-    if cache_dir:
-        # same platform/XLA-flags/host scoping as the plugin wires at
-        # session init, so the entries this replay writes are the ones
-        # a real session will read
-        import hashlib
-
-        import jax
-
-        from ..plugin import _host_cpu_fingerprint
-        fp = hashlib.sha1(
-            f"{jax.__version__}|{jax.default_backend()}|"
-            f"{os.environ.get('XLA_FLAGS', '')}|"
-            f"{_host_cpu_fingerprint()}".encode()).hexdigest()[:12]
-        d = os.path.join(os.path.expanduser(cache_dir), fp)
-        os.makedirs(d, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", d)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.0)
+    if not os.environ.get("SPARK_RAPIDS_TPU_DISABLE_COMPILE_CACHE"):
+        # the one cache a session reads (plugin.init_compilation_cache):
+        # the entries this replay writes are the ones it will find
+        from ..plugin import init_compilation_cache
+        init_compilation_cache()
     from ..obs.compileprof import CompileObservatory
     from ..obs.prewarm import prewarm_from_ledger
     CompileObservatory.get().configure(enabled=True, ledger_path=path)
@@ -593,10 +579,6 @@ def main(argv=None):
     pw.add_argument("--top", type=int, default=32,
                     help="how many programs to replay, ranked by "
                          "cumulative compile seconds")
-    pw.add_argument("--cache-dir", default=None,
-                    help="persistent XLA compile cache to populate "
-                         "(spark.rapids.tpu.jit.persistentCacheDir); "
-                         "without it the replay only validates recipes")
     tp = sub.add_parser("top",
                         help="live in-flight query view (phase, "
                              "progress, ETA, deepest open operator, "
@@ -673,7 +655,7 @@ def main(argv=None):
         return run_estimator_report(args.ledger, top=args.top,
                                     as_json=args.json)
     elif args.cmd == "prewarm":
-        return _run_prewarm(args.ledger, args.top, args.cache_dir)
+        return _run_prewarm(args.ledger, args.top)
     elif args.cmd == "top":
         from .top import run_top
         return run_top(args.url, interval=args.interval,

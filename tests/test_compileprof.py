@@ -253,3 +253,38 @@ def test_disabled_observatory_returns_plain_jit(obs):
     f = eb.process_jit(("ProbeExec", "off"), lambda: (lambda x: x + 1))
     assert int(f(jnp.int32(41))) == 42
     assert obs.snapshot()["builds"] == 0
+
+
+# -- a refusal surfaces once, with its message ------------------------------
+
+def test_a_program_that_cannot_be_built_raises_and_is_traced_once(obs):
+    calls = []
+
+    def refused(x):
+        calls.append(1)
+        raise NotImplementedError("the compiler's own message")
+
+    f = eb.process_jit(("ProbeExec", "refused"), lambda: refused)
+    with pytest.raises(NotImplementedError, match="the compiler's own"):
+        f(jnp.arange(4))
+    # no second, silent build through plain jit
+    assert len(calls) == 1
+    assert obs.snapshot()["builds"] == 0
+
+
+def test_prewarmed_programs_serve_only_the_default_device():
+    import jax
+    from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                              SingleDeviceSharding)
+    from spark_rapids_tpu.obs.compileprof import (_dispatch_key,
+                                                  _on_default_device)
+    devs = jax.devices()
+    home = jax.device_put(jnp.arange(8), devs[0])
+    away = jax.device_put(jnp.arange(8), devs[1])
+    spread = jax.device_put(
+        jnp.arange(8), NamedSharding(Mesh(np.array(devs), ("d",)),
+                                     PartitionSpec("d")))
+    assert isinstance(home.sharding, SingleDeviceSharding)
+    assert _on_default_device(_dispatch_key((home, 3, np.float32(1))))
+    assert not _on_default_device(_dispatch_key((home, away)))
+    assert not _on_default_device(_dispatch_key((spread,)))

@@ -1,6 +1,7 @@
 """ICI transport routing: session queries run the fused mesh aggregate
 when spark.rapids.shuffle.transport=ici and multiple chips exist."""
 
+import jax
 import numpy as np
 import pyarrow as pa
 import pytest
@@ -8,6 +9,9 @@ import pytest
 from spark_rapids_tpu.api import functions as F
 from spark_rapids_tpu.api.column import col
 from spark_rapids_tpu.api.session import TpuSession
+
+# every test here runs collectives across the mesh's device threads
+pytestmark = pytest.mark.time_limit(300)
 
 
 def _session(transport="ici"):
@@ -262,7 +266,7 @@ def test_ici_bare_repartition_routed():
         "v": pa.array(rng.integers(-99, 99, n).astype(np.int64)),
     })
     got = (s.create_dataframe(tb, num_partitions=4)
-           .repartition(8, col("k")).collect())
+           .repartition(jax.device_count(), col("k")).collect())
     names = _names(s)
     assert "IciExchangeExec" in names, names
     assert "ShuffleExchangeExec" not in names
@@ -440,7 +444,7 @@ def test_ici_array_repartition_device_resident(monkeypatch):
     })
     s = _session()
     got = (s.create_dataframe(tb, num_partitions=4)
-           .repartition(8, col("k")).collect())
+           .repartition(jax.device_count(), col("k")).collect())
     assert "IciExchangeExec" in _names(s), _names(s)
     key = lambda r: (r[0], repr(r[1]))  # noqa: E731
     got_rows = sorted(zip(got.column("k").to_pylist(),

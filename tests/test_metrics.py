@@ -312,7 +312,7 @@ def test_r007_allow_annotation_sanctions_in_place(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# device-probe deadline (the MULTICHIP rc=124 guard)
+# device-probe deadline
 # ---------------------------------------------------------------------------
 
 def test_discover_devices_timeout_counts_and_raises(reg, monkeypatch):
@@ -328,8 +328,18 @@ def test_discover_devices_timeout_counts_and_raises(reg, monkeypatch):
     c = reg.counter("tpu_device_probe_failures_total", "d")
     assert c.value() == 1
     assert reg.gauge("tpu_device_probe_ok", "d").value() == 0
-    # and device_count degrades to the single-chip default
-    assert mesh.device_count(timeout_s=0.2, default=1) == 1
+    # device_count raises too: it never answers "one chip" for a probe
+    # that failed, and the ICI planner lets that propagate
+    with pytest.raises(mesh.DeviceDiscoveryTimeout):
+        mesh.device_count(timeout_s=0.2)
+    from spark_rapids_tpu import config as cfg
+    from spark_rapids_tpu.parallel.ici_exec import install_ici_stages
+    monkeypatch.setenv("SPARK_RAPIDS_TPU_DEVICE_PROBE_TIMEOUT_S", "0.2")
+    ici = cfg.RapidsConf({"spark.rapids.shuffle.transport": "ici"})
+    with pytest.raises(mesh.DeviceDiscoveryTimeout):
+        install_ici_stages(object(), ici)
+    # other transports never probe
+    assert install_ici_stages("plan", cfg.RapidsConf({})) == "plan"
 
 
 def test_discover_devices_success_sets_probe_ok(reg):

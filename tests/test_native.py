@@ -136,3 +136,46 @@ def test_spill_uses_default_codec():
         assert not np.asarray(back.columns[0].data[:10_000]).any()
     finally:
         meta.set_default_codec("none")
+
+
+# -- the binary on disk is trusted by content, not by mtime -----------------
+
+def _src_sha():
+    from spark_rapids_tpu import native
+    return native._sha256(native._SRC)
+
+
+def test_stamp_ties_the_binary_to_its_source(tmp_path):
+    import shutil
+    from spark_rapids_tpu import native
+    assert get_lib() is not None
+    assert native._built_from(native._SO, _src_sha())
+    so = str(tmp_path / "libtpu_native.so")
+    shutil.copy(native._SO, so)
+    # a copied binary with no stamp, a stamp for another source, and a
+    # stamp whose binary was swapped: none is trusted, however new
+    assert not native._built_from(so, _src_sha())
+    shutil.copy(native._SO + ".sha256", so + ".sha256")
+    assert native._built_from(so, _src_sha())
+    assert not native._built_from(so, "0" * 64)
+    with open(so, "ab") as f:
+        f.write(b"\0")
+    assert not native._built_from(so, _src_sha())
+
+
+def test_no_compiler_is_the_zlib_mode_and_a_failed_compile_is_an_error(
+        monkeypatch, tmp_path):
+    import subprocess
+    from spark_rapids_tpu import native
+    monkeypatch.setattr(native, "_SO", str(tmp_path / "build" / "x.so"))
+    monkeypatch.setattr(native, "_user_cache_so",
+                        lambda: str(tmp_path / "cache" / "x.so"))
+    monkeypatch.setattr(native.shutil, "which", lambda name: None)
+    assert "no C++ compiler" in native._build()
+    monkeypatch.setattr(native.shutil, "which", lambda name: "/usr/bin/g++")
+    monkeypatch.setattr(
+        native.subprocess, "run",
+        lambda *a, **k: subprocess.CompletedProcess(a, 1, "", "boom"))
+    with pytest.raises(RuntimeError, match="native build failed: boom"):
+        native._build()
+    assert not list(tmp_path.rglob("*.tmp"))

@@ -1,13 +1,13 @@
-"""ICI mesh shuffle + distributed stage tests on the virtual 8-device
-CPU mesh (the hermetic stand-in the driver complements with
-__graft_entry__.dryrun_multichip)."""
+"""ICI mesh shuffle + distributed stage tests on the virtual 4-device
+CPU mesh of tests/conftest.py (`chip_smoke.py --chips 4` is the same
+mesh on real chips)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
 import pytest
-from spark_rapids_tpu.parallel.distributed import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from spark_rapids_tpu.parallel import (DistributedAggregate,
@@ -19,10 +19,13 @@ from spark_rapids_tpu.expr.core import AttributeReference as A
 from spark_rapids_tpu.expr.aggregates import (AggregateExpression, Average,
                                               Count, Sum)
 
-N_DEV = 8
+N_DEV = 4
+
+# every test here runs collectives across the mesh's device threads
+pytestmark = pytest.mark.time_limit(300)
 
 
-def mesh8():
+def the_mesh():
     assert len(jax.devices()) >= N_DEV
     return build_mesh(N_DEV)
 
@@ -35,7 +38,7 @@ def shard_tables(table, n=N_DEV):
 
 def run_exchange(table, pid_of_row):
     """Drive exchange_by_pid under shard_map; return per-device tables."""
-    mesh = mesh8()
+    mesh = the_mesh()
     tables = shard_tables(table)
     stacked = stack_shards(tables)
     # pids derive from a designated int column via a pure function
@@ -99,7 +102,7 @@ def test_exchange_carries_nulls_and_strings():
 def run_exchange_guarded(table, pid_of_row, slot):
     """exchange_by_pid with a sub-capacity slot under on_overflow='guard';
     returns (per-device tables, per-device ok bools)."""
-    mesh = mesh8()
+    mesh = the_mesh()
     stacked = stack_shards(shard_tables(table))
 
     def step(shard):
@@ -121,7 +124,7 @@ def test_exchange_guard_mode_clean_when_budget_fits():
     """A sub-capacity slot that every destination fits under must route
     all rows AND report ok=True on every shard (the speculative-sizing
     fast path: ~slot/capacity of the full exchange footprint)."""
-    n = 800  # 100 rows/shard; round-robin pids -> ~13 per destination
+    n = 100 * N_DEV  # 100 rows/shard; round-robin pids -> 25 per destination
     table = pa.table({
         "k": pa.array((np.arange(n) % N_DEV).astype(np.int64)),
         "v": pa.array(np.arange(n, dtype=np.int64)),
@@ -140,7 +143,7 @@ def test_exchange_guard_mode_flags_overflow():
     """A skewed destination that exceeds the slot budget must flip the
     sending shards' guard to False — the caller's signal to re-run at
     slot=capacity — never silently drop rows without a flag."""
-    n = 800  # every row targets device 0: 100 sends/shard > slot=32
+    n = 100 * N_DEV  # every row targets device 0: 100 sends/shard > slot=32
     table = pa.table({
         "k": pa.array(np.zeros(n, dtype=np.int64)),
         "v": pa.array(np.arange(n, dtype=np.int64)),
@@ -152,7 +155,7 @@ def test_exchange_guard_mode_flags_overflow():
 
 def test_allgather_broadcast():
     table = pa.table({"b": pa.array(np.arange(64, dtype=np.int64))})
-    mesh = mesh8()
+    mesh = the_mesh()
     stacked = stack_shards(shard_tables(table))
 
     def step(shard):
@@ -182,7 +185,7 @@ def test_distributed_aggregate_matches_single_host():
                     AggregateExpression(Count(None), "c")],
         in_names=["k", "v", "f"],
         in_types=_types(table),
-        mesh=mesh8())
+        mesh=the_mesh())
     got = dagg.run(shard_tables(table)).sort_by("k")
 
     import pyarrow.compute as pc
@@ -202,7 +205,7 @@ def test_distributed_global_aggregate():
     dagg = DistributedAggregate(
         grouping=[], aggregates=[AggregateExpression(Sum(A("v")), "sv"),
                                  AggregateExpression(Count(None), "c")],
-        in_names=["v"], in_types=_types(table), mesh=mesh8())
+        in_names=["v"], in_types=_types(table), mesh=the_mesh())
     got = dagg.run(shard_tables(table))
     assert got.num_rows == 1
     assert got.column("sv").to_pylist() == [n * (n - 1) // 2]
@@ -217,7 +220,7 @@ def test_distributed_exchange_partitions_by_key():
         "v": pa.array(rng.random(n)),
     })
     dx = DistributedExchange([A("k")], ["k", "v"], _types(table),
-                             mesh=mesh8())
+                             mesh=the_mesh())
     outs = dx.run(shard_tables(table))
     # same key never appears on two devices
     seen = {}
@@ -249,7 +252,7 @@ def test_distributed_sort_balances_shards():
                                                        unstack_shards)
     from spark_rapids_tpu.parallel.mesh import build_mesh
 
-    n_dev = 8
+    n_dev = N_DEV
     rng = np.random.default_rng(9)
     n = 4096
     vals = rng.integers(-10**6, 10**6, n).astype(np.int64)
@@ -339,3 +342,33 @@ def test_exchange_carries_arrays_and_maps():
                            table.column("a").to_pylist(),
                            table.column("m").to_pylist()), key=key)
     assert got_rows == want_rows
+
+
+def test_stack_shards_puts_each_shard_on_its_own_device():
+    """On real chips nothing may start on chip 0: shard i is uploaded
+    to mesh device i, and unstacking hands back each device's own
+    buffer (an index into the sharded array would run one SPMD gather,
+    collectives included, per shard)."""
+    n = 40 * N_DEV
+    table = pa.table({"k": pa.array(np.arange(n, dtype=np.int64)),
+                      "s": pa.array([f"s{i % 7}" * (1 + i % 3)
+                                     for i in range(n)])})
+    mesh = the_mesh()
+    stacked = stack_shards(shard_tables(table), mesh=mesh)
+    devices = list(mesh.devices.flat)
+    for leaf in jax.tree_util.tree_leaves(stacked):
+        assert leaf.shape[0] == N_DEV
+        assert [s.device for s in sorted(
+            leaf.addressable_shards,
+            key=lambda s: s.index[0].start)] == devices
+    shards = unstack_shards(stacked)
+    for i, b in enumerate(shards):
+        assert {d for leaf in jax.tree_util.tree_leaves(b)
+                for d in leaf.devices()} == {devices[i]}
+        assert batch_to_arrow(b).column("k").to_pylist() == \
+            list(range(40 * i, 40 * (i + 1)))
+    home = unstack_shards(stacked, device=devices[0])
+    assert {d for b in home for leaf in jax.tree_util.tree_leaves(b)
+            for d in leaf.devices()} == {devices[0]}
+    with pytest.raises(ValueError, match="shards for a"):
+        stack_shards(shard_tables(table)[:2], mesh=mesh)
