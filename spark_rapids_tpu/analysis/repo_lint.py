@@ -69,9 +69,10 @@ R006 = register_rule(
     "time.perf_counter/perf_counter_ns or jax.profiler.TraceAnnotation "
     "used directly in exec/, ops/, shuffle/ or parallel/: operator "
     "timing must route through MetricTimer (which owns the sanctioned "
-    "clock reads and the NVTX-analog annotation) or the obs/ flight "
-    "recorder, so the engine has ONE timing path that metrics, traces "
-    "and the self-emitted event log all agree on.")
+    "clock reads) or the obs/ tracer (which owns the one NVTX-analog "
+    "annotation, obs/tracer.open_range), so the engine has ONE timing "
+    "path that metrics, traces, the profiler's ranges and the "
+    "self-emitted event log all agree on.")
 
 R007 = register_rule(
     "TPU-R007", ERROR, "ad-hoc module-level metric tally",

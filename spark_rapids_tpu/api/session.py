@@ -405,6 +405,9 @@ class TpuSession:
             tenant=getattr(self, "_tenant", "") or "default",
             deadline_ms=deadline_ms)
         prog.bind_to_thread(handle)
+        # the profiler's root range: every range of this query nests in
+        # one that carries its id (the flight recorder has its own root)
+        root_range = obs.open_range(f"query:q{self._sql_counter}")
         try:
             if not tracing:
                 try:
@@ -447,6 +450,7 @@ class TpuSession:
         finally:
             prog.bind_to_thread(None)
             memprof.pop_context()
+            obs.close_range(root_range)
 
     def _execute_query(self, lp: L.LogicalPlan, tracer,
                        eventlog_dir) -> pa.Table:

@@ -362,14 +362,38 @@ def batch_to_device(rb: pa.RecordBatch,
                     row_buckets: Sequence[int] = DEFAULT_ROW_BUCKETS,
                     char_buckets: Sequence[int] = DEFAULT_CHAR_BUCKETS,
                     capacity: Optional[int] = None, xp=jnp) -> DeviceBatch:
-    """Upload an Arrow RecordBatch, padding to a capacity bucket."""
+    """Upload an Arrow RecordBatch, padding to a capacity bucket.
+
+    With ``xp=jnp`` this is the host->device crossing: the span
+    ``scan.upload`` and the counter ``tpu_upload_bytes_total`` (every
+    lane placed on the device, validity included).  The ``xp=np``
+    callers (UDF and CPU-engine paths) upload nothing and count
+    nothing."""
     n = rb.num_rows
     cap = capacity if capacity is not None else bucket_for(n, row_buckets)
-    cols = []
-    for i, f in enumerate(rb.schema):
-        dtype = from_arrow_type(f.type)
-        cols.append(column_to_device(rb.column(i), dtype, cap, char_buckets, xp))
-    return DeviceBatch(cols, n, names=rb.schema.names)
+
+    def place() -> DeviceBatch:
+        cols = []
+        for i, f in enumerate(rb.schema):
+            dtype = from_arrow_type(f.type)
+            cols.append(column_to_device(rb.column(i), dtype, cap,
+                                         char_buckets, xp))
+        return DeviceBatch(cols, n, names=rb.schema.names)
+
+    if xp is not jnp:
+        return place()
+    from ..obs import metrics as m
+    from ..obs.tracer import trace_span
+    with trace_span("scan.upload", rows=n) as sp:
+        batch = place()
+        nbytes = sum(int(leaf.nbytes)
+                     for leaf in jax.tree_util.tree_leaves(batch)
+                     if isinstance(leaf, jax.Array))
+        sp.set(bytes=nbytes)
+    m.counter("tpu_upload_bytes_total",
+              "bytes placed on the device by batch_to_device, validity "
+              "lanes included").inc(nbytes)
+    return batch
 
 
 # ---------------------------------------------------------------------------

@@ -33,6 +33,7 @@ buffers; Arrow conversion proceeds on host exactly as before.
 
 from __future__ import annotations
 
+import contextlib
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -48,20 +49,27 @@ def _is_device(x) -> bool:
     return isinstance(x, jax.Array)
 
 
-def _note_crossing(transfers: int, nbytes: int) -> None:
-    """Account one device->host fetch: continuous counters plus a
-    flight-recorder event — the per-query crossing count is a
-    DETERMINISTIC regression-watchdog field (obs/history.py), so every
-    sanctioned crossing must announce itself here."""
+@contextlib.contextmanager
+def _crossing():
+    """One device->host fetch, as the span ``fetch.crossing`` around the
+    blocking transfer, plus the continuous counters.  Yields a callback
+    for the bytes that came back.  The per-query crossing count is a
+    DETERMINISTIC regression-watchdog field (obs/history.py counts the
+    spans by name), so every sanctioned crossing must pass through
+    here; a transfer that raises is no crossing."""
     from ..obs import metrics as m
-    from ..obs.tracer import trace_event
+    from ..obs.tracer import trace_span
+    fetched: List[int] = []
+    with trace_span("fetch.crossing", transfers=1) as sp:
+        yield fetched.append
+        nbytes = sum(fetched)
+        sp.set(bytes=nbytes)
     m.counter("tpu_fetch_crossings_total",
               "device->host transfer round trips through the "
-              "sanctioned fetch path").inc(transfers)
+              "sanctioned fetch path").inc(1)
     m.counter("tpu_fetch_bytes_total",
               "bytes moved device->host through the sanctioned fetch "
               "path").inc(nbytes)
-    trace_event("fetch.crossing", transfers=transfers, bytes=nbytes)
 
 
 def fetch_ints(scalars: Sequence) -> List[int]:
@@ -83,8 +91,10 @@ def fetch_ints(scalars: Sequence) -> List[int]:
         else:
             out.append(int(s))
     if dev_vals:
-        fetched = np.asarray(jnp.stack(dev_vals))  # one transfer
-        _note_crossing(1, fetched.nbytes)
+        stacked = jnp.stack(dev_vals)
+        with _crossing() as got:
+            fetched = np.asarray(stacked)  # one transfer
+            got(fetched.nbytes)
         for i, v in zip(dev_idx, fetched):
             out[i] = int(v)
     return out  # type: ignore[return-value]
@@ -93,9 +103,11 @@ def fetch_ints(scalars: Sequence) -> List[int]:
 def fetch_array(x) -> np.ndarray:
     """Sanctioned single-transfer host materialization of one device
     array (e.g. the join count phase's stacked sizes vector)."""
-    out = np.asarray(x)
-    if _is_device(x):
-        _note_crossing(1, out.nbytes)
+    if not _is_device(x):
+        return np.asarray(x)
+    with _crossing() as got:
+        out = np.asarray(x)
+        got(out.nbytes)
     return out
 
 
@@ -535,13 +547,17 @@ def fetch_batch(batch: DeviceBatch,
                                                            s_plan))
         sizes_dev = sizes_fn(batch, extras_t)
         spec_out = spec_fn(batch)
-        fetched = jax.device_get((sizes_dev,) + tuple(spec_out))  # 1 sync
+        with _crossing() as got:
+            fetched = jax.device_get(
+                (sizes_dev,) + tuple(spec_out))  # 1 sync
+            got(sum(int(b.nbytes) for b in fetched))
         sizes = np.asarray(fetched[0])
         spec_bufs = fetched[1:]
-        _note_crossing(1, sum(int(b.nbytes) for b in fetched))
     else:
-        sizes = np.asarray(sizes_fn(batch, extras_t))  # round trip 1
-        _note_crossing(1, sizes.nbytes)
+        sizes_dev = sizes_fn(batch, extras_t)
+        with _crossing() as got:
+            sizes = np.asarray(sizes_dev)  # round trip 1
+            got(sizes.nbytes)
     extra_vals = sizes[len(sizes) - n_extra:] if n_extra else None
     if n_extra:
         sizes = sizes[:len(sizes) - n_extra]
@@ -580,8 +596,10 @@ def fetch_batch(batch: DeviceBatch,
         pack_fn = process_jit(("fetch_pack", skey, out_cap, vc, plan),
                               lambda: _make_shrink_pack_fn(out_cap, vc,
                                                            plan))
-        bufs = jax.device_get(pack_fn(batch))    # round trip 2 (one sync)
-        _note_crossing(1, sum(int(b.nbytes) for b in bufs))
+        packed = pack_fn(batch)
+        with _crossing() as got:
+            bufs = jax.device_get(packed)    # round trip 2 (one sync)
+            got(sum(int(b.nbytes) for b in bufs))
     this_plan = (out_cap, vc, plan)
     prev = _LAST_PLAN.get(pkey)
     if len(_LAST_PLAN) > 256 and pkey not in _LAST_PLAN:

@@ -589,9 +589,18 @@ JIT_PREWARM_BACKGROUND = conf(
 
 PROFILE_TRACE_ANNOTATIONS = conf(
     "spark.rapids.sql.profile.traceAnnotations").boolean() \
-    .doc("Wrap timed operator work in jax.profiler TraceAnnotation ranges "
-         "so device kernels correlate with operators in the TensorBoard "
-         "trace viewer (the NVTX-range analog, ref NvtxWithMetrics).") \
+    .doc("Put the engine's spans on the profiler's clock: one "
+         "jax.profiler TraceAnnotation range per query (query:q<n>), "
+         "session phase (phase:*), admission wait, operator pull "
+         "(<Exec>.pull), timed operator block (<Exec>.<metric>, e.g. "
+         "FilterExec.opTime), program dispatch and build "
+         "(jit.dispatch:<kind>, jit.build:<kind>), device-to-host fetch "
+         "(fetch.crossing), upload (scan.upload) and every other "
+         "obs/tracer span, so a jax.profiler trace shows them beside "
+         "the device's operations (the NVTX-range analog, ref "
+         "NvtxWithMetrics).  Host-side only: the programs are the "
+         "same.  The flight recorder (spark.rapids.tpu.trace.enabled) "
+         "records the same spans on the host clock.") \
     .create_with_default(False)
 
 METRICS_LEVEL = conf("spark.rapids.sql.metrics.level").string() \

@@ -31,26 +31,13 @@ import pyarrow as pa
 from .. import types as t
 from ..columnar.device import DeviceBatch, DeviceColumn, batch_to_arrow, batch_to_device
 from ..config import RapidsConf
+# the tracer's two sinks (flight recorder, profiler ranges) are opt-in;
+# with neither on every hook below is a module-attribute read + a None
+# check — cheap enough for the per-partition (never per-row) paths
+from ..obs import tracer as _obs
+from ..obs.tracer import set_trace_annotations  # noqa: F401  (re-export)
 
 Batch = DeviceBatch  # alias: same structure on both engines
-
-
-# ---------------------------------------------------------------------------
-# flight-recorder hooks (obs/tracer.py)
-# ---------------------------------------------------------------------------
-# The tracer is opt-in per query; with none installed every hook is one
-# module-attribute read + a None check — cheap enough to sit on the
-# per-partition (never per-row) paths.
-
-_obs_mod = None
-
-
-def _active_tracer():
-    global _obs_mod
-    if _obs_mod is None:
-        from ..obs import tracer as _t
-        _obs_mod = _t
-    return _obs_mod.active_tracer()
 
 
 # ---------------------------------------------------------------------------
@@ -319,13 +306,18 @@ class Metric:
     (every host<->device crossing is a sync that drains the dispatch
     pipeline)."""
 
-    __slots__ = ("name", "_value", "level", "_pending")
+    __slots__ = ("name", "_value", "level", "_pending", "owner")
 
-    def __init__(self, name: str, level: str = MODERATE):
+    def __init__(self, name: str, level: str = MODERATE,
+                 owner: Optional[str] = None):
         self.name = name
         self._value = 0
         self.level = level
         self._pending: list = []
+        # the operator that made it: MetricTimer's profiler range reads
+        # "<owner>.<name>" (FilterExec.opTime), so a trace says WHICH
+        # operator's timed block was open
+        self.owner = owner
 
     @property
     def value(self):
@@ -383,34 +375,20 @@ def maybe_sync(out) -> None:
         jax.block_until_ready(out)
 
 
-_trace_annotations_enabled = False
-
-
-def set_trace_annotations(enabled: bool) -> None:
-    """Toggle jax.profiler trace annotations around timed operator work —
-    the NVTX-range analog (ref NvtxWithMetrics.scala:22-49; ranges show
-    up in the TensorBoard/XPlane trace viewer instead of Nsight)."""
-    global _trace_annotations_enabled
-    _trace_annotations_enabled = enabled
-
-
 class MetricTimer:
-    """Times a block into a metric; optionally also opens a profiler
-    trace annotation of the same name (NvtxWithMetrics)."""
+    """Times a block into a metric; with trace annotations on it also
+    opens the profiler range ``<owner>.<metric>`` through the tracer's
+    sink (NvtxWithMetrics)."""
 
-    def __init__(self, metric: Metric, name: Optional[str] = None):
+    def __init__(self, metric: Metric):
         self.metric = metric
-        self.name = name
-        self._ann = None
 
     def __enter__(self):
-        if _trace_annotations_enabled:
-            from jax.profiler import TraceAnnotation
-            # tpulint: allow[TPU-R006] MetricTimer IS the sanctioned
-            # timing path; the annotation lives here so every operator
-            # shares one NVTX-analog range implementation
-            self._ann = TraceAnnotation(self.name or self.metric.name)
-            self._ann.__enter__()
+        self._ann = None
+        if _obs.ANNOTATIONS_ON:
+            m = self.metric
+            self._ann = _obs.open_range(
+                f"{m.owner}.{m.name}" if m.owner else m.name)
         # tpulint: allow[TPU-R006] the one sanctioned raw clock read
         self._t0 = time.perf_counter_ns()
         return self
@@ -418,9 +396,7 @@ class MetricTimer:
     def __exit__(self, *exc):
         # tpulint: allow[TPU-R006] the one sanctioned raw clock read
         self.metric.add(time.perf_counter_ns() - self._t0)
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
-            self._ann = None
+        _obs.close_range(self._ann)
 
 
 class SpeculativeSizingMiss(RuntimeError):
@@ -491,27 +467,38 @@ OP_TIME = "opTime"
 
 
 def _wrap_execute_partition(fn):
-    """Route every operator's execute_partition through the flight
-    recorder and the progress observatory: with a tracer installed the
+    """Route every operator's execute_partition through the tracer's
+    two sinks and the progress observatory: with a tracer installed the
     produced iterator is wrapped in a per-(operator, partition) span
-    recording batches/rows/bytes and the exception on failure; with a
-    progress handle bound to the thread the iterator also feeds the
-    live view (partitions done, rows so far) and observes the
-    cooperative cancel flag per batch.  The progress wrapper sits
-    INSIDE the tracer wrapper so a cancel raised between batches
-    propagates through trace_operator's error arm and closes the span
-    immediately.  Without either, the original generator is returned
-    untouched (two global reads per partition call)."""
+    recording batches/rows/bytes and the exception on failure; with
+    trace annotations on, each pull of it is a profiler range
+    ``<ExecClass>.pull``; with a progress handle bound to the thread
+    the iterator also feeds the live view (partitions done, rows so
+    far) and observes the cooperative cancel flag per batch.  The
+    progress wrapper sits INSIDE the tracer wrapper so a cancel raised
+    between batches propagates through trace_operator's error arm and
+    closes the span immediately.  Without any, the original generator
+    is returned untouched (three global reads per partition call).
+
+    This is also where a metric learns its operator: subclasses add
+    theirs (BUILD_TIME, ...) after Exec.__init__ ran, and every
+    operator passes through here before it times anything."""
     import functools
 
     @functools.wraps(fn)
     def wrapper(self, pid, ctx):
         from ..obs import progress as prog
-        tr = _active_tracer()
+        tr = _obs.active_tracer()
         inner = fn(self, pid, ctx)
         handle = prog.current_handle()
         if handle is not None:
             inner = handle.observe_operator(self, pid, inner)
+        if _obs.ANNOTATIONS_ON:
+            owner = type(self).__name__
+            for m in self.metrics.values():
+                if m.owner is None:
+                    m.owner = owner
+            inner = _obs.annotate_pulls(owner + ".pull", inner)
         if tr is None:
             return inner
         return tr.trace_operator(self, pid, inner)
@@ -544,10 +531,12 @@ class Exec:
 
     def __init__(self, children: Sequence["Exec"]):
         self.children: List[Exec] = list(children)
+        owner = type(self).__name__
         self.metrics: Dict[str, Metric] = {
-            NUM_OUTPUT_ROWS: Metric(NUM_OUTPUT_ROWS, ESSENTIAL),
-            NUM_OUTPUT_BATCHES: Metric(NUM_OUTPUT_BATCHES, MODERATE),
-            OP_TIME: Metric(OP_TIME, MODERATE),
+            NUM_OUTPUT_ROWS: Metric(NUM_OUTPUT_ROWS, ESSENTIAL, owner),
+            NUM_OUTPUT_BATCHES: Metric(NUM_OUTPUT_BATCHES, MODERATE,
+                                       owner),
+            OP_TIME: Metric(OP_TIME, MODERATE, owner),
         }
 
     # -- schema -------------------------------------------------------------

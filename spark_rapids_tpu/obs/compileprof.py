@@ -69,6 +69,8 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+from . import tracer as _tracer
+
 log = logging.getLogger("spark_rapids_tpu.obs.compileprof")
 
 LEDGER_FILENAME = "compile_ledger.jsonl"
@@ -162,6 +164,36 @@ def _exec_kind(key: tuple) -> str:
         if isinstance(part, str):
             return part
     return str(key[1])[:40] if len(key) > 1 else "?"
+
+
+#: the role strings call sites append to an operator's key: one
+#: operator, several programs (exec/aggregate.py, exec/join.py,
+#: exec/basic.py, parallel/distributed.py)
+_ROLES = frozenset((
+    "update", "merge", "merge_eval", "eval", "complete", "sortkeys",
+    "rowpos", "count", "expand", "unmatched", "spec"))
+
+
+def program_name(key: tuple) -> str:
+    """``<exec kind>[.<role>]`` for a process_jit key: the name the
+    program carries into XLA (module ``jit_<name>``), so a device trace
+    says which operator built it — and keeps saying so when an edit to
+    the program changes its fingerprint."""
+    kind = _exec_kind(key)
+    last = next((p for p in reversed(key) if isinstance(p, str)), kind)
+    return f"{kind}.{last}" if last in _ROLES else kind
+
+
+def _name_program(fn, key: tuple):
+    """Name `fn` after the operator that built it, before jax.jit reads
+    the name.  Call sites hand process_jit lambdas; a callable that
+    takes no name (a partial, a bound method) stays as it is."""
+    name = program_name(key)
+    try:
+        fn.__name__ = fn.__qualname__ = name
+    except (AttributeError, TypeError):
+        pass
+    return fn
 
 
 # ---------------------------------------------------------------------------
@@ -414,11 +446,11 @@ class CompileObservatory:
         every per-shape program build; disabled -> plain jax.jit plus
         the legacy untimed jit.build event."""
         import jax
-        fn = make_fn()
+        fn = _name_program(make_fn(), key)
         jitted = jax.jit(fn)
         if not self.enabled:
-            from .tracer import trace_event
-            trace_event("jit.build", sig=str(_exec_kind(key))[:80])
+            _tracer.trace_event("jit.build",
+                                sig=str(_exec_kind(key))[:80])
             return jitted
         return _ProfiledJit(self, key, jitted, fn)
 
@@ -485,7 +517,7 @@ class CompileObservatory:
         import jax
         if not self.enabled:
             return 0
-        jitted = jax.jit(fn)
+        jitted = jax.jit(_name_program(fn, key))
         proxy = _ProfiledJit(self, key, jitted, fn)
         n = 0
         for abstract in abstract_list:
@@ -634,8 +666,7 @@ class CompileObservatory:
             "caps": [list(s) for s in cap_sig],
             "canon_caps": [list(s) for s in canon_caps],
             "key_head": key_head})
-        from .tracer import trace_event
-        trace_event("jit.build", op=exec_kind, cause=cause,
+        _tracer.trace_event("jit.build", op=exec_kind, cause=cause,
                     key=key_hash, shape=shape_hash,
                     total_s=round(total_s, 6),
                     trace_s=None if trace_s is None
@@ -723,11 +754,11 @@ class _ProfiledJit:
             return self._traced_call(args)
         fn = self._compiled.get(sig)
         if fn is not None:
-            return fn(*args)
+            return self._dispatch(fn, args)
         if self._prewarmed and _on_default_device(sig):
             fn = self._prewarmed.get(_erase_sharding(sig))
             if fn is not None:
-                out = fn(*args)
+                out = self._dispatch(fn, args)
                 with self._lock:
                     self._compiled.setdefault(sig, fn)
                 self._obs.note_prewarm_hit(
@@ -736,6 +767,15 @@ class _ProfiledJit:
                      _shape_record(sig, self._obs.buckets)[0]))
                 return out
         return self._build_and_call(sig, args)
+
+    def _dispatch(self, fn, args):
+        """The call into the compiled executable, as the span
+        ``jit.dispatch:<exec kind>`` when either of the tracer's sinks
+        is on: the host's side of every program launch."""
+        if not _tracer.ANNOTATIONS_ON and _tracer.active_tracer() is None:
+            return fn(*args)
+        with _tracer.trace_span("jit.dispatch:" + self._exec):
+            return fn(*args)
 
     def _traced_call(self, args):
         """Plain-jit dispatch for tracer-leaf calls — but the first call
@@ -764,9 +804,13 @@ class _ProfiledJit:
         with self._lock:
             fn = self._compiled.get(sig)
             if fn is None:
-                fn = self._build(sig, args)
+                # lower + compile-or-cache-load as one span; the
+                # jit.build instant event record_build emits inside it
+                # carries the split timing for the flight recorder
+                with _tracer.trace_span("jit.build:" + self._exec):
+                    fn = self._build(sig, args)
                 self._compiled[sig] = fn
-        return fn(*args)
+        return self._dispatch(fn, args)
 
     def _build(self, sig, args):
         t0 = time.perf_counter()

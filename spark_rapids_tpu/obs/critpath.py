@@ -16,8 +16,10 @@ segment             booked from
 ``planning``        ``phase:plan`` / ``phase:planning`` /
                     ``phase:overrides`` / ``phase:subqueries`` /
                     ``phase:plan-retry`` / ``replan`` self-time
-``compile``         synthetic intervals reconstructed from enriched
-                    ``jit.build`` instant events (``total_s`` attr)
+``compile``         ``jit.build:<kind>`` spans (lower + compile or
+                    cache load), and inside them the synthetic
+                    intervals reconstructed from enriched ``jit.build``
+                    instant events (``total_s`` attr)
 ``prewarm``         same, when the build's ``cause`` is ``prewarm``
 ``host_assist``     ``phase:host_assist`` self-time (fetch crossings)
 ``compute:<Kind>``  operator-kind spans (``FilterExec`` etc.) self-time
@@ -81,6 +83,17 @@ _PLANNING_NAMES = frozenset((
 
 _OC_PREFIX = "oc."
 
+#: spans that detail their parent's own work (a program launch, a
+#: fetch's blocking transfer, an upload): they own no segment, their
+#: time stays the enclosing operator's or phase's self-time
+_DETAIL_NAMES = frozenset(("fetch.crossing", "scan.upload"))
+_DETAIL_PREFIX = "jit.dispatch:"
+_BUILD_PREFIX = "jit.build:"
+
+
+def _is_detail(name: str) -> bool:
+    return name in _DETAIL_NAMES or name.startswith(_DETAIL_PREFIX)
+
 
 def segment_of(span: dict) -> str:
     """Map one span dict to its latency segment.
@@ -101,6 +114,8 @@ def segment_of(span: dict) -> str:
     if name == "jit.build":  # synthetic compile interval (see below)
         attrs = span.get("attrs") or {}
         return SEG_PREWARM if attrs.get("cause") == "prewarm" else SEG_COMPILE
+    if name.startswith(_BUILD_PREFIX):
+        return SEG_COMPILE
     if name == "shuffle.map_write":
         return SEG_SHUFFLE_WRITE
     if name == "shuffle.fetch":
@@ -166,7 +181,8 @@ def extract_critical_path(spans: Sequence[dict],
     by_id: Dict[object, dict] = {}
     children: Dict[object, List[dict]] = {}
     for s in work:
-        if s.get("kind") == "event" or not s.get("durNs"):
+        if s.get("kind") == "event" or not s.get("durNs") or \
+                _is_detail(s.get("name", "")):
             continue  # instants and zero-length spans own no wall time
         s = dict(s)
         s["_t0"] = int(s.get("startNs", 0))

@@ -15,7 +15,7 @@ edits:
   recorder already rides) additionally routes each produced iterator
   through :meth:`_QueryHandle.observe_operator`, which notes operator
   starts, per-batch row counts, and partition completions;
-* **phase transitions** — ``QueryTrace.start`` notifies
+* **phase transitions** — ``tracer.trace_span`` notifies
   :func:`note_span_open` for ``phase:*`` and ``admission.wait`` spans,
   so the live view's ``phase`` tracks planning -> queued -> executing
   without the session narrating each step;
@@ -787,8 +787,9 @@ def current_token() -> Optional[CancelToken]:
 
 def note_span_open(name: str, kind: str) -> None:
     """Tracer hook: phase transitions for the live view.  Called by
-    ``QueryTrace.start`` for phase spans and ``admission.wait``; cheap
-    no-op for threads with no bound handle."""
+    ``tracer.trace_span`` for phase spans and ``admission.wait``,
+    whether or not a trace is recording; cheap no-op for threads with
+    no bound handle."""
     h = getattr(_TLS, "handle", None)
     if h is None:
         return

@@ -21,6 +21,13 @@ Instrumented modules reach the recorder through the installed-tracer
 pattern the tmsan shadow ledger uses (``memory/memsan.py``): with no
 query tracing, ``active_tracer()`` is None and every hook is a cheap
 no-op.
+
+``trace_span`` is ONE producer path with two sinks: the ``QueryTrace``
+above (host clock, ``spark.rapids.tpu.trace.enabled``) and the
+profiler's own clock (``spark.rapids.sql.profile.traceAnnotations``:
+one ``jax.profiler.TraceAnnotation`` per span, so a device trace shows
+engine phases, operators, dispatch and fetch beside the device's
+operations).  ``open_range`` is the engine's only ``TraceAnnotation``.
 """
 
 from __future__ import annotations
@@ -187,13 +194,6 @@ class QueryTrace:
                       node_id=node_id, pid=pid, attrs=dict(attrs))
             self.spans.append(sp)
             self._by_id[sid] = sp
-        # progress observatory phase feed — outside the span lock (the
-        # hook takes the tracker's own lock; never nest the two).
-        # Phase spans and the admission wait are the only names that
-        # move a query's live-view phase, so filter here on the hot path
-        if kind == PHASE or name == "admission.wait":
-            from . import progress as _progress
-            _progress.note_span_open(name, kind)
         return sid
 
     def end(self, sid: Optional[int], status: str = "ok",
@@ -570,16 +570,91 @@ def trace_event(name: str, **attrs) -> None:
         tr.event(name, **attrs)
 
 
+# ---------------------------------------------------------------------------
+# the profiler sink (spark.rapids.sql.profile.traceAnnotations)
+# ---------------------------------------------------------------------------
+
+#: read on the hot paths (one module-global read when off); set only
+#: through set_trace_annotations
+ANNOTATIONS_ON = False
+
+
+def set_trace_annotations(enabled: bool) -> None:
+    """Toggle the profiler sink: every span, operator pull, metric
+    timer, dispatch and fetch also opens a jax.profiler range of its
+    name — the NVTX-range analog (ref NvtxWithMetrics.scala:22-49;
+    ranges show up in the TensorBoard/XPlane trace viewer instead of
+    Nsight)."""
+    global ANNOTATIONS_ON
+    ANNOTATIONS_ON = bool(enabled)
+
+
+def open_range(name: str):
+    """An entered range of `name` on the profiler's clock, or None with
+    the switch off.  A range is thread-scoped: close it (close_range)
+    on the opening thread and never across a generator's ``yield``."""
+    if not ANNOTATIONS_ON:
+        return None
+    from jax.profiler import TraceAnnotation
+    ann = TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
+def close_range(ann) -> None:
+    if ann is not None:
+        ann.__exit__(None, None, None)
+
+
+def annotate_pulls(name: str, inner):
+    """Wrap an operator's iterator in one profiler range per pull —
+    around each ``next()``, never across a ``yield`` (a range
+    suspended inside a generator would break the thread's nesting;
+    ``QueryTrace.trace_operator`` pushes and pops per pull for the
+    same reason)."""
+    it = iter(inner)
+
+    def gen():
+        try:
+            while True:
+                ann = open_range(name)
+                try:
+                    b = next(it)
+                except StopIteration:
+                    return
+                finally:
+                    close_range(ann)
+                yield b
+        finally:
+            # an abandoned pull (early-exit limits) closes the operator's
+            # own generator as returning it untouched would have
+            close = getattr(it, "close", None)
+            if close is not None:
+                close()
+
+    return gen()
+
+
 @contextlib.contextmanager
 def trace_span(name: str, kind: str = SPAN, **attrs):
-    """Span context manager against the active trace; yields a handle
-    with ``.set(**attrs)`` (or an inert one when tracing is off)."""
-    tr = active_tracer()
-    if tr is None:
-        yield _SpanHandle_NULL
-        return
-    with tr.span(name, kind=kind, **attrs) as h:
-        yield h
+    """Span context manager: a profiler range when annotations are on,
+    a recorded span when a trace is active, and the live view's phase
+    feed for phase spans — with all of them off, an inert handle.
+    Yields a handle with ``.set(**attrs)``."""
+    ann = open_range(name)
+    try:
+        tr = active_tracer()
+        with (_NULL_SPAN if tr is None
+              else tr.span(name, kind=kind, **attrs)) as h:
+            # phase spans and the admission wait are the only names
+            # that move a query's live-view phase; called outside the
+            # span lock (the hook takes the tracker's own lock)
+            if kind == PHASE or name == "admission.wait":
+                from . import progress as _progress
+                _progress.note_span_open(name, kind)
+            yield h
+    finally:
+        close_range(ann)
 
 
 class _NullHandle:
@@ -593,3 +668,4 @@ class _NullHandle:
 
 
 _SpanHandle_NULL = _NullHandle()
+_NULL_SPAN = contextlib.nullcontext(_SpanHandle_NULL)

@@ -288,3 +288,114 @@ def test_prewarmed_programs_serve_only_the_default_device():
     assert _on_default_device(_dispatch_key((home, 3, np.float32(1))))
     assert not _on_default_device(_dispatch_key((home, away)))
     assert not _on_default_device(_dispatch_key((spread,)))
+
+
+# -- programs are named by the operator that built them ---------------------
+
+@pytest.fixture(scope="module")
+def built_programs():
+    """The programs the real call sites build for a filter + project +
+    group-by, on one partition (complete) and on several (update,
+    merge_eval, the exchange's map): {program name: [XLA module names of its executables]}."""
+    import pyarrow as pa
+    from spark_rapids_tpu.api import functions as F
+    from spark_rapids_tpu.api.column import col
+    from spark_rapids_tpu.api.session import TpuSession
+    from spark_rapids_tpu.obs.compileprof import _ProfiledJit, program_name
+    obs_metrics.MetricsRegistry.reset_for_tests()
+    CompileObservatory.reset_for_tests()
+    eb.clear_jit_cache()
+    s = (TpuSession.builder()
+         .config("spark.rapids.sql.enabled", True).get_or_create())
+    table = pa.table({"k": pa.array([i % 5 for i in range(400)]),
+                      "x": pa.array(range(400))})
+    for parts in (1, 4):
+        df = s.create_dataframe(table, num_partitions=parts)
+        out = (df.filter(col("x") > 10)
+               .select(col("k"), (col("x") * 2).alias("y"))
+               .group_by(col("k")).agg(F.sum(col("y")).alias("s"))
+               .collect())
+        assert out.num_rows == 5
+    found = {}
+    for key, proxy in eb._JIT_CACHE.items():
+        assert isinstance(proxy, _ProfiledJit)
+        modules = [c.as_text().split(",", 1)[0].split()[1]
+                   for c in proxy._compiled.values()]
+        found.setdefault(program_name(key), []).extend(modules)
+    yield found
+    eb.clear_jit_cache()
+    CompileObservatory.reset_for_tests()
+    obs_metrics.MetricsRegistry.reset_for_tests()
+
+
+@pytest.mark.parametrize("name", [
+    "FilterExec", "ProjectExec", "TpuHashAggregateExec.update",
+    "TpuHashAggregateExec.merge_eval", "TpuHashAggregateExec.complete",
+    "ShuffleExchangeExec", "fetch_sizes", "fetch_pack"])
+def test_call_site_lowers_to_a_module_named_by_its_operator(
+        built_programs, name):
+    assert name in built_programs, sorted(built_programs)
+    modules = built_programs[name]
+    assert modules and set(modules) == {"jit_" + name}
+
+
+def test_no_program_of_the_main_path_is_a_lambda(built_programs):
+    for name, modules in built_programs.items():
+        assert "lambda" not in name
+        assert all("lambda" not in m for m in modules), (name, modules)
+
+
+@pytest.mark.parametrize("key,name", [
+    (("3.2.0", "FilterExec", (("x", "long"),), ("gt",)), "FilterExec"),
+    (("3.2.0", "FilterExec", (("x", "long"),), ("gt",), "rowpos"),
+     "FilterExec.rowpos"),
+    (("3.2.0", "TpuHashAggregateExec", "complete", False, (), "update"),
+     "TpuHashAggregateExec.update"),
+    (("3.2.0", "TpuHashAggregateExec", "final", True, (), "merge"),
+     "TpuHashAggregateExec.merge"),
+    (("3.2.0", "TpuHashAggregateExec", "final", True, (), "eval"),
+     "TpuHashAggregateExec.eval"),
+    (("3.2.0", "TpuHashAggregateExec", "final", True, (), "sortkeys"),
+     "TpuHashAggregateExec.sortkeys"),
+    (("3.2.0", "HashJoinExec", "inner", (), "expand", 1024, (), ()),
+     "HashJoinExec.expand"),
+    (("3.2.0", "HashJoinExec", "inner", (), "spec", 4096),
+     "HashJoinExec.spec"),
+    (("3.2.0", "fetch_pack", (("x", "long"),), 1024, (), ()),
+     "fetch_pack"),
+    (("3.2.0", "DistributedAggregate", "data", (0, 1)),
+     "DistributedAggregate"),
+])
+def test_program_name_is_kind_and_trailing_role(key, name):
+    from spark_rapids_tpu.obs.compileprof import program_name
+    assert program_name(key) == name
+
+
+def test_a_rename_leaves_the_keys_as_they_were(obs):
+    """The function's name is in no key: process_jit's table key, the
+    ledger's key hash and the canonical key are what they were for an
+    unnamed lambda, whatever the function was called."""
+    from spark_rapids_tpu.obs.compileprof import _stable_hash
+    from spark_rapids_tpu.shims import active_shim
+    tail = ("ProbeExec", ("x", "int"), "update")
+
+    def first(x):
+        return x + 1
+
+    f = eb.process_jit(tail, lambda: first)
+    full = (active_shim().version,) + tail
+    assert list(eb._JIT_CACHE) == [full]
+    assert f._key_hash == _stable_hash(full)
+    canon = f._canon_key
+    assert first.__name__ == first.__qualname__ == "ProbeExec.update"
+    assert f._jitted.lower(jnp.zeros(8, jnp.int32)).as_text() \
+        .startswith("module @jit_ProbeExec.update ")
+    eb.clear_jit_cache()
+    g = eb.process_jit(tail, lambda: (lambda x: x + 1))
+    assert list(eb._JIT_CACHE) == [full]
+    assert (g._key_hash, g._canon_key) == (f._key_hash, canon)
+    # a callable that takes no name is built as it is
+    import functools
+    h = eb.process_jit(("ProbeExec", "partial"),
+                       lambda: functools.partial(first, 1))
+    assert int(h()) == 2

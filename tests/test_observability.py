@@ -5,6 +5,7 @@ api_validation/)."""
 import os
 
 import pyarrow as pa
+import pytest
 
 from spark_rapids_tpu import config as cfg
 from spark_rapids_tpu.api import functions as F
@@ -178,3 +179,201 @@ def test_generated_docs_cover_observability():
     assert "spark.rapids.tpu.trace.enabled" in text
     from spark_rapids_tpu.docsgen import generate_lint_rules
     assert "TPU-R006" in generate_lint_rules()
+
+
+# ---------------------------------------------------------------------------
+# one span path, two sinks: the flight recorder and the profiler's clock
+# (spark.rapids.sql.profile.traceAnnotations)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture()
+def annotations_off_after():
+    yield
+    from spark_rapids_tpu.exec.base import set_trace_annotations
+    set_trace_annotations(False)
+
+
+def _group_by_query(s, n=2000):
+    df = s.create_dataframe(pa.table({
+        "k": pa.array([i % 7 for i in range(n)]),
+        "x": pa.array(range(n))}))
+    return lambda: (df.filter(col("x") > 100).group_by(col("k"))
+                    .agg(F.sum(col("x")).alias("s")).collect())
+
+
+def _host_spans_under_profiler(run, tmp_path):
+    """Run `run` under jax.profiler; the /host:CPU events of the trace
+    as {line name: [(name, start_ns, end_ns)]}."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        out = run()
+    finally:
+        jax.profiler.stop_trace()
+    files = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    assert len(files) == 1
+    lines = {}
+    for plane in ProfileData.from_file(files[0]).planes:
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                lines[line.name] = [
+                    (e.name, e.start_ns, e.start_ns + e.duration_ns)
+                    for e in line.events]
+    return out, lines
+
+
+def test_engine_spans_nest_in_the_query_range_on_the_profilers_clock(
+        tmp_path, annotations_off_after):
+    s = (TpuSession.builder()
+         .config("spark.rapids.sql.enabled", True)
+         .config("spark.rapids.sql.profile.traceAnnotations", True)
+         .get_or_create())
+    run = _group_by_query(s)
+    run()   # programs built outside the profile
+    out, lines = _host_spans_under_profiler(run, tmp_path)
+    assert out.num_rows == 7
+    # the client thread's line is the one that holds the root range
+    mine = [evs for evs in lines.values()
+            if any(n.startswith("query:q") for n, _, _ in evs)]
+    assert len(mine) == 1
+    events = mine[0]
+    roots = [(a, b) for n, a, b in events if n.startswith("query:q")]
+    assert len(roots) == 1
+    names = {n for n, _, _ in events}
+    wanted = {"phase:plan", "phase:execute", "FilterExec.opTime",
+              "TpuHashAggregateExec.opTime", "FilterExec.pull",
+              "TpuHashAggregateExec.pull", "DeviceToHostExec.pull",
+              "jit.dispatch:FilterExec",
+              "jit.dispatch:TpuHashAggregateExec", "fetch.crossing"}
+    assert wanted <= names, wanted - names
+    # no range is named by the metric alone
+    assert "opTime" not in names
+    assert not any(n.endswith("Time") and "." not in n for n in names)
+    lo, hi = roots[0]
+    engine = [(n, a, b) for n, a, b in events
+              if n in wanted or n.startswith(("phase:", "jit.dispatch:"))
+              or n.endswith(".pull")]
+    assert engine
+    for n, a, b in engine:
+        assert lo <= a and b <= hi, f"{n} lies outside its query's range"
+    # a pull encloses the operator's timed block and its dispatch
+    pulls = [(a, b) for n, a, b in events if n == "FilterExec.pull"]
+    for inner in ("FilterExec.opTime", "jit.dispatch:FilterExec"):
+        a, b = next((a, b) for n, a, b in events if n == inner)
+        assert any(pa_ <= a and b <= pb for pa_, pb in pulls)
+
+
+def test_recorder_and_profiler_hold_the_same_spans(tmp_path,
+                                                   annotations_off_after):
+    """One producer path, two sinks: with both on, every span the
+    flight recorder holds is a range of the same name in the profile
+    (its root and its per-partition operator spans are `query:q<n>` and
+    per-pull `<Exec>.pull` there), and every trace_span range of the
+    profile is a recorded span."""
+    s = _traced_session(**{
+        "spark.rapids.sql.profile.traceAnnotations": True})
+    run = _group_by_query(s)
+    run()
+    _, lines = _host_spans_under_profiler(run, tmp_path)
+    profiled = {n for evs in lines.values() for n, _, _ in evs}
+    tr = s.last_query_trace()
+    recorded = {sp.name for sp in tr.spans
+                if sp.kind not in ("event", "query", "operator")}
+    assert {"phase:plan", "phase:execute", "fetch.crossing",
+            "jit.dispatch:FilterExec"} <= recorded
+    assert recorded <= profiled, recorded - profiled
+    for sp in tr.spans:
+        if sp.kind == "operator":
+            assert sp.name.endswith(".execute")
+            assert sp.name[:-len("execute")] + "pull" in profiled
+    assert any(n.startswith("query:q") for n in profiled)
+    span_like = {n for n in profiled
+                 if n.startswith(("phase:", "jit.dispatch:", "jit.build:"))
+                 or n in ("fetch.crossing", "scan.upload",
+                          "admission.wait")}
+    assert span_like <= recorded, span_like - recorded
+
+
+def test_both_sinks_off_costs_no_range_and_still_moves_the_live_phase(
+        monkeypatch):
+    import inspect
+    import jax.profiler
+    from spark_rapids_tpu.exec.base import ExecContext
+    from spark_rapids_tpu.exec.basic import FilterExec
+    from spark_rapids_tpu.obs import progress, tracer
+
+    made = []
+
+    class Counting(jax.profiler.TraceAnnotation):
+        def __init__(self, *a, **kw):
+            made.append(a)
+            super().__init__(*a, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    phases = []
+    real = progress._QueryHandle.set_phase
+    monkeypatch.setattr(
+        progress._QueryHandle, "set_phase",
+        lambda self, phase: (phases.append(phase), real(self, phase))[1])
+    s = (TpuSession.builder()
+         .config("spark.rapids.sql.enabled", True).get_or_create())
+    assert not tracer.ANNOTATIONS_ON and tracer.active_tracer() is None
+    out = _group_by_query(s)()
+    assert out.num_rows == 7
+    assert made == []
+    # the repair: with no QueryTrace the live view used to stay in
+    # `starting` until the query finished
+    assert progress.PHASE_PLANNING in phases
+    assert progress.PHASE_EXECUTING in phases
+    assert phases.index(progress.PHASE_PLANNING) < \
+        phases.index(progress.PHASE_EXECUTING)
+    # and an operator hands back its own generator, unwrapped
+    node = next(e for e in _plan_nodes(s.last_plan)
+                if isinstance(e, FilterExec))
+    it = node.execute_partition(0, ExecContext(s.conf))
+    assert inspect.isgenerator(it)
+    assert it.gi_code is FilterExec.execute_partition.__wrapped__.__code__
+    it.close()
+    # with the switch on the same call is wrapped, one range a pull
+    tracer.set_trace_annotations(True)
+    try:
+        wrapped = node.execute_partition(0, ExecContext(s.conf))
+        assert wrapped.gi_code is not it.gi_code
+        assert len(list(wrapped)) >= 1
+        assert [a[0] for a in made].count("FilterExec.pull") >= 2
+    finally:
+        tracer.set_trace_annotations(False)
+
+
+def _plan_nodes(plan):
+    out = []
+    plan.foreach(out.append)
+    return out
+
+
+def test_upload_counter_counts_the_lanes_once(annotations_off_after):
+    """tpu_upload_bytes_total: every lane batch_to_device places on the
+    device, validity included; the pin cache makes the second call
+    upload nothing."""
+    from spark_rapids_tpu.columnar.device import (DEFAULT_ROW_BUCKETS,
+                                                  bucket_for)
+    from spark_rapids_tpu.obs import metrics as m
+    s = (TpuSession.builder()
+         .config("spark.rapids.sql.enabled", True).get_or_create())
+    n = 3000
+    run = _group_by_query(s, n)
+    fam = m.counter("tpu_upload_bytes_total")
+    before = fam.total()
+    run()
+    first = fam.total() - before
+    cap = bucket_for(n, DEFAULT_ROW_BUCKETS)
+    # two int64 columns, a data lane and a validity lane each
+    assert first == 2 * (cap * 8 + cap)
+    run()
+    assert fam.total() - before == first
