@@ -186,8 +186,13 @@ def verify_gates() -> List[Tuple[str, str, t.DataType]]:
 
 DEVICE_KERNELS: Dict[str, Dict[str, str]] = {
     "ops/carry.py": {
-        "sort_rows": "multi-operand stable carry sort (lax.sort); host "
-                     "path uses np.argsort + gather instead",
+        "sort_rows": "rows moved by lax.sort (lean: a pass per 32-bit "
+                     "word keyed by the rank; else one multi-operand "
+                     "stable sort); host path uses np.lexsort + fancy "
+                     "indexing instead",
+        "move_lanes": "a lane put in place by one 2-operand (uint32, "
+                      "int32) lax.sort keyed by the destination rank; "
+                      "the host path assigns through the rank",
         "lean_argsort": "compile-lean radix argsort: every pass is one "
                         "2-operand (uint32, int32) lax.sort",
         "stable_argsort": "the device argsort in the session's sort "

@@ -53,30 +53,34 @@ def _group_reduce(xp, key_cols: List[DeviceColumn],
     """Core sort+segment kernel.  Returns (out_key_cols, out_value_cols,
     num_groups).
 
-    Round-4 kernel structure (see ops/carry.py docstring for the chip
+    Kernel structure (see ops/carry.py docstring for the chip
     measurements behind it):
 
-      1. ONE stable carry-sort by the key words — every flat lane of the
-         key and value columns rides the sort as a payload operand, so no
-         post-sort row gathers.
+      1. ONE stable sort by the key words.  Every flat lane of the key
+         and value columns is moved by `carry.sort_rows`: a sort pass per
+         32-bit word keyed by the row's rank (lean mode), or a payload
+         operand of the one sort; never a gather by the order.
       2. Per sum/count: a Hillis-Steele prefix scan + elementwise
          exclusive value — the per-segment total is the difference of the
          exclusive scan at consecutive segment starts.  No 64-bit
          scatters anywhere; float sums scan finite values only and
          rebuild IEEE inf/nan from per-segment special-value counts.
-      3. ONE carry-compaction-sort moves the boundary rows (and all
-         per-op scan lanes + flat key lanes) to the slot positions.
+      3. ONE compaction (`carry.compact_rows`) moves the boundary rows
+         (and all per-op scan lanes + flat key lanes) to the slot
+         positions.
       4. min/max/first/last use int32 scatter tournaments + one row
          gather; variable-width columns keep the gather-based paths.
     """
     from ..ops import carry
     # --- sort keys, carrying all row data -----------------------------------
-    words: List = [(~live).astype(xp.uint8)]  # padding rows sort last
+    words: List = [~live]  # padding rows sort last
     for kc in key_cols:
         words += seg.key_words_for_column(xp, kc, live, for_grouping=True)
     all_cols = list(key_cols) + list(value_cols)
+    # only the merge of collected arrays reads the order itself
     order, sorted_cols, ex = carry.sort_rows(
-        xp, words, all_cols, cap, extras=[live] + words[1:])
+        xp, words, all_cols, cap, extras=[live] + words[1:],
+        need_order=any(op.startswith("collect_concat") for op in ops))
     key_sorted = sorted_cols[:len(key_cols)]
     val_sorted = sorted_cols[len(key_cols):]
     live_sorted = ex[0]
@@ -93,7 +97,7 @@ def _group_reduce(xp, key_cols: List[DeviceColumn],
     slot_valid = iota_slots < num_groups
 
     # --- deferred scan lanes (compacted once, below) ------------------------
-    lanes: List = [iota_slots]        # lane 0 -> first row index per slot
+    lanes: List = []
     lane_pos: dict = {}
 
     def enlane(a) -> int:
@@ -230,17 +234,17 @@ def _group_reduce(xp, key_cols: List[DeviceColumn],
     # --- flat key lanes join the compaction ---------------------------------
     import jax
     key_plans = []
+    first_lane = None       # the first row index per slot, for span keys
     for ks in key_sorted:
         if carry.carriable(ks):
             leaves, treedef = jax.tree_util.tree_flatten(ks)
             key_plans.append((treedef, [enlane(l) for l in leaves]))
         else:
             key_plans.append((None, None))
+            first_lane = enlane(iota_slots)
 
     # --- ONE compaction: boundary rows -> slot positions --------------------
-    ckey = (~new_group).astype(xp.uint8)
-    _, comp = carry.sort_lanes(xp, [ckey], lanes, cap)
-    first_idx = xp.clip(comp[0], 0, cap - 1).astype(xp.int32)
+    _, _, comp = carry.compact_rows(xp, new_group, (), cap, extras=lanes)
 
     def span_next(lane_idx, total):
         """Per-slot value from the NEXT slot's compacted lane entry; the
@@ -288,7 +292,9 @@ def _group_reduce(xp, key_cols: List[DeviceColumn],
     out_keys = []
     for ks, (treedef, lidx) in zip(key_sorted, key_plans):
         if treedef is None:
-            out_keys.append(gather_column(xp, ks, first_idx, slot_valid))
+            first_idx = xp.clip(comp[first_lane], 0, cap - 1)
+            out_keys.append(gather_column(xp, ks, first_idx.astype(xp.int32),
+                                          slot_valid))
         else:
             col = jax.tree_util.tree_unflatten(
                 treedef, [comp[i] for i in lidx])

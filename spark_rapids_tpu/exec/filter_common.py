@@ -1,8 +1,9 @@
 """Filter compaction shared by FilterExec / conditional joins / having.
 
-Static-shape compaction: stable-sort rows on the (negated) keep flag so
-survivors move to the front in original order, then gather every column.
-One lax.sort + gathers — no dynamic shapes, no host sync.
+Static-shape compaction: a stable partition on the keep flag, so survivors
+move to the front in original order.  Each row's place is a prefix sum of
+the flags, and every lane goes there by a sort pass (ops/carry.py):
+no gather by an order, no dynamic shapes, no host sync.
 """
 
 from __future__ import annotations
@@ -29,9 +30,9 @@ def keep_flags(xp, batch: DeviceBatch, pred_value):
 
 
 def compact(xp, batch: DeviceBatch, keep, names):
-    """Move kept rows to the front (stable), shrink num_rows.  One
-    carry-sort on the keep flag; dropped rows become padding (validity
-    masked off per the batch contract)."""
+    """Move kept rows to the front (stable), shrink num_rows
+    (`carry.compact_rows`); dropped rows become padding (validity masked
+    off per the batch contract)."""
     from ..ops.carry import compact_rows, mask_validity
     cap = batch.capacity
     new_n = xp.sum(keep.astype(np.int32))

@@ -65,7 +65,7 @@ class SortExec(Exec):
     def _sort_batch(self, xp, batch: Batch) -> Batch:
         ctx = EvalContext(xp, batch)
         live = ctx.row_mask()
-        words: List = [(~live).astype(xp.uint8)]  # padding last
+        words: List = [~live]  # padding last
         for e, asc, nulls_first in self._bound:
             v = e.eval(ctx)
             from ..expr.core import ColumnValue, make_column
@@ -78,7 +78,7 @@ class SortExec(Exec):
                 nulls_first=nulls_first, ascending=asc)
         from ..ops import carry
         _, cols, _ = carry.sort_rows(xp, words, batch.columns,
-                                     batch.capacity)
+                                     batch.capacity, need_order=False)
         return DeviceBatch(cols, batch.num_rows, batch.names)
 
     @functools.cached_property

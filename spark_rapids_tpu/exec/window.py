@@ -129,7 +129,7 @@ class WindowExec(Exec):
                  for p in spec.partition_by]
         okeys = [(bind_expression(o, cn, ct).eval(ctx).col, asc, nf)
                  for o, asc, nf in spec.order_by]
-        words = [(~live).astype(xp.uint8)]
+        words = [~live]
         pwords: List = []
         for pk in pkeys:
             pwords += seg.key_words_for_column(xp, pk, live,
@@ -515,12 +515,16 @@ class WindowExec(Exec):
                 per.append((w, res[1], res[2]))
             if not per:
                 continue
-            # ONE carry-sort back to input order for the whole group
-            back_key = lay.order.astype(xp.uint32)
+            # ONE move back to input order for the whole group: the
+            # layout's order is where each sorted row came from
             flat: List = []
             for _, d, v in per:
                 flat += [d, v]
-            _, back = carry.sort_lanes(xp, [back_key], flat, cap)
+            if xp is np or carry.compile_lean_enabled():
+                back = carry.move_lanes(xp, lay.order, flat)
+            else:
+                _, back = carry.sort_lanes(
+                    xp, [lay.order.astype(xp.uint32)], flat, cap)
             for i, (w, _, _) in enumerate(per):
                 d, v = back[2 * i], back[2 * i + 1]
                 out_dtype = w.resolved_type(cn, ct)

@@ -603,8 +603,15 @@ class CompileObservatory:
                      compile_s: Optional[float], total_s: float,
                      hlo_bytes: int, key_head: str,
                      hlo_hash: Optional[str] = None,
-                     cost: Optional[Dict[str, float]] = None) -> str:
-        """Register one program build; returns the classified cause."""
+                     cost: Optional[Dict[str, float]] = None,
+                     lane_moves: Optional[Dict[str, int]] = None
+                     ) -> str:
+        """Register one program build; returns the classified cause.
+        `lane_moves` is what tracing the program raised of
+        `ops/carry.lane_move_counts` (lane_moves_sorted,
+        lane_moves_gathered, sort_passes); None where the trace was not
+        this build's own."""
+        moves = lane_moves or {}
         shape_hash, dtype_sig, cap_sig, canon_caps = \
             _shape_record(sig, self.buckets)
         dtype_hash = _stable_hash(dtype_sig)
@@ -621,7 +628,7 @@ class CompileObservatory:
             self._programs[pid] = {
                 "exec": exec_kind, "key": key_hash,
                 "canon_key": canon_key, "shape": shape_hash,
-                "cause": cause, "total_s": total_s}
+                "cause": cause, "total_s": total_s, **moves}
             self._resident.add(pid)
             self._evicted.discard(pid)
             self._evicted_live.discard(pid)
@@ -665,7 +672,7 @@ class CompileObservatory:
             "dtypes": list(dtype_sig),
             "caps": [list(s) for s in cap_sig],
             "canon_caps": [list(s) for s in canon_caps],
-            "key_head": key_head})
+            "key_head": key_head, **moves})
         _tracer.trace_event("jit.build", op=exec_kind, cause=cause,
                     key=key_hash, shape=shape_hash,
                     total_s=round(total_s, 6),
@@ -673,7 +680,7 @@ class CompileObservatory:
                     else round(trace_s, 6),
                     compile_s=None if compile_s is None
                     else round(compile_s, 6),
-                    hlo_bytes=hlo_bytes, sig=key_head)
+                    hlo_bytes=hlo_bytes, sig=key_head, **moves)
         return cause
 
     def _append_ledger(self, rec: Dict) -> None:
@@ -708,6 +715,10 @@ class CompileObservatory:
                 "prewarm_seconds": round(self.prewarm_seconds, 6),
                 "prewarm": dict(self.prewarm_stats)
                 if self.prewarm_stats else None,
+                # one record a program (exec kind, hashes, cause,
+                # seconds, and how its rows move: lane_moves_sorted,
+                # lane_moves_gathered, sort_passes)
+                "programs": [dict(p) for p in self._programs.values()],
             }
 
 
@@ -807,18 +818,23 @@ class _ProfiledJit:
                 # lower + compile-or-cache-load as one span; the
                 # jit.build instant event record_build emits inside it
                 # carries the split timing for the flight recorder
-                with _tracer.trace_span("jit.build:" + self._exec):
-                    fn = self._build(sig, args)
+                with _tracer.trace_span("jit.build:" + self._exec) as span:
+                    fn = self._build(sig, args, span)
                 self._compiled[sig] = fn
         return self._dispatch(fn, args)
 
-    def _build(self, sig, args):
+    def _build(self, sig, args, span):
+        from ..ops.carry import lane_move_counts
         t0 = time.perf_counter()
         hlo_bytes = 0
         hlo_hash = None
         # a lower or compile failure is the compiler's refusal of this
         # program: it surfaces here, once, with its message
+        before = lane_move_counts()
         lowered = self._jitted.lower(*args)
+        lane_moves = {k: v - before[k]
+                      for k, v in lane_move_counts().items()}
+        span.set(**lane_moves)
         t1 = time.perf_counter()
         trace_s = t1 - t0
         try:
@@ -837,7 +853,7 @@ class _ProfiledJit:
                                self._canon_key, sig, trace_s,
                                compile_s, total_s, hlo_bytes,
                                self._key_head, hlo_hash=hlo_hash,
-                               cost=cost)
+                               cost=cost, lane_moves=lane_moves)
         return fn
 
 

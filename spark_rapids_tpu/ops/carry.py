@@ -1,39 +1,45 @@
-"""Carry-sorts: permute whole rows through lax.sort payload operands.
+"""Row permutations on the device: every lane move is a sort pass.
 
-Profiling the chip (round 4) showed a 1M-row gather costs ~20ms (~400MB/s
-— XLA TPU gather is row-at-a-time) while adding payload operands to an
-existing lax.sort is unmeasurable at the dispatch floor.  So every
-sort-then-permute path in the engine (filter compaction, sort exec,
-group-by, window ordering) carries its row data THROUGH the sort instead
-of gathering afterwards.  Columns with span structure (strings, arrays,
-maps — anything with offsets) cannot ride a row permutation and fall back
-to gather_column on the carried iota.
+A gather is row-at-a-time on this chip and a sort is not: a 33,554,432-row
+XLA gather of one 32-bit lane took 0.93 s (0.15 GB/s, 1/5000 of the HBM
+peak; ledger, PR 25) where a sort pass over the same rows takes a tenth
+of it (PERF.md, PR 26).  So every sort-then-permute path in the engine
+(filter compaction, sort exec, group-by, window ordering) moves its row
+data with `lax.sort`, never with `x[order]`.  Columns with span structure
+(strings, arrays, maps: anything with offsets) cannot ride a row
+permutation and keep `gather_column`.
+
+Two kernel structures do that, chosen by spark.rapids.tpu.sort.compileLean:
+
+* on (the default), compile-lean.  The TPU compiler's time for a
+  `lax.sort` is set by the sort's signature, not by how often a program
+  repeats it (asked of the v5e compiler at 4,194,304 rows, PR 21: one
+  stable (uint64, int32) sort 87 s, three of them in one program 90 s; a
+  stable 2-key sort with two more payload operands 320 s; an unstable
+  2-key (uint32, int32) sort 23 s).  Every device sort is therefore a
+  pass of that ONE cheapest signature (`_sort_pass`), whose two operands
+  are both keys and always unique, so that it carries no payload:
+    - `_lean_perm` finds a sort's order and its inverse, the rank, from
+      passes over (key digit, tie-break);
+    - `move_lanes` puts a 32-bit word of row data in its new place with
+      one pass keyed by the rank: `sort((rank, x))[1]` is `x[order]`, bit
+      for bit.  64-bit lanes are two words, up to 32 bool lanes one;
+    - a stable partition (`compact_rows`, a sort by one bool word) has
+      its rank in closed form from a prefix sum, and sorts nothing.
+* off: payloads ride ONE stable multi-operand `lax.sort` as operands.
 
 The numpy engine mirrors the semantics with fancy indexing per lane.
 """
 
 from __future__ import annotations
 
+import threading
 from typing import List, Sequence, Tuple
 
 import numpy as np
 
 from ..columnar.device import DeviceColumn
 from .gather import gather_column
-
-# ---------------------------------------------------------------------------
-# Compile-lean mode
-# ---------------------------------------------------------------------------
-# The TPU compiler's time for a `lax.sort` is set by the sort's signature,
-# not by how often a program repeats it (asked of the v5e compiler at
-# 4,194,304 rows, PR 21: one stable (uint64, int32) sort 87 s, three of
-# them in one program 90 s; a stable 2-key sort with two more payload
-# operands 320 s; an unstable 2-key (uint32, int32) sort 23 s).  In lean
-# mode every device sort in the engine is therefore built from passes of
-# that ONE cheapest signature: `lean_argsort` below.  Gathers then move
-# the payload, so warm cost rises by a gather per lane and per pass; what
-# that costs on the chip has not been measured on this code.  The session
-# picks the mode from spark.rapids.tpu.sort.compileLean.
 
 _LEAN = True
 
@@ -47,38 +53,277 @@ def compile_lean_enabled() -> bool:
     return _LEAN
 
 
-def _u32_digits(xp, w) -> list:
-    """The order-preserving uint32 digits of one integer key word, least
-    significant first: one for words of up to 32 bits, two for 64."""
+# ---------------------------------------------------------------------------
+# What a program's build moved, and how (read by obs/compileprof)
+# ---------------------------------------------------------------------------
+
+class _MoveCounts(threading.local):
+    sorted = 0        # lanes moved by sort passes
+    gathered = 0      # lanes of span columns moved by gather_column
+    passes = 0        # every `_sort_pass`, the orders' own included
+
+
+_COUNTS = _MoveCounts()
+
+
+def lane_move_counts() -> dict:
+    """Lanes moved by sort pass, lanes moved by gather and sort passes
+    traced on this thread so far, under the names a program's build
+    record gives them.  Tracing a program raises them, so the difference
+    around a `lower()` is what that program does."""
+    return {"lane_moves_sorted": _COUNTS.sorted,
+            "lane_moves_gathered": _COUNTS.gathered,
+            "sort_passes": _COUNTS.passes}
+
+
+def _gather_span_column(xp, col: DeviceColumn, order, cap: int):
+    import jax
+    _COUNTS.gathered += len(jax.tree_util.tree_leaves(col))
+    return gather_column(xp, col, order, xp.ones((cap,), dtype=bool))
+
+
+# ---------------------------------------------------------------------------
+# The one sort signature, and the moves built from it
+# ---------------------------------------------------------------------------
+
+_SIGN32 = np.uint32(0x80000000)
+
+
+def _sort_pass(a, b):
+    """Sort the unique (uint32, int32) pairs lexicographically.  Both are
+    keys, so the compiler is asked for no stability and no payload."""
+    from jax import lax
+    _COUNTS.passes += 1
+    return lax.sort((a, b), num_keys=2, is_stable=False)
+
+
+def _as_i32(x):
+    from jax import lax
+    return lax.bitcast_convert_type(x, np.int32)
+
+
+def _inverse(xp, perm, cap: int):
+    """inv[perm[i]] = i: an order's rank, a rank's order."""
+    return _sort_pass(perm.astype(xp.uint32),
+                      xp.arange(cap, dtype=xp.int32))[1]
+
+
+def _f64_split_words(d):
+    """A double as the TPU holds it: the nearest float32 and the float32
+    remainder (`segmented._float_split_ordered`)."""
+    import jax.numpy as jnp
+    hi = d.astype(jnp.float32)
+    lo = jnp.where(jnp.isfinite(hi), d - hi.astype(jnp.float64),
+                   0.0).astype(jnp.float32)
+    return _as_i32(hi), _as_i32(lo)
+
+
+def _f64_join_split(w0, w1):
+    import jax.numpy as jnp
+    from jax import lax
+    hi = lax.bitcast_convert_type(w0, jnp.float32).astype(jnp.float64)
+    lo = lax.bitcast_convert_type(w1, jnp.float32).astype(jnp.float64)
+    # -0.0 + 0.0 is +0.0: a head without a remainder is the whole value
+    return jnp.where(lo == 0.0, hi, hi + lo)
+
+
+def _f64_bit_words(d):
+    from jax import lax
+    w = lax.bitcast_convert_type(d, np.int32)
+    return w[:, 0], w[:, 1]
+
+
+def _f64_join_bits(w0, w1):
+    import jax.numpy as jnp
+    from jax import lax
+    return lax.bitcast_convert_type(jnp.stack([w0, w1], axis=-1),
+                                    np.float64)
+
+
+def _to_words(xp, x):
+    """(int32 words, rebuild) of one non-bool lane: the 32-bit pieces
+    that move, and the function that makes the lane of them again."""
+    from jax import lax
+    dt = np.dtype(x.dtype)
+    if dt.kind in "iu" and dt.itemsize < 4:
+        return [x.astype(xp.int32)], lambda ws: ws[0].astype(dt)
+    if dt.itemsize == 4 and dt.kind in "iuf":
+        return [_as_i32(x)], lambda ws: lax.bitcast_convert_type(ws[0], dt)
+    if dt.kind in "iu" and dt.itemsize == 8:
+        u = x.astype(xp.uint64)
+        words = [_as_i32(u.astype(xp.uint32)),
+                 _as_i32((u >> np.uint64(32)).astype(xp.uint32))]
+
+        def join(ws):
+            lo, hi = (lax.bitcast_convert_type(w, np.uint32).astype(
+                xp.uint64) for w in ws)
+            return ((hi << np.uint64(32)) | lo).astype(dt)
+        return words, join
+    if dt == np.float64:
+        # the TPU has no bit view of a double (doubles are float32 pairs
+        # there); elsewhere the bits move as they are
+        words = lax.platform_dependent(x, tpu=_f64_split_words,
+                                       default=_f64_bit_words)
+        return list(words), lambda ws: lax.platform_dependent(
+            ws[0], ws[1], tpu=_f64_join_split, default=_f64_join_bits)
+    raise TypeError(f"no sort-pass move for a lane of {dt}")
+
+
+def move_lanes(xp, rank, lanes: Sequence) -> List:
+    """Every 1-D lane of `lanes` with row r at `rank[r]` (`rank` a
+    permutation of 0..cap-1): `x[order]` for the order whose inverse
+    `rank` is, bit for bit, without a gather.  One sort pass per 32-bit
+    word of distinct lane; bool lanes travel 32 to a word."""
+    if xp is np:
+        out = []
+        for x in lanes:
+            y = np.empty_like(x)
+            y[rank] = x
+            out.append(y)
+        return out
+    if not lanes:
+        return []
+    slot_of: dict = {}          # the same lane may back several columns
+    uniq: List = []
+    for x in lanes:
+        if id(x) not in slot_of:
+            slot_of[id(x)] = len(uniq)
+            uniq.append(x)
+    _COUNTS.sorted += len(uniq)
+    rk = rank.astype(xp.uint32)
+
+    def moved(word):
+        return _sort_pass(rk, word)[1]
+
+    out_u: List = [None] * len(uniq)
+    flags = [i for i, x in enumerate(uniq) if np.dtype(x.dtype) == np.bool_]
+    for at in range(0, len(flags), 32):
+        group = flags[at:at + 32]
+        packed = uniq[group[0]].astype(xp.uint32)
+        for bit, i in enumerate(group[1:], start=1):
+            packed = packed | (uniq[i].astype(xp.uint32) << np.uint32(bit))
+        packed = moved(_as_i32(packed))
+        for bit, i in enumerate(group):
+            out_u[i] = ((packed >> np.int32(bit)) & np.int32(1)) != 0
+    for i, x in enumerate(uniq):
+        if out_u[i] is None:
+            words, rebuild = _to_words(xp, x)
+            out_u[i] = rebuild([moved(w) for w in words])
+    return [out_u[slot_of[id(x)]] for x in lanes]
+
+
+def compaction_rank(xp, keep, cap: int):
+    """Where each row goes in a stable partition, kept rows first
+    (int32[cap]), in closed form: a prefix sum and no sort."""
+    from .scan import cumsum_fast
+    k = keep.astype(xp.int32)
+    seen = cumsum_fast(xp, k)           # kept rows up to and with this one
+    pos = xp.arange(cap, dtype=xp.int32)
+    return xp.where(keep, seen - 1, seen[-1] + pos - seen)
+
+
+def _u32_pieces(xp, w) -> list:
+    """(uint32 lane, bits) pieces of one integer key word, least
+    significant first, which order as the word does: one for words of up
+    to 32 bits, two for 64."""
     dt = np.dtype(w.dtype)
     if dt == np.bool_:
-        return [w.astype(xp.uint32)]
+        return [(w.astype(xp.uint32), 1)]
     if dt.kind not in "iu":
         raise TypeError(f"lean sort key words are integers, not {dt}")
+    bits = 8 * dt.itemsize
     if dt.kind == "i":
         # two's complement orders like unsigned once the sign bit flips
         u = np.dtype(f"u{dt.itemsize}")
-        w = w.astype(u) ^ u.type(1 << (8 * dt.itemsize - 1))
-    if dt.itemsize <= 4:
-        return [w.astype(xp.uint32)]
-    return [w.astype(xp.uint32), (w >> np.uint64(32)).astype(xp.uint32)]
+        w = w.astype(u) ^ u.type(1 << (bits - 1))
+    if bits <= 32:
+        return [(w.astype(xp.uint32), bits)]
+    return [(w.astype(xp.uint32), 32),
+            ((w >> np.uint64(32)).astype(xp.uint32), 32)]
+
+
+def _digits(xp, key_words, cap: int) -> list:
+    """The key as radix digits, least significant first.  A pass sorts 64
+    bits and the tie-break needs `pos_bits` of them, so a digit is as
+    many adjacent pieces as fit the rest: the subquery's (live flag, null
+    flag, int64) key is two digits at 33,554,432 rows, not four."""
+    pos_bits = (cap - 1).bit_length()
+    digits: list = []
+    room = 0
+    for w in reversed(list(key_words)):
+        for piece in _u32_pieces(xp, w):
+            if piece[1] > room:
+                digits.append([])
+                room = 64 - pos_bits
+            digits[-1].append(piece)
+            room -= piece[1]
+    return digits
+
+
+def _digit_pass(xp, digit, tie, cap: int):
+    """The tie-breaks (int32, a permutation of 0..cap-1, row-aligned with
+    the digit) in ascending order of (digit, tie-break)."""
+    total = sum(bits for _, bits in digit)
+    wide = np.uint32 if total <= 32 else np.uint64
+    value, shift = None, 0
+    for lane, bits in digit:
+        part = lane.astype(wide) << wide(shift)
+        value = part if value is None else value | part
+        shift += bits
+    if total <= 32:
+        return _sort_pass(value, tie)[1]
+    # more than one operand's worth: the low bits ride above the
+    # tie-break in the second operand, biased to order as unsigned
+    pos_bits = (cap - 1).bit_length()
+    both = (value << np.uint64(pos_bits)) | tie.astype(xp.uint64)
+    low = _as_i32(both.astype(xp.uint32) ^ _SIGN32)
+    out = _sort_pass((both >> np.uint64(32)).astype(xp.uint32), low)[1]
+    return (out ^ np.int32(-2**31)) & np.int32((1 << pos_bits) - 1)
+
+
+def _lean_perm(xp, key_words, cap: int, want_order: bool = True,
+               want_rank: bool = True):
+    """(order, rank) of the stable ascending lexicographic sort by integer
+    key words, most significant first; one not wanted may be None.
+    `order[j]` is the row at place j, `rank[r]` the place of row r.
+
+    A least-significant-digit radix sort in which nothing but the
+    permutation moves: each pass sorts (digit, rank so far) with both in
+    row order, which is stable because the rank is the tie-break, and
+    costs the passes that compose its answer onto the order and the rank
+    (two digits: 5 passes for a rank, where gathering digits and order
+    between position-keyed passes as sort-pass moves would take 6, and
+    four unpacked digits 16)."""
+    iota = xp.arange(cap, dtype=xp.int32)
+    if len(key_words) == 1 and np.dtype(key_words[0].dtype) == np.bool_:
+        rank = compaction_rank(xp, ~key_words[0], cap)
+        return (_inverse(xp, rank, cap) if want_order else None), rank
+    digits = _digits(xp, key_words, cap)
+    if not digits:
+        return iota, iota
+    order = rank = None
+    for k, digit in enumerate(digits):
+        last = k == len(digits) - 1
+        q = _digit_pass(xp, digit, iota if k == 0 else rank, cap)
+        if k == 0:
+            order = q
+            rank = _inverse(xp, q, cap) if (want_rank or not last) else None
+            continue
+        # q[j] is the OLD rank of the row now at place j
+        inv_q = _inverse(xp, q, cap)
+        old_order = order
+        # rank[r] = inv_q[old_rank[r]];  order[j] = old_order[q[j]]
+        rank = _sort_pass(old_order.astype(xp.uint32), inv_q)[1] \
+            if (want_rank or not last) else None
+        order = _sort_pass(inv_q.astype(xp.uint32), old_order)[1] \
+            if (want_order or not last) else None
+    return order, rank
 
 
 def lean_argsort(xp, key_words, cap: int):
     """Stable ascending lexicographic argsort (int32[cap]) by integer key
-    words, most significant first: a least-significant-digit radix sort
-    whose every pass is the same 2-operand (uint32 digit, int32 position)
-    sort.  The position is the second key, which makes each pass stable
-    without asking the compiler for a stable sort."""
-    from jax import lax
-    iota = xp.arange(cap, dtype=xp.int32)
-    order = None
-    for w in reversed(list(key_words)):
-        for digit in _u32_digits(xp, w):
-            kw = digit if order is None else digit[order]
-            _, p = lax.sort((kw, iota), num_keys=2, is_stable=False)
-            order = p if order is None else order[p]
-    return iota if order is None else order
+    words, most significant first, from passes of the one signature."""
+    return _lean_perm(xp, key_words, cap, want_rank=False)[0]
 
 
 def stable_argsort(xp, key_words, cap: int):
@@ -93,16 +338,6 @@ def stable_argsort(xp, key_words, cap: int):
                     is_stable=True)[-1]
 
 
-def _sort_rows_lean(xp, key_words, cols, cap, extras):
-    """`sort_rows` by `lean_argsort`, then gather everything by the final
-    order.  Same results as the carry path, far cheaper to compile."""
-    order = lean_argsort(xp, key_words, cap)
-    ones = xp.ones((cap,), dtype=bool)
-    out_cols = [gather_column(xp, c, order, ones) for c in cols]
-    out_extras = [e[order] for e in extras]
-    return order, out_cols, out_extras
-
-
 def carriable(col: DeviceColumn) -> bool:
     """True when every lane of the column is row-aligned (no offsets
     anywhere in the tree), so a row permutation is just a lane permute."""
@@ -111,19 +346,44 @@ def carriable(col: DeviceColumn) -> bool:
     return all(carriable(c) for c in col.children)
 
 
+def _sort_rows_lean(xp, key_words, cols, cap, extras, need_order):
+    """`sort_rows` from passes of the one signature: the rank, then a
+    move per lane.  Same results as the carry path, far cheaper to
+    compile; span columns are gathered by the order."""
+    import jax
+    flats = [jax.tree_util.tree_flatten(c) if carriable(c) else None
+             for c in cols]
+    lanes = [leaf for f in flats if f is not None for leaf in f[0]]
+    lanes += list(extras)
+    order, rank = _lean_perm(
+        xp, key_words, cap, want_rank=bool(lanes),
+        want_order=need_order or any(f is None for f in flats))
+    moved = iter(move_lanes(xp, rank, lanes))
+    out_cols = []
+    for c, f in zip(cols, flats):
+        if f is None:
+            out_cols.append(_gather_span_column(xp, c, order, cap))
+        else:
+            out_cols.append(jax.tree_util.tree_unflatten(
+                f[1], [next(moved) for _ in f[0]]))
+    return order, out_cols, list(moved)
+
+
 def _permute_col_np(col: DeviceColumn, order) -> DeviceColumn:
     import jax
     return jax.tree_util.tree_map(lambda lane: lane[order], col)
 
 
 def sort_rows(xp, key_words: Sequence, cols: Sequence[DeviceColumn],
-              cap: int, extras: Sequence = ()):
+              cap: int, extras: Sequence = (), need_order: bool = True):
     """Stable ascending lexicographic sort by `key_words`; rows of `cols`
     and the 1-D arrays in `extras` travel with the permutation.
 
     Returns (order:int32[cap], out_cols, out_extras).  Non-carriable
     columns are gathered by `order` (validity preserved; a permutation
-    never invents nulls)."""
+    never invents nulls).  A caller that drops the order says so with
+    `need_order=False` and may get None: in lean mode the order costs a
+    pass of its own."""
     import jax
     if xp is np:
         order = np.lexsort(tuple(reversed(list(key_words)))).astype(np.int32)
@@ -138,7 +398,7 @@ def sort_rows(xp, key_words: Sequence, cols: Sequence[DeviceColumn],
         return order, out_cols, out_extras
 
     if _LEAN:
-        return _sort_rows_lean(xp, key_words, cols, cap, extras)
+        return _sort_rows_lean(xp, key_words, cols, cap, extras, need_order)
 
     from jax import lax
     iota = xp.arange(cap, dtype=xp.int32)
@@ -172,8 +432,7 @@ def sort_rows(xp, key_words: Sequence, cols: Sequence[DeviceColumn],
     out_cols = []
     for c, (treedef, idxs) in zip(cols, flats):
         if treedef is None:
-            ones = xp.ones((cap,), dtype=bool)
-            out_cols.append(gather_column(xp, c, order, ones))
+            out_cols.append(_gather_span_column(xp, c, order, cap))
         else:
             out_cols.append(jax.tree_util.tree_unflatten(
                 treedef, [res[i] for i in idxs]))
@@ -181,18 +440,24 @@ def sort_rows(xp, key_words: Sequence, cols: Sequence[DeviceColumn],
     return order, out_cols, out_extras
 
 
-def sort_lanes(xp, key_words: Sequence, lanes: Sequence, cap: int):
+def sort_lanes(xp, key_words: Sequence, lanes: Sequence, cap: int,
+               need_order: bool = True):
     """Lane-only carry-sort: returns (order, sorted_lanes)."""
-    order, _, out = sort_rows(xp, key_words, (), cap, extras=lanes)
+    order, _, out = sort_rows(xp, key_words, (), cap, extras=lanes,
+                              need_order=need_order)
     return order, out
 
 
 def compact_rows(xp, keep, cols: Sequence[DeviceColumn], cap: int,
-                 extras: Sequence = ()):
+                 extras: Sequence = (), need_order: bool = False):
     """Stable partition: rows with keep=True move to the front in
-    original order (ONE u8-key carry-sort)."""
-    key = (~keep).astype(np.uint8 if xp is np else xp.uint8)
-    return sort_rows(xp, [key], cols, cap, extras=extras)
+    original order.  A sort by the one-bit key `~keep`: in lean mode its
+    rank is `compaction_rank`'s closed form and only the lanes' own
+    passes run; otherwise ONE u8-key carry-sort."""
+    if xp is np or not _LEAN:
+        key = (~keep).astype(np.uint8 if xp is np else xp.uint8)
+        return sort_rows(xp, [key], cols, cap, extras=extras)
+    return _sort_rows_lean(xp, [~keep], cols, cap, extras, need_order)
 
 
 def mask_validity(xp, col: DeviceColumn, mask) -> DeviceColumn:

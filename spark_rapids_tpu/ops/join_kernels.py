@@ -96,7 +96,7 @@ def count_matches(xp, build_hash, build_live, probe_hash, probe_live):
         counts = np.where(probe_live, hi - lo, 0).astype(np.int64)
         return order, lo, counts
     from jax import lax
-    from .carry import compile_lean_enabled, lean_argsort
+    from .carry import compile_lean_enabled, lean_argsort, sort_lanes
     from .scan import cummax_i32, cumsum_fast
     cap_p = probe_hash.shape[0]
     iota_b = xp.arange(cap_b, dtype=xp.int32)
@@ -106,8 +106,8 @@ def count_matches(xp, build_hash, build_live, probe_hash, probe_live):
     idx = xp.concatenate([iota_b, xp.arange(cap_p, dtype=xp.int32)])
     if compile_lean_enabled():
         order = lean_argsort(xp, [bh], cap_b)
-        both = lean_argsort(xp, [allh, side], cap_b + cap_p)
-        sh, ss, si = allh[both], side[both], idx[both]
+        _, (sh, ss, si) = sort_lanes(xp, [allh, side], [allh, side, idx],
+                                     cap_b + cap_p, need_order=False)
     else:
         _, order = lax.sort((bh, iota_b), num_keys=1, is_stable=True)
         sh, ss, si = lax.sort((allh, side, idx), num_keys=2,

@@ -112,7 +112,8 @@ def key_words_for_column(xp, col: DeviceColumn, live_mask,
                          ascending: bool = True):
     """Sort-key words (most-significant first) for one column.
 
-    Word 0 is the null indicator (uint8; nulls group/sort together);
+    Word 0 is the null indicator (bool: one bit of a lean sort's digit;
+    nulls group/sort together);
     remaining words encode the value — uint32 for types that fit 32 bits
     (half the sort-comparator cost on TPU), uint64 otherwise.  Strings
     use content hashes when only grouping (equality) is needed, or
@@ -121,8 +122,7 @@ def key_words_for_column(xp, col: DeviceColumn, live_mask,
     validity = col.validity
     if validity is None:
         validity = xp.ones((col.capacity,), dtype=bool)
-    null_word = xp.where(validity, xp.uint8(1 if nulls_first else 0),
-                         xp.uint8(0 if nulls_first else 1))
+    null_word = validity if nulls_first else ~validity
     words = [null_word]
     if isinstance(dtype, (t.StringType, t.BinaryType)):
         if for_grouping:

@@ -388,7 +388,8 @@ class DistributedSort:
         maxw = jnp.uint64(0xFFFFFFFFFFFFFFFF)
         from ..ops import carry
         parked = jnp.where(live, w0, maxw)
-        sorted_w0 = parked[carry.lean_argsort(jnp, [parked], cap)] \
+        sorted_w0 = carry.sort_lanes(jnp, [parked], [parked], cap,
+                                     need_order=False)[1][0] \
             if carry.compile_lean_enabled() else jnp.sort(parked)
         n_live = jnp.sum(live.astype(jnp.int32))
         # local splitter candidates at the n_dev-quantiles

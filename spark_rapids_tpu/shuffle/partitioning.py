@@ -145,10 +145,10 @@ def slice_batch_by_partition(xp, batch: DeviceBatch, pids,
     from ..ops import carry
     live = xp.arange(batch.capacity, dtype=np.int32) < batch.num_rows
     key = xp.where(live, pids, np.int32(num_partitions))  # padding last
-    # rows ride the sort as payload lanes (no post-sort gathers)
+    # rows ride the sort (no post-sort gathers)
     _, cols, ex = carry.sort_rows(xp, [key.astype(xp.uint32)],
                                   batch.columns, batch.capacity,
-                                  extras=[key])
+                                  extras=[key], need_order=False)
     sorted_pids = ex[0]
     counts = xp.zeros((num_partitions,), dtype=np.int64)
     if xp is np:

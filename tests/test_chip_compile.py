@@ -156,6 +156,26 @@ def test_lean_argsort_pass_at_the_largest_bucket(one_chip):
                      pad, k)
 
 
+def test_compact_at_the_largest_bucket(one_chip, record_property):
+    """The filter's compaction as a prefix sum and sort passes of the one
+    signature (ten of them here): its compile time must stay that of one
+    sort, whatever the number of lanes."""
+    import time
+    from spark_rapids_tpu.exec.filter_common import compact
+    batch = abstract_batch(FACT + (("d", t.DATE), ("g", t.DOUBLE)), M4,
+                           one_chip)
+    keep = jax.ShapeDtypeStruct((M4,), np.bool_, sharding=one_chip)
+    t0 = time.perf_counter()
+    compiled = compile_for_chip(lambda b, k: compact(jnp, b, k, b.names),
+                                batch, keep)
+    seconds = time.perf_counter() - t0
+    record_property("compact_4194304_compile_s", round(seconds, 1))
+    print(f"compact at {M4} rows for v5e: {seconds:.1f} s")
+    text = compiled.as_text()
+    assert " sort(" in text and " gather(" not in text
+    assert seconds < 240, "a lane took a new sort signature"
+
+
 def test_exchange_and_aggregate_over_four_chips(mesh4):
     """`DistributedAggregate`'s SPMD step (partial aggregate,
     `exchange_by_pid` all_to_all, final aggregate) on a 4-device mesh of

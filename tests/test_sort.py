@@ -66,15 +66,24 @@ def _lean_words(rng, n):
         "bool_i32_u64": [rng.integers(0, 2, n).astype(bool),
                          rng.integers(-2, 2, n).astype(np.int32), dup],
         "no_words": [],
+        # one bool word is a stable partition: its rank is a prefix sum
+        "bool": [rng.integers(0, 3, n) == 0],
+        # the grouped aggregate's key (live flag, null flag, int64): two
+        # packed digits, the second wider than one operand
+        "bool_bool_i64": [rng.integers(0, 9, n) == 0,
+                          rng.integers(0, 5, n) == 0, neg // 2**40],
+        "u16_i64_i64": [rng.integers(0, 4, n).astype(np.uint16), neg,
+                        neg[::-1].copy()],
     }
 
 
 @pytest.mark.parametrize("case", ["u8", "u64", "u8_u64", "i32", "i64_u8",
-                                  "bool_i32_u64", "no_words"])
+                                  "bool_i32_u64", "no_words", "bool",
+                                  "bool_bool_i64", "u16_i64_i64"])
 @pytest.mark.parametrize("lean", [True, False])
 def test_stable_argsort_is_numpys_stable_lexsort(case, lean):
     """Every device sort in the engine goes through this; in lean mode it
-    is a radix sort of (uint32 digit, int32 position) passes."""
+    is a radix sort of (uint32, int32) passes over packed digits."""
     import jax
     import jax.numpy as jnp
     from spark_rapids_tpu.ops import carry
