@@ -361,14 +361,17 @@ def column_to_device(arr: pa.Array, dtype: t.DataType, cap: int,
 def batch_to_device(rb: pa.RecordBatch,
                     row_buckets: Sequence[int] = DEFAULT_ROW_BUCKETS,
                     char_buckets: Sequence[int] = DEFAULT_CHAR_BUCKETS,
-                    capacity: Optional[int] = None, xp=jnp) -> DeviceBatch:
+                    capacity: Optional[int] = None, xp=jnp,
+                    device=None) -> DeviceBatch:
     """Upload an Arrow RecordBatch, padding to a capacity bucket.
 
     With ``xp=jnp`` this is the host->device crossing: the span
     ``scan.upload`` and the counter ``tpu_upload_bytes_total`` (every
     lane placed on the device, validity included).  The ``xp=np``
     callers (UDF and CPU-engine paths) upload nothing and count
-    nothing."""
+    nothing.  ``device`` sends the lanes to that chip and commits them
+    there, so what is computed from them runs there; without it they go
+    to JAX's default device, uncommitted."""
     n = rb.num_rows
     cap = capacity if capacity is not None else bucket_for(n, row_buckets)
 
@@ -384,8 +387,17 @@ def batch_to_device(rb: pa.RecordBatch,
         return place()
     from ..obs import metrics as m
     from ..obs.tracer import trace_span
-    with trace_span("scan.upload", rows=n) as sp:
-        batch = place()
+    attrs = {} if device is None else {"device": device.id}
+    with trace_span("scan.upload", rows=n, **attrs) as sp:
+        if device is None:
+            batch = place()
+        else:
+            with jax.default_device(device):
+                batch = place()
+            # the lanes are there already: committing copies nothing
+            # (the row count stays the host scalar a scan batch carries)
+            batch = DeviceBatch(jax.device_put(batch.columns, device), n,
+                                batch.names)
         nbytes = sum(int(leaf.nbytes)
                      for leaf in jax.tree_util.tree_leaves(batch)
                      if isinstance(leaf, jax.Array))

@@ -35,6 +35,12 @@ class LocalScanExec(Exec):
     """Scan over in-memory Arrow data split into partitions
     (analog of Spark's LocalTableScanExec feeding the plugin)."""
 
+    #: set by ``parallel/ici_exec.install_ici_stages`` on a scan that
+    #: feeds a mesh stage through partition-local operators alone (ICI
+    #: transport, several chips): its partitions are placed one a mesh
+    #: device, round robin, and pinned there
+    mesh_resident = False
+
     def __init__(self, table: pa.Table, num_partitions: int = 1,
                  batch_rows: Optional[int] = None,
                  pin_cache: Optional[dict] = None):
@@ -86,10 +92,13 @@ class LocalScanExec(Exec):
                              retained=total_bytes(st),
                              note="pinned scan cache")
 
+    def _pin_key(self, pid):
+        return (pid, self._num_partitions, self.batch_rows,
+                self.placement) + (("mesh",) if self.mesh_resident else ())
+
     def execute_partition(self, pid, ctx) -> Iterator[Batch]:
         from .. import config as cfg
-        key = (pid, self._num_partitions, self.batch_rows,
-               self.placement)
+        key = self._pin_key(pid)
         pin = self.pin_cache if (self.pin_cache is not None and
                                  ctx.conf.get(cfg.SCAN_PIN_DEVICE)) else None
         if pin is not None and key in pin:
@@ -114,7 +123,31 @@ class LocalScanExec(Exec):
                 from ..memory.spill import SpillCatalog
                 SpillCatalog.get().register_pinned(pin, key, produced)
 
+    @property
+    def pinned_devices(self) -> int:
+        """Distinct devices this scan's pinned lanes lie on (0 before
+        the first call, or with nothing pinned)."""
+        devs = set()
+        for pid in range(self._num_partitions):
+            batches = (self.pin_cache or {}).get(self._pin_key(pid), ())
+            for leaf in jax.tree_util.tree_leaves(
+                    [b.columns for b in batches]):
+                if isinstance(leaf, jax.Array):
+                    devs |= leaf.devices()
+        return len(devs)
+
+    def _partition_device(self, pid):
+        """The chip that keeps partition ``pid``: mesh device
+        ``pid % n_dev`` where a mesh stage reads this scan in place
+        (``mesh_resident``); otherwise None, JAX's default device."""
+        if not self.mesh_resident or self.placement != TPU:
+            return None
+        from ..parallel.mesh import discover_devices
+        devs = discover_devices()
+        return devs[pid % len(devs)] if len(devs) > 1 else None
+
     def _produce_partition(self, pid, ctx) -> Iterator[Batch]:
+        device = self._partition_device(pid)
         n = self.table.num_rows
         per = -(-n // self._num_partitions)
         start = min(pid * per, n)
@@ -129,13 +162,13 @@ class LocalScanExec(Exec):
             rb = piece.to_batches()
             if rb:
                 b = batch_to_device(pa.Table.from_batches(rb).combine_chunks()
-                                    .to_batches()[0], xp=xp)
+                                    .to_batches()[0], xp=xp, device=device)
             else:
                 b = batch_to_device(
                     pa.RecordBatch.from_pydict(
                         {n_: pa.array([], type=f.type)
                          for n_, f in zip(self._names, self.table.schema)}),
-                    xp=xp)
+                    xp=xp, device=device)
             self.metrics[NUM_OUTPUT_ROWS] += b.num_rows
             self.metrics[NUM_OUTPUT_BATCHES] += 1
             yield b

@@ -24,14 +24,21 @@ class DeviceManager:
     def __init__(self, conf: cfg.RapidsConf):
         self.conf = conf
         self.device = None
+        self.devices = list(jax.devices())
+        #: the HBM budget of each chip, by ``device.id``; a table spread
+        #: over the mesh is booked against the chips that hold it
+        #: (memory/spill.py), never all against the first
+        self.hbm_limits = {}
         self.hbm_limit = 0
         self.hbm_reserve = conf.get(cfg.HBM_RESERVE)
-        devs = jax.devices()
-        if devs:
-            self.device = devs[0]
-            total = self._device_capacity(conf)
+        if self.devices:
+            self.device = self.devices[0]
             frac = conf.get(cfg.HBM_POOL_FRACTION)
-            self.hbm_limit = int(total * frac) - self.hbm_reserve
+            for d in self.devices:
+                self.hbm_limits[d.id] = int(
+                    self._device_capacity(conf, d) * frac) \
+                    - self.hbm_reserve
+            self.hbm_limit = self.hbm_limits[self.device.id]
 
     # per-generation HBM capacities (public TPU specs); used only when the
     # PJRT runtime reports no memory_stats for the device
@@ -42,22 +49,24 @@ class DeviceManager:
         ("v2", 8 * (1 << 30)),
     )
 
-    def _device_capacity(self, conf: cfg.RapidsConf) -> int:
-        """Resolve real device memory: explicit conf > PJRT memory_stats >
-        device-kind table > host RAM (CPU backend).  An unrecognized
-        accelerator with no stats raises instead of silently assuming a
-        capacity the spill budget would then be fiction against."""
+    def _device_capacity(self, conf: cfg.RapidsConf, device=None) -> int:
+        """Resolve one chip's real memory (the first chip's by default):
+        explicit conf > PJRT memory_stats > device-kind table > host RAM
+        (CPU backend).  An unrecognized accelerator with no stats raises
+        instead of silently assuming a capacity the spill budget would
+        then be fiction against."""
+        device = self.device if device is None else device
         override = conf.get(cfg.HBM_LIMIT_OVERRIDE)
         if override:
             return int(override)
         try:
-            stats = self.device.memory_stats() or {}
+            stats = device.memory_stats() or {}
         except Exception:
             stats = {}
         if stats.get("bytes_limit"):
             return int(stats["bytes_limit"])
-        kind = (getattr(self.device, "device_kind", "") or "").lower()
-        platform = getattr(self.device, "platform", "")
+        kind = (getattr(device, "device_kind", "") or "").lower()
+        platform = getattr(device, "platform", "")
         for marker, cap in self._KNOWN_HBM:
             if marker in kind:
                 return cap
@@ -81,9 +90,15 @@ class DeviceManager:
     def get(cls) -> Optional["DeviceManager"]:
         return cls._instance
 
-    def memory_in_use(self) -> int:
+    def memory_in_use(self, device=None) -> int:
+        """Bytes in use on one chip (the first by default)."""
+        device = self.device if device is None else device
         try:
-            stats = self.device.memory_stats() or {}
+            stats = device.memory_stats() or {}
             return stats.get("bytes_in_use", 0)
         except Exception:
             return 0
+
+    def memory_in_use_by_device(self) -> dict:
+        """``device.id`` -> bytes in use, for every chip of the mesh."""
+        return {d.id: self.memory_in_use(d) for d in self.devices}

@@ -84,6 +84,11 @@ def _metrics():
     )
 
 
+#: every chip together, where a chip's ``device.id`` (or None, for
+#: lanes no one chip holds) would pick one
+ALL_CHIPS = "all"
+
+
 class StorageTier(Enum):
     DEVICE = 0
     HOST = 1
@@ -95,6 +100,17 @@ class SpillPriority:
     INPUT = -10
     SHUFFLE = 0
     ACTIVE = 100
+
+
+def chip_of(batches) -> Optional[int]:
+    """``device.id`` of the one chip these batches' lanes lie on; None
+    for host lanes and for lanes on several chips (a stacked mesh
+    batch), which no single chip's budget answers for."""
+    ids = set()
+    for leaf in jax.tree_util.tree_leaves(batches):
+        if isinstance(leaf, jax.Array):
+            ids |= {d.id for d in leaf.devices()}
+    return ids.pop() if len(ids) == 1 else None
 
 
 def batch_device_bytes(batch: DeviceBatch) -> int:
@@ -124,6 +140,7 @@ class SpillableBatch:
         self._disk_path: Optional[str] = None
         self.closed = False
         self.device_bytes = batch_device_bytes(batch)
+        self.chip = chip_of(batch)
         # num_rows may be a traced device scalar; resolving it here would
         # force a sync per registered batch — defer to first read
         self._num_rows = batch.num_rows
@@ -215,6 +232,7 @@ class SpillableBatch:
         batch = deserialize_batch(data, xp=xp)
         if self.catalog.unspill_enabled and xp is not np:
             self._batch = batch
+            self.chip = chip_of(batch)   # it comes back on the default
             self._host_bytes = None
             if self._disk_path:
                 try:
@@ -277,8 +295,12 @@ class SpillCatalog:
     def __init__(self, device_budget: int = 8 << 30,
                  host_budget: int = 1 << 30,
                  spill_dir: Optional[str] = None,
-                 unspill_enabled: bool = False):
+                 unspill_enabled: bool = False,
+                 chip_budgets: Optional[Dict[int, int]] = None):
+        #: what ONE chip may hold of registered bytes; ``chip_budgets``
+        #: (``device.id`` -> bytes) where the chips differ
         self.device_budget = device_budget
+        self.chip_budgets = dict(chip_budgets or {})
         self.host_budget = host_budget
         self.spill_dir = spill_dir or tempfile.mkdtemp(
             prefix="spark_rapids_tpu_spill_")
@@ -293,6 +315,7 @@ class SpillCatalog:
         # device-resident but reclaimable, RapidsDeviceMemoryStore)
         self._pinned: Dict[tuple, int] = {}
         self._pin_owners: Dict[tuple, Dict] = {}
+        self._pin_chips: Dict[tuple, Optional[int]] = {}
         self._reg_lock = threading.RLock()
         self.spilled_to_host_bytes = 0
         self.spilled_to_disk_bytes = 0
@@ -316,12 +339,15 @@ class SpillCatalog:
         from .device import DeviceManager
         dm = DeviceManager.get()
         device_budget = conf.get(cfg.SPILL_DEVICE_BUDGET)
+        chip_budgets = None
         if device_budget is None:
             device_budget = dm.hbm_limit if dm and dm.hbm_limit > 0 \
                 else 8 << 30
+            if dm and dm.hbm_limit > 0:
+                chip_budgets = dm.hbm_limits
         with cls._lock:
             cls._instance = SpillCatalog(
-                device_budget=device_budget,
+                device_budget=device_budget, chip_budgets=chip_budgets,
                 host_budget=conf.get(cfg.HOST_SPILL_STORAGE_SIZE),
                 spill_dir=conf.get(cfg.SPILL_DIRS).split(",")[0],
                 unspill_enabled=conf.get(cfg.UNSPILL_ENABLED))
@@ -382,7 +408,7 @@ class SpillCatalog:
     # -- pinned scan batches -------------------------------------------------
     def register_pinned(self, owner: Dict, key, batch_list) -> None:
         """Account a pin-cache entry (owner[key] = batches) against the
-        device budget and make it evictable."""
+        budget of the chip it lies on and make it evictable."""
         nbytes = sum(batch_device_bytes(b) for b in batch_list)
         led = _ledger()
         if led is not None:
@@ -393,15 +419,17 @@ class SpillCatalog:
         with self._reg_lock:
             self._pinned[(id(owner), key)] = nbytes
             self._pin_owners[(id(owner), key)] = owner
+            self._pin_chips[(id(owner), key)] = chip_of(batch_list)
         _metrics()[1].inc(nbytes)
         self.maybe_spill()
         self._update_gauges()
 
-    def pinned_bytes(self) -> int:
+    def pinned_bytes(self, chip=ALL_CHIPS) -> int:
         with self._reg_lock:
-            return sum(self._pinned.values())
+            return sum(n for k, n in self._pinned.items()
+                       if chip is ALL_CHIPS or self._pin_chips[k] == chip)
 
-    def _evict_pinned(self, target_free: int) -> int:
+    def _evict_pinned(self, target_free: int, chip=ALL_CHIPS) -> int:
         freed = 0
         led = _ledger()
         tl = _timeline()
@@ -409,6 +437,9 @@ class SpillCatalog:
             for (oid, key), nbytes in list(self._pinned.items()):
                 if freed >= target_free:
                     break
+                if chip is not ALL_CHIPS and \
+                        self._pin_chips[(oid, key)] != chip:
+                    continue
                 owner = self._pin_owners.get((oid, key))
                 if owner is not None:
                     owner.pop(key, None)
@@ -418,6 +449,7 @@ class SpillCatalog:
                     tl.on_evict(_pin_handle_id(owner, key, oid))
                 self._pinned.pop((oid, key), None)
                 self._pin_owners.pop((oid, key), None)
+                self._pin_chips.pop((oid, key), None)
                 freed += nbytes
                 self.pinned_evicted_bytes += nbytes
                 _trace_event("spill.evict_pinned", bytes=nbytes)
@@ -428,11 +460,20 @@ class SpillCatalog:
         self.maybe_spill()
 
     # -- accounting ---------------------------------------------------------
-    def device_bytes_registered(self) -> int:
+    def device_bytes_registered(self, chip=ALL_CHIPS) -> int:
+        """Registered device-resident bytes, pinned included: of every
+        chip, or of one (by ``device.id``)."""
         with self._reg_lock:
             return sum(b.device_bytes for b in self._buffers.values()
-                       if b.tier == StorageTier.DEVICE) + \
-                sum(self._pinned.values())
+                       if b.tier == StorageTier.DEVICE and
+                       (chip is ALL_CHIPS or b.chip == chip)) + \
+                self.pinned_bytes(chip)
+
+    def _chips(self) -> set:
+        with self._reg_lock:
+            return {b.chip for b in self._buffers.values()
+                    if b.tier == StorageTier.DEVICE} | \
+                set(self._pin_chips.values())
 
     def host_bytes_registered(self) -> int:
         with self._reg_lock:
@@ -440,16 +481,18 @@ class SpillCatalog:
                        if b.tier == StorageTier.HOST)
 
     # -- spilling -----------------------------------------------------------
-    def synchronous_spill(self, target_free: int) -> int:
+    def synchronous_spill(self, target_free: int, chip=ALL_CHIPS) -> int:
         """Demote device buffers (lowest priority first) until
-        `target_free` bytes are released (ref synchronousSpill)."""
+        `target_free` bytes are released (ref synchronousSpill): of any
+        chip, or of one chip's residents alone."""
         # pinned scan batches go first: dropping them frees real HBM at
         # zero serialization cost (they rebuild from host Arrow on miss)
-        freed = self._evict_pinned(target_free)
+        freed = self._evict_pinned(target_free, chip)
         with self._reg_lock:
             candidates = sorted(
                 (b for b in self._buffers.values()
-                 if b.tier == StorageTier.DEVICE),
+                 if b.tier == StorageTier.DEVICE and
+                 (chip is ALL_CHIPS or b.chip == chip)),
                 key=lambda b: b.priority)
             for b in candidates:
                 if freed >= target_free:
@@ -478,9 +521,13 @@ class SpillCatalog:
             used -= sz
 
     def maybe_spill(self):
-        over = self.device_bytes_registered() - self.device_budget
-        if over > 0:
-            self.synchronous_spill(over)
+        """Each chip answers for what lies on it: one over its budget
+        spills its own residents, the others keep theirs."""
+        for chip in self._chips():
+            over = self.device_bytes_registered(chip) - \
+                self.chip_budgets.get(chip, self.device_budget)
+            if over > 0:
+                self.synchronous_spill(over, chip)
 
 
 def is_oom_error(ex: Exception) -> bool:

@@ -174,13 +174,30 @@ _ROLES = frozenset((
     "rowpos", "count", "expand", "unmatched", "spec"))
 
 
-def program_name(key: tuple) -> str:
+#: the SPMD steps are keyed by the stage class that traces them
+#: (parallel/distributed.py); a trace names them by the operator that
+#: plans and dispatches them (parallel/ici_exec.py)
+_OPERATOR_OF = {
+    "DistributedAggregate": "IciAggregateExec",
+    "DistributedSort": "IciSortExec",
+    "DistributedHashJoin": "IciJoinExec",
+    "DistributedExchange": "IciExchangeExec",
+}
+
+
+def program_name(key: tuple, fn=None) -> str:
     """``<exec kind>[.<role>]`` for a process_jit key: the name the
     program carries into XLA (module ``jit_<name>``), so a device trace
     says which operator built it — and keeps saying so when an edit to
-    the program changes its fingerprint."""
+    the program changes its fingerprint.  A helper program that several
+    operators share says whose it is through its function's
+    ``program_name`` (the mesh stages' reshard); no key holds a name."""
+    given = getattr(fn, "program_name", None)
+    if given:
+        return given
     kind = _exec_kind(key)
     last = next((p for p in reversed(key) if isinstance(p, str)), kind)
+    kind = _OPERATOR_OF.get(kind, kind)
     return f"{kind}.{last}" if last in _ROLES else kind
 
 
@@ -188,7 +205,7 @@ def _name_program(fn, key: tuple):
     """Name `fn` after the operator that built it, before jax.jit reads
     the name.  Call sites hand process_jit lambdas; a callable that
     takes no name (a partial, a bound method) stays as it is."""
-    name = program_name(key)
+    name = program_name(key, fn)
     try:
         fn.__name__ = fn.__qualname__ = name
     except (AttributeError, TypeError):
@@ -609,7 +626,9 @@ class CompileObservatory:
         """Register one program build; returns the classified cause.
         `lane_moves` is what tracing the program raised of
         `ops/carry.lane_move_counts` (lane_moves_sorted,
-        lane_moves_gathered, sort_passes); None where the trace was not
+        lane_moves_gathered, sort_passes) and of
+        `parallel/alltoall.wire_byte_counts` (ici_wire_bytes, what one
+        dispatch puts on the interconnect); None where the trace was not
         this build's own."""
         moves = lane_moves or {}
         shape_hash, dtype_sig, cap_sig, canon_caps = \
@@ -716,10 +735,48 @@ class CompileObservatory:
                 "prewarm": dict(self.prewarm_stats)
                 if self.prewarm_stats else None,
                 # one record a program (exec kind, hashes, cause,
-                # seconds, and how its rows move: lane_moves_sorted,
-                # lane_moves_gathered, sort_passes)
+                # seconds, how its rows move: lane_moves_sorted,
+                # lane_moves_gathered, sort_passes, and what a dispatch
+                # sends between chips: ici_wire_bytes)
                 "programs": [dict(p) for p in self._programs.values()],
             }
+
+
+# ---------------------------------------------------------------------------
+# what tracing a program counts, and what dispatching it sends
+# ---------------------------------------------------------------------------
+
+def _trace_counts() -> Dict[str, int]:
+    """The counts that tracing raises on this thread: how the program's
+    rows move (ops/carry) and what its collectives put on the wire
+    (parallel/alltoall).  The difference around one ``lower()`` is that
+    program's."""
+    from ..ops.carry import lane_move_counts
+    from ..parallel.alltoall import wire_byte_counts
+    return {**lane_move_counts(), **wire_byte_counts()}
+
+
+class _Dispatched(threading.local):
+    wire_bytes = 0
+
+
+_DISPATCHED = _Dispatched()
+
+
+def dispatched_wire_bytes() -> int:
+    """Wire bytes of the programs this thread has dispatched so far; a
+    mesh stage reads the difference around its dispatches."""
+    return _DISPATCHED.wire_bytes
+
+
+def _note_wire_bytes(nbytes: int) -> None:
+    if nbytes:
+        _DISPATCHED.wire_bytes += nbytes
+        _registry().counter(
+            "tpu_ici_wire_bytes_total",
+            "bytes the dispatched programs' collectives hand to the "
+            "interconnect (all_to_all, all_gather), summed over the "
+            "mesh; from static shapes").inc(nbytes)
 
 
 # ---------------------------------------------------------------------------
@@ -733,7 +790,7 @@ class _ProfiledJit:
 
     __slots__ = ("_obs", "_key", "_key_hash", "_canon_key", "_exec",
                  "_key_head", "_jitted", "_fn", "_compiled",
-                 "_prewarmed", "_traced_sigs", "_lock")
+                 "_prewarmed", "_traced_sigs", "_wire_bytes", "_lock")
 
     def __init__(self, obs: CompileObservatory, key: tuple, jitted,
                  fn=None):
@@ -750,6 +807,9 @@ class _ProfiledJit:
         # recipes, keyed by sharding-erased signature
         self._prewarmed: Dict[tuple, Any] = {}
         self._traced_sigs: set = set()  # aval sigs seen under a trace
+        # shape signature -> bytes one dispatch of that program puts on
+        # the interconnect; only programs with collectives are listed
+        self._wire_bytes: Dict[tuple, int] = {}
         self._lock = threading.Lock()
 
     def built_pids(self) -> List[Tuple[str, str]]:
@@ -765,7 +825,7 @@ class _ProfiledJit:
             return self._traced_call(args)
         fn = self._compiled.get(sig)
         if fn is not None:
-            return self._dispatch(fn, args)
+            return self._dispatch(fn, args, sig)
         if self._prewarmed and _on_default_device(sig):
             fn = self._prewarmed.get(_erase_sharding(sig))
             if fn is not None:
@@ -779,10 +839,14 @@ class _ProfiledJit:
                 return out
         return self._build_and_call(sig, args)
 
-    def _dispatch(self, fn, args):
+    def _dispatch(self, fn, args, sig=None):
         """The call into the compiled executable, as the span
         ``jit.dispatch:<exec kind>`` when either of the tracer's sinks
-        is on: the host's side of every program launch."""
+        is on: the host's side of every program launch.  A program with
+        collectives adds its wire bytes (found when it was traced) to
+        ``tpu_ici_wire_bytes_total``; no device value is read."""
+        if self._wire_bytes:
+            _note_wire_bytes(self._wire_bytes.get(sig, 0))
         if not _tracer.ANNOTATIONS_ON and _tracer.active_tracer() is None:
             return fn(*args)
         with _tracer.trace_span("jit.dispatch:" + self._exec):
@@ -821,20 +885,21 @@ class _ProfiledJit:
                 with _tracer.trace_span("jit.build:" + self._exec) as span:
                     fn = self._build(sig, args, span)
                 self._compiled[sig] = fn
-        return self._dispatch(fn, args)
+        return self._dispatch(fn, args, sig)
 
     def _build(self, sig, args, span):
-        from ..ops.carry import lane_move_counts
         t0 = time.perf_counter()
         hlo_bytes = 0
         hlo_hash = None
         # a lower or compile failure is the compiler's refusal of this
         # program: it surfaces here, once, with its message
-        before = lane_move_counts()
+        before = _trace_counts()
         lowered = self._jitted.lower(*args)
         lane_moves = {k: v - before[k]
-                      for k, v in lane_move_counts().items()}
+                      for k, v in _trace_counts().items()}
         span.set(**lane_moves)
+        if lane_moves["ici_wire_bytes"]:
+            self._wire_bytes[sig] = lane_moves["ici_wire_bytes"]
         t1 = time.perf_counter()
         trace_s = t1 - t0
         try:

@@ -28,6 +28,7 @@ shuffle when the accelerated transport cannot carry a batch
 
 from __future__ import annotations
 
+import threading
 from typing import List, Optional
 
 import jax
@@ -37,6 +38,42 @@ from .. import types as t
 from ..columnar.device import DeviceBatch, DeviceColumn
 from ..ops.carry import stable_argsort
 from ..ops.scan import cumsum_fast
+
+
+# ---------------------------------------------------------------------------
+# What a program's build puts on the wire (read by obs/compileprof)
+# ---------------------------------------------------------------------------
+
+class _WireCounts(threading.local):
+    bytes = 0     # bytes that leave a chip, summed over the mesh
+
+
+_WIRE = _WireCounts()
+
+
+def wire_byte_counts() -> dict:
+    """Bytes the collectives traced on this thread so far hand to the
+    interconnect, summed over the mesh, under the name a program's build
+    record gives them.  A figure of static shapes: tracing a program
+    raises it, so the difference around a `lower()` is what one dispatch
+    of that program sends."""
+    return {"ici_wire_bytes": _WIRE.bytes}
+
+
+def wire_all_to_all(x, axis_name: str, n_parts: int):
+    """Tiled all_to_all of a per-chip ``[n_parts, ...]`` send tensor: one
+    slice stays, the other ``n_parts - 1`` leave, on each of the
+    ``n_parts`` chips."""
+    _WIRE.bytes += x.size * x.dtype.itemsize * (n_parts - 1)
+    return jax.lax.all_to_all(x, axis_name, split_axis=0, concat_axis=0,
+                              tiled=True)
+
+
+def wire_all_gather(x, axis_name: str, n_parts: int):
+    """Tiled all_gather along axis 0: every chip's ``x`` goes to the
+    ``n_parts - 1`` others."""
+    _WIRE.bytes += x.size * x.dtype.itemsize * n_parts * (n_parts - 1)
+    return jax.lax.all_gather(x, axis_name, axis=0, tiled=True)
 
 
 def exchange_supported(dtypes) -> Optional[str]:
@@ -252,8 +289,7 @@ def exchange_by_pid(batch: DeviceBatch, pids, n_parts: int, axis_name: str,
     send_valid = j[None, :] < counts[:, None]                  # [P, slot]
     src_row = order[jnp.clip(send_pos, 0, cap - 1)]            # [P, slot]
 
-    a2a = lambda x: jax.lax.all_to_all(  # noqa: E731
-        x, axis_name, split_axis=0, concat_axis=0, tiled=True)
+    a2a = lambda x: wire_all_to_all(x, axis_name, n_parts)  # noqa: E731
 
     recv_valid = a2a(send_valid)
     flat_rows = n_parts * slot
@@ -327,7 +363,7 @@ def allgather_batch(batch: DeviceBatch, axis_name: str,
     each device ends up with the concatenation of all shards, valid rows
     compacted to the front."""
     cap = batch.capacity
-    ag = lambda x: jax.lax.all_gather(x, axis_name, axis=0, tiled=True)  # noqa: E731
+    ag = lambda x: wire_all_gather(x, axis_name, n_parts)  # noqa: E731
     live = batch.row_mask()
     flat_rows = n_parts * cap
     valid_flat = ag(live)

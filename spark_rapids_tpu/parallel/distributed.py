@@ -30,7 +30,8 @@ from ..columnar.device import (DEFAULT_ROW_BUCKETS, DeviceBatch,
 from ..expr.core import EvalContext
 from ..shuffle.partitioning import HashPartitioning
 from .alltoall import (allgather_batch, allgather_supported,
-                       exchange_by_pid, exchange_supported)
+                       exchange_by_pid, exchange_supported,
+                       wire_all_gather)
 from .mesh import DATA_AXIS, build_mesh
 
 
@@ -397,8 +398,8 @@ class DistributedSort:
         cand = sorted_w0[jnp.clip(q, 0, cap - 1)]
         # every shard contributes candidates; global splitters are the
         # n_dev-quantiles of the gathered candidate set
-        all_cand = jax.lax.all_gather(cand, self.axis, axis=0,
-                                      tiled=True)          # [(n_dev-1)*n_dev]
+        all_cand = wire_all_gather(cand, self.axis,
+                                   n_dev)                  # [(n_dev-1)*n_dev]
         all_sorted = jnp.sort(all_cand)
         m = all_cand.shape[0]
         pick = (jnp.arange(1, n_dev, dtype=jnp.int32) * m) // n_dev

@@ -14,6 +14,7 @@ exchange.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Iterator, List, Optional
 
 import numpy as np
@@ -23,18 +24,48 @@ from .. import config as cfg
 from ..exec.base import (NUM_OUTPUT_BATCHES, NUM_OUTPUT_ROWS, OP_TIME, TPU,
                          Batch, Exec, MetricTimer, to_host_batch)
 from ..columnar.interop import to_arrow_schema
-from ..obs.tracer import trace_event
+from ..obs.tracer import trace_span
 
 
-def _note_stage(op: str, path: str, chips: int) -> None:
-    """One ICI stage ran: flight-recorder event + the continuous
-    stacked-vs-host decision counter (a drift toward `host` is the
-    ICI reshard quietly degrading — the watchdog's signal)."""
-    trace_event("ici.stage", op=op, path=path, chips=chips)
+#: how a stage's input reached the mesh: shards taken where they lie,
+#: one batch resharded on device, or staged through host Arrow
+RESIDENT, STACKED, HOST = "resident", "stacked", "host"
+
+
+class _StageNote:
+    """What a stage says of itself inside its ``ici.stage`` span."""
+
+    __slots__ = ("operator", "path", "rows")
+
+    def __init__(self, operator: str):
+        self.operator = operator     # the exec's class, for program names
+        self.path = HOST
+        self.rows = None
+
+
+@contextlib.contextmanager
+def _stage(operator: Exec, op: str, chips: int):
+    """One ICI stage: the span ``ici.stage:<op>`` around assembling the
+    input and dispatching the step (never around a ``yield``), with the
+    path taken, the input's rows where the host knows them, and the
+    bytes its programs put on the wire (the static figure of each
+    dispatched program, obs/compileprof); and the continuous
+    decision counter (a drift toward `host` is the device-resident
+    edge quietly degrading: the watchdog's signal)."""
+    from ..obs import compileprof
     from ..obs import metrics as m
+    note = _StageNote(type(operator).__name__)
+    wire0 = compileprof.dispatched_wire_bytes()
+    with trace_span("ici.stage:" + op, op=op, chips=chips) as sp:
+        yield note
+        attrs = {"path": note.path,
+                 "wire_bytes": compileprof.dispatched_wire_bytes() - wire0}
+        if note.rows is not None:
+            attrs["rows"] = note.rows
+        sp.set(**attrs)
     m.counter("tpu_ici_stage_total",
               "fused mesh stages by operator and data path",
-              ("op", "path")).labels(op=op, path=path).inc()
+              ("op", "path")).labels(op=op, path=note.path).inc()
 
 
 class IciAggregateExec(Exec):
@@ -81,32 +112,40 @@ class IciAggregateExec(Exec):
         return self.final_agg.determinism()
 
     def execute_partition(self, pid, ctx) -> Iterator[Batch]:
-        source = self.children[0]
+        out, on_mesh = _run_source_stage(self, "aggregate", self._dagg, ctx)
+        yield from (_emit_stacked if on_mesh else _emit_table)(self, out)
+
+
+def _run_source_stage(self, op: str, dist, ctx):
+    """A one-source stage (aggregate, sort) inside its span: the source
+    stacked on the mesh and the step dispatched over it, or, for a schema
+    the mesh edge cannot carry, staged through host Arrow.  Returns the
+    output and whether it is a stacked device batch (else a table)."""
+    source = self.children[0]
+    with _stage(self, op, dist.n_dev) as note:
         stacked = _gather_source_stacked(
             source, ctx, source.output_names, source.output_types,
-            self.mesh)
+            self.mesh, note)
         if stacked is not None:
-            _note_stage("aggregate", "stacked", self._dagg.n_dev)
             self.stage_input_devices = _device_count(stacked)
             with MetricTimer(self.metrics[OP_TIME]):
-                out = self._dagg._compiled(stacked)
-            yield from _emit_stacked(self, out)
-            return
-        _note_stage("aggregate", "host", self._dagg.n_dev)
+                return dist._compiled(stacked), True
         tbl = _gather_source_table(source, ctx, source.output_names,
                                    source.output_types)
-        shards = _shard_table(tbl, self._dagg.n_dev)
+        note.rows = tbl.num_rows
+        shards = _shard_table(tbl, dist.n_dev)
         with MetricTimer(self.metrics[OP_TIME]):
-            out = self._dagg.run(shards)
-        yield from _emit_table(self, out)
+            return dist.run(shards), False
 
 
-def _device_count(stacked) -> int:
-    """Distinct devices holding a stacked stage input's lanes."""
+def _device_count(batches) -> int:
+    """Distinct devices holding the device lanes of a batch, stacked or
+    not, or of several."""
     import jax
     devs = set()
-    for leaf in jax.tree_util.tree_leaves(stacked):
-        devs |= leaf.devices()
+    for leaf in jax.tree_util.tree_leaves(batches):
+        if isinstance(leaf, jax.Array):
+            devs |= leaf.devices()
     return len(devs)
 
 
@@ -154,18 +193,75 @@ def _stackable_schema(dtypes) -> bool:
     return all(flat(dt) or spannable(dt) for dt in dtypes)
 
 
-def _gather_source_stacked(source: Exec, ctx, names, dtypes, mesh):
-    """Device-resident scan->mesh edge: collect the source's DEVICE
-    batches, concatenate on device, reshape every lane to
-    (n_dev, shard_cap) with ONE jitted program, and hand shard ``i`` to
-    mesh device ``i`` (device-to-device) — rows never stage through
-    host Arrow (ref RapidsShuffleInternalManagerBase.scala:74: shuffle
-    input stays device-resident end-to-end).  String/binary
-    lanes rebase: each shard slices its char range at the source's char
-    capacity (conservative static shape; a balanced shard holds ~1/n of
-    the bytes) and rewrites offsets relative to its slice.  Returns the
-    stacked DeviceBatch, or None for schemas the reshard cannot carry
-    (arrays/maps — the host path remains)."""
+def _stack_resident(batches, mesh):
+    """The stacked stage input from shards that already lie where the
+    stage wants them: batch ``i`` on mesh device ``i`` alone, every batch
+    of the same lanes, shapes and types (one row capacity, one char
+    capacity a string column).  Each lane gets its leading axis on its
+    own chip and the global arrays are assembled from those buffers:
+    nothing is concatenated, resharded or moved between chips.  None
+    where the batches are not so laid out."""
+    import jax
+    import jax.numpy as jnp
+    from ..columnar.device import DeviceBatch
+    from .mesh import mesh_sharding
+
+    devices = list(mesh.devices.flat)
+    if len(batches) != len(devices):
+        return None
+    flat = [jax.tree_util.tree_flatten(b.columns) for b in batches]
+    leaves0, treedef = flat[0]
+    for (leaves, td), dev in zip(flat, devices):
+        if td != treedef:
+            return None
+        for x, x0 in zip(leaves, leaves0):
+            if not isinstance(x, jax.Array) or x.shape != x0.shape or \
+                    x.dtype != x0.dtype or x.devices() != {dev}:
+                return None
+    sharding = mesh_sharding(mesh)
+    n_dev = len(devices)
+
+    def stack(*xs):
+        return jax.make_array_from_single_device_arrays(
+            (n_dev,) + xs[0].shape, sharding, [x[None] for x in xs])
+
+    columns = jax.tree_util.tree_unflatten(
+        treedef, [stack(*xs) for xs in zip(*(lv for lv, _ in flat))])
+    rows = stack(*[
+        jnp.asarray(b.num_rows, jnp.int32) if isinstance(b.num_rows,
+                                                          jax.Array)
+        else jax.device_put(np.int32(b.num_rows), dev)
+        for b, dev in zip(batches, devices)])
+    return DeviceBatch(columns, rows, batches[0].names)
+
+
+def _host_rows(batches) -> Optional[int]:
+    """Rows of the batches where every count is a host scalar (a scan's
+    are); None rather than a device read."""
+    import jax
+    if any(isinstance(b.num_rows, jax.Array) for b in batches):
+        return None
+    return sum(int(b.num_rows) for b in batches)
+
+
+def _gather_source_stacked(source: Exec, ctx, names, dtypes, mesh, note):
+    """Device-resident scan->mesh edge: the source's DEVICE batches as
+    one stacked batch, every lane ``(n_dev, shard_cap)`` with shard
+    ``i`` on mesh device ``i``; rows never stage through host Arrow
+    (ref RapidsShuffleInternalManagerBase.scala:74: shuffle input stays
+    device-resident end-to-end).  Where the source yields one batch a
+    mesh device, each already on its device and all of one shape (a
+    table the scan placed for the ICI transport), the shards are taken
+    where they lie (``_stack_resident``).  Every other layout (more
+    partitions than chips, unequal capacities, batches on another
+    device) is brought onto the mesh's first chip, concatenated there,
+    resharded with ONE jitted program and handed out device-to-device.
+    There string/binary lanes rebase: each shard slices its char range
+    at the source's char capacity (conservative static shape; a balanced
+    shard holds ~1/n of the bytes) and rewrites offsets relative to its
+    slice.  ``note`` learns the path taken.  Returns the stacked
+    DeviceBatch, or None for schemas the reshard cannot carry
+    (span-inside-struct and deeper: the host path remains)."""
     if not _stackable_schema(dtypes):
         return None
     import jax
@@ -182,6 +278,16 @@ def _gather_source_stacked(source: Exec, ctx, names, dtypes, mesh):
     for spid in range(source.num_partitions):
         for b in source.execute_partition(spid, ctx):
             batches.append(b)
+    stacked = _stack_resident(batches, mesh)
+    if stacked is not None:
+        note.path, note.rows = RESIDENT, _host_rows(batches)
+        return stacked
+    note.path = STACKED
+    if _device_count([b.columns for b in batches]) > 1:
+        # partitions placed over the mesh that do not line up with it
+        first = mesh.devices.flat[0]
+        batches = [DeviceBatch(jax.device_put(b.columns, first),
+                               int(b.num_rows), b.names) for b in batches]
     batches = [b for b in batches if int(b.num_rows)]
     if not batches:
         schema = to_arrow_schema(names, dtypes)
@@ -191,7 +297,7 @@ def _gather_source_stacked(source: Exec, ctx, names, dtypes, mesh):
         batches = [batch_to_device(rb)]
     merged = concat_batches(jnp, batches, names, dtypes) \
         if len(batches) > 1 else batches[0]
-    total = int(merged.num_rows)
+    total = note.rows = int(merged.num_rows)
     # per-shard row budget rounds up to a power of two so distinct totals
     # share compiled reshard programs (static-shape discipline) while
     # shard imbalance stays bounded by 2x (the sparse row-bucket ladder
@@ -265,6 +371,9 @@ def _gather_source_stacked(source: Exec, ctx, names, dtypes, mesh):
                 - jnp.arange(n_dev, dtype=jnp.int32) * np.int32(per),
                 0, np.int32(per))
             return DeviceBatch(cols, rows, b.names)
+        # one program for whichever stage asks first; it carries that
+        # operator's name into the trace (no key holds it)
+        reshard.program_name = note.operator + ".reshard"
         return reshard
     fn = process_jit(("ici_reshard", tuple(names),
                       tuple(repr(d) for d in dtypes), in_cap, n_dev, per,
@@ -273,17 +382,46 @@ def _gather_source_stacked(source: Exec, ctx, names, dtypes, mesh):
     return jax.device_put(fn(merged), mesh_sharding(mesh))
 
 
+#: shards whose rows together fit this many are handed on as one batch:
+#: ``CoalesceBatchesExec``'s own default goal (exec/basic.py)
+COALESCE_GOAL_ROWS = 1 << 22
+
+
 def _emit_stacked(self, stacked) -> Iterator[Batch]:
-    """Yield per-shard device batches (mesh order) without host staging,
-    gathered device-to-device onto the mesh's first chip, where the
-    single-device operators downstream run."""
+    """Yield the stage's output as device batches on the mesh's first
+    chip, where the single-device operators downstream run, in mesh
+    order and without host staging.  A shard of the output has the
+    exchange's capacity (``n_parts`` x the input's) and its live rows in
+    front, and the host reads the shards' row counts here in any case:
+    so each shard is cut to the row bucket that holds its rows before it
+    moves, and shards that together fit ``CoalesceBatchesExec``'s goal
+    are concatenated into one batch, as that operator would if the
+    counts were still on the host when they reach it.  The operators
+    above then work once at the size of the rows, not ``n_dev`` times at
+    the exchange's capacity, and one answer is one fetch (a fetch a chip
+    of a few rows each picks its transfer plan, a program, by each
+    chip's own value range)."""
+    import jax
+    import jax.numpy as jnp
+    from ..columnar.device import (DEFAULT_ROW_BUCKETS, bucket_for,
+                                   shrink_batch)
+    from ..exec.concat import concat_batches
     from .distributed import unstack_shards
-    for b in unstack_shards(stacked, device=self.mesh.devices.flat[0]):
-        n = int(np.asarray(b.num_rows))
+    first = self.mesh.devices.flat[0]
+    rows = np.asarray(stacked.num_rows)       # one read for every shard
+    shards = []
+    for b, n in zip(unstack_shards(stacked), rows):
+        n = int(n)
         if n == 0:
             continue
-        out = Batch(b.columns, n, b.names)
-        self.metrics[NUM_OUTPUT_ROWS] += n
+        b = shrink_batch(b, bucket_for(n, DEFAULT_ROW_BUCKETS))
+        shards.append(Batch(jax.device_put(b.columns, first), n, b.names))
+    if len(shards) > 1 and \
+            sum(int(b.num_rows) for b in shards) <= COALESCE_GOAL_ROWS:
+        shards = [concat_batches(jnp, shards, self.output_names,
+                                 self.output_types)]
+    for out in shards:
+        self.metrics[NUM_OUTPUT_ROWS] += int(out.num_rows)
         self.metrics[NUM_OUTPUT_BATCHES] += 1
         yield out
 
@@ -338,25 +476,9 @@ class IciSortExec(Exec):
         return self.sort_exec.determinism()
 
     def execute_partition(self, pid, ctx) -> Iterator[Batch]:
-        source = self.children[0]
-        stacked = _gather_source_stacked(
-            source, ctx, source.output_names, source.output_types,
-            self.mesh)
-        if stacked is not None:
-            _note_stage("sort", "stacked", self._dsort.n_dev)
-            self.stage_input_devices = _device_count(stacked)
-            # shard i holds globally-ordered range i: emit in mesh order
-            with MetricTimer(self.metrics[OP_TIME]):
-                out = self._dsort._compiled(stacked)
-            yield from _emit_stacked(self, out)
-            return
-        _note_stage("sort", "host", self._dsort.n_dev)
-        tbl = _gather_source_table(source, ctx, source.output_names,
-                                   source.output_types)
-        shards = _shard_table(tbl, self._dsort.n_dev)
-        with MetricTimer(self.metrics[OP_TIME]):
-            out = self._dsort.run(shards)
-        yield from _emit_table(self, out)
+        out, on_mesh = _run_source_stage(self, "sort", self._dsort, ctx)
+        # shard i holds globally-ordered range i: emitted in mesh order
+        yield from (_emit_stacked if on_mesh else _emit_table)(self, out)
 
 
 class IciJoinExec(Exec):
@@ -398,29 +520,37 @@ class IciJoinExec(Exec):
     def execute_partition(self, pid, ctx) -> Iterator[Batch]:
         lsrc, rsrc = self.children
         n_dev = self._djoin.n_dev
-        # device-resident edge first: both sides reshard on device and
-        # the join consumes the stacked shards without host staging
-        ls = _gather_source_stacked(lsrc, ctx, lsrc.output_names,
-                                    lsrc.output_types, self.mesh)
-        rs = _gather_source_stacked(rsrc, ctx, rsrc.output_names,
-                                    rsrc.output_types, self.mesh) \
-            if ls is not None else None
-        if ls is not None and rs is not None:
-            _note_stage("join", "stacked", n_dev)
-            self.stage_input_devices = min(_device_count(ls),
-                                           _device_count(rs))
-            with MetricTimer(self.metrics[OP_TIME]):
-                out = self._djoin.run_stacked(ls, rs)
-            yield from _emit_table(self, out)
-            return
-        _note_stage("join", "host", n_dev)
-        lt = _gather_source_table(lsrc, ctx, lsrc.output_names,
-                                  lsrc.output_types)
-        rt = _gather_source_table(rsrc, ctx, rsrc.output_names,
-                                  rsrc.output_types)
-        with MetricTimer(self.metrics[OP_TIME]):
-            out = self._djoin.run(_shard_table(lt, n_dev),
-                                  _shard_table(rt, n_dev))
+        with _stage(self, "join", n_dev) as note:
+            # device-resident edge first: both sides reach the mesh on
+            # device and the join consumes the stacked shards without
+            # host staging
+            lnote, rnote = (_StageNote(note.operator),
+                            _StageNote(note.operator))
+            ls = _gather_source_stacked(lsrc, ctx, lsrc.output_names,
+                                        lsrc.output_types, self.mesh, lnote)
+            rs = _gather_source_stacked(rsrc, ctx, rsrc.output_names,
+                                        rsrc.output_types, self.mesh,
+                                        rnote) \
+                if ls is not None else None
+            if ls is not None and rs is not None:
+                # resident only where neither side was resharded
+                note.path = RESIDENT if lnote.path == rnote.path == RESIDENT \
+                    else STACKED
+                if lnote.rows is not None and rnote.rows is not None:
+                    note.rows = lnote.rows + rnote.rows
+                self.stage_input_devices = min(_device_count(ls),
+                                               _device_count(rs))
+                with MetricTimer(self.metrics[OP_TIME]):
+                    out = self._djoin.run_stacked(ls, rs)
+            else:
+                lt = _gather_source_table(lsrc, ctx, lsrc.output_names,
+                                          lsrc.output_types)
+                rt = _gather_source_table(rsrc, ctx, rsrc.output_names,
+                                          rsrc.output_types)
+                note.rows = lt.num_rows + rt.num_rows
+                with MetricTimer(self.metrics[OP_TIME]):
+                    out = self._djoin.run(_shard_table(lt, n_dev),
+                                          _shard_table(rt, n_dev))
         yield from _emit_table(self, out)
 
 
@@ -497,31 +627,30 @@ class IciExchangeExec(Exec):
             if hit is not None:
                 return hit
             source = self.children[0]
-            stacked = _gather_source_stacked(
-                source, ctx, source.output_names, source.output_types,
-                self.mesh)
-            _note_stage("exchange",
-                        "stacked" if stacked is not None else "host",
-                        self._dex.n_dev)
-            with MetricTimer(self.metrics[OP_TIME]):
-                if stacked is not None:
-                    out = self._dex.run_stacked(stacked)
-                    from .distributed import unstack_shards
-                    shards = unstack_shards(
-                        out, device=self.mesh.devices.flat[0])
-                else:
-                    tbl = _gather_source_table(source, ctx,
-                                               source.output_names,
-                                               source.output_types)
-                    tables = self._dex.run(
-                        _shard_table(tbl, self._dex.n_dev))
-                    from ..columnar.device import batch_to_device
-                    shards = []
-                    for tb in tables:
-                        rbs = tb.combine_chunks().to_batches()
-                        shards.append(
-                            batch_to_device(rbs[0], xp=self.xp) if rbs
-                            else None)
+            with _stage(self, "exchange", self._dex.n_dev) as note:
+                stacked = _gather_source_stacked(
+                    source, ctx, source.output_names, source.output_types,
+                    self.mesh, note)
+                with MetricTimer(self.metrics[OP_TIME]):
+                    if stacked is not None:
+                        out = self._dex.run_stacked(stacked)
+                        from .distributed import unstack_shards
+                        shards = unstack_shards(
+                            out, device=self.mesh.devices.flat[0])
+                    else:
+                        tbl = _gather_source_table(source, ctx,
+                                                   source.output_names,
+                                                   source.output_types)
+                        note.rows = tbl.num_rows
+                        tables = self._dex.run(
+                            _shard_table(tbl, self._dex.n_dev))
+                        from ..columnar.device import batch_to_device
+                        shards = []
+                        for tb in tables:
+                            rbs = tb.combine_chunks().to_batches()
+                            shards.append(
+                                batch_to_device(rbs[0], xp=self.xp) if rbs
+                                else None)
             self._memo[key] = shards
             return shards
 
@@ -625,4 +754,27 @@ def install_ici_stages(root: Exec, conf: cfg.RapidsConf) -> Exec:
                 pass
         return node
 
-    return wrap_exchanges(rewrite(root))
+    root = wrap_exchanges(rewrite(root))
+    root.foreach(_keep_sources_on_mesh)
+    return root
+
+
+def _keep_sources_on_mesh(node: Exec) -> None:
+    """An in-memory table that a mesh stage reads through partition-local
+    operators alone is kept where the stage wants it: partition ``i`` on
+    mesh device ``i % n_dev`` (``LocalScanExec.mesh_resident``), so the
+    stage takes its shards where they lie.  A scan that anything else
+    reads (an operator that brings partitions together on one device: a
+    host exchange, a broadcast, a union) stays on the default device, as
+    on one chip."""
+    if not isinstance(node, (IciAggregateExec, IciSortExec, IciJoinExec,
+                             IciExchangeExec)):
+        return
+    from ..exec.basic import (CoalesceBatchesExec, FilterExec,
+                              LocalScanExec, ProjectExec)
+    for source in node.children:
+        while isinstance(source, (ProjectExec, FilterExec,
+                                  CoalesceBatchesExec)):
+            source = source.children[0]
+        if isinstance(source, LocalScanExec) and source.placement == TPU:
+            source.mesh_resident = True

@@ -364,7 +364,7 @@ def test_no_program_of_the_main_path_is_a_lambda(built_programs):
     (("3.2.0", "fetch_pack", (("x", "long"),), 1024, (), ()),
      "fetch_pack"),
     (("3.2.0", "DistributedAggregate", "data", (0, 1)),
-     "DistributedAggregate"),
+     "IciAggregateExec"),
 ])
 def test_program_name_is_kind_and_trailing_role(key, name):
     from spark_rapids_tpu.obs.compileprof import program_name
