@@ -1,7 +1,10 @@
 """On the chip: that a lane moved by sort pass (`ops/carry.move_lanes`)
-equals the gather by the order, elementwise, for every lane type; and what
-a sort pass, a gather and the compaction's prefix sum cost at the bucket
-sizes of the records.  Run through the chip tool:
+equals the gather by the order, elementwise, for every lane type; that the
+exchange's contiguous slices and copies (`parallel/alltoall._send_runs`,
+`_receive_runs`) equal the gathers they replaced at the mesh step's sizes;
+and what a sort pass, a gather, the compaction's prefix sum, the slices and
+the copies cost at the bucket sizes of the records.  Run through the chip
+tool:
 
     chiprun --timeout 900 -- python devtools/chip_lane_moves.py
 
@@ -87,6 +90,119 @@ def check_moves() -> bool:
     return ok
 
 
+N_PARTS = 4
+
+
+def exchange_case(rng, cap):
+    """Destinations and the rows a chip would receive: (pid_key with dead
+    rows parked at N_PARTS, counts of a made-up arrival [N_PARTS])."""
+    pid = rng.integers(0, N_PARTS, cap).astype(np.int32)
+    pid[rng.integers(0, 20, cap) == 0] = N_PARTS
+    arrived = rng.integers(cap // 8, cap // 2, N_PARTS).astype(np.int32)
+    arrived[1] = 0                      # a peer that sends nothing
+    return pid, arrived
+
+
+def send_both(pid_key, x):
+    """A lane's [N_PARTS, cap] send tensor by slices of the pid-sorted
+    lane, and by the gather of the source rows that PR 27's exchange made."""
+    from spark_rapids_tpu.parallel import alltoall as a2a
+    cap = x.shape[0]
+    counts, starts = a2a._counts_starts(pid_key, N_PARTS)
+    j = jnp.arange(cap, dtype=jnp.int32)
+    send_valid = j[None, :] < counts[:, None]
+    key = pid_key.astype(jnp.uint32)
+    _, (by_pid,) = carry.sort_lanes(jnp, [key], [x], cap, need_order=False)
+    sliced = a2a._send_runs(by_pid, starts, send_valid, cap)
+    order = carry.stable_argsort(jnp, [key], cap)
+    src_row = order[jnp.clip(starts[:, None] + j[None, :], 0, cap - 1)]
+    gathered = jnp.where(send_valid, x[src_row], jnp.zeros((), x.dtype))
+    return sliced, gathered
+
+
+def receive_both(arrived, recv):
+    """A received [N_PARTS, slot] tensor packed by contiguous copies, by a
+    compaction's sort passes, and by the gather of PR 27's exchange."""
+    from spark_rapids_tpu.parallel import alltoall as a2a
+    n_parts, slot = recv.shape
+    flat_rows = n_parts * slot
+    valid = (jnp.arange(slot, dtype=jnp.int32)[None, :]
+             < arrived[:, None]).reshape(flat_rows)
+    starts = cumsum_fast(jnp, arrived) - arrived
+    out_live = jnp.arange(flat_rows, dtype=jnp.int32) < jnp.sum(arrived)
+    copied = a2a._receive_runs(recv, starts, out_live)
+    zero = jnp.zeros((), recv.dtype)
+    _, _, (passed,) = carry.compact_rows(jnp, valid, (), flat_rows,
+                                         extras=[recv.reshape(flat_rows)])
+    passed = jnp.where(out_live, passed, zero)
+    ord2 = carry.stable_argsort(jnp, [~valid], flat_rows)
+    gathered = jnp.where(out_live, recv.reshape(flat_rows)[ord2], zero)
+    return copied, passed, gathered
+
+
+def check_runs(cap: int) -> bool:
+    """Slices and copies against the gathers for every lane type, at the
+    step's own sizes: `cap` rows a chip, N_PARTS x cap received.  One
+    program a side, so that the sort's signature compiles twice."""
+    rng = np.random.default_rng(28)
+    pid, arrived = exchange_case(rng, cap)
+    lanes = {k: jnp.asarray(v) for k, v in lanes_to_check(rng, cap).items()}
+
+    def differing(a, b):
+        return jnp.sum(~same_on_device(a, b))
+
+    def send(p, xs):
+        out = {k: send_both(p, x) for k, x in xs.items()}
+        return ({k: differing(s, g) for k, (s, g) in out.items()},
+                {k: s for k, (s, _) in out.items()})
+
+    def receive(n, rs):
+        out = {k: receive_both(n, r) for k, r in rs.items()}
+        return {k: (differing(c, g), differing(p, g))
+                for k, (c, p, g) in out.items()}
+    bad_send, sent = jax.jit(send)(jnp.asarray(pid), lanes)
+    bad_recv = jax.jit(receive)(jnp.asarray(arrived), sent)
+    ok = True
+    for name in lanes:
+        bad = (int(bad_send[name]), int(bad_recv[name][0]),
+               int(bad_recv[name][1]))
+        say(check="runs_equal_gathers", lane=name, rows=cap,
+            send_differing=bad[0], receive_differing=bad[1],
+            receive_by_pass_differing=bad[2])
+        ok = ok and bad == (0, 0, 0)
+    return ok
+
+
+def run_costs(cap: int):
+    """The slices of a sorted lane and the copies of a received tensor,
+    alone, beside the compaction's passes over the same tensor."""
+    from spark_rapids_tpu.parallel import alltoall as a2a
+    rng = np.random.default_rng(cap + 1)
+    pid, arrived = exchange_case(rng, cap)
+    counts = np.bincount(pid, minlength=N_PARTS + 1)[:N_PARTS]
+    starts = jnp.asarray((np.cumsum(counts) - counts).astype(np.int32))
+    j = np.arange(cap, dtype=np.int32)
+    send_valid = jnp.asarray(j[None, :] < counts[:, None])
+    arrived = jnp.asarray(arrived)
+    flat_rows = N_PARTS * cap
+    recv_starts = cumsum_fast(jnp, arrived) - arrived
+    out_live = jnp.arange(flat_rows, dtype=jnp.int32) < jnp.sum(arrived)
+    slices = jax.jit(lambda x, s, v: a2a._send_runs(x, s, v, cap))
+    copies = jax.jit(a2a._receive_runs)
+    for name in ("int32", "float64", "bool"):
+        x = jnp.asarray(lanes_to_check(rng, cap)[name])
+        say(cost=f"send_slices_{name}_s", rows=cap,
+            seconds=timed(slices, x, starts, send_valid))
+        sent = slices(x, starts, send_valid)
+        say(cost=f"receive_copies_{name}_s", rows=flat_rows,
+            seconds=timed(copies, sent, recv_starts, out_live))
+    # the same tensor packed by a compaction's passes (`sent` is the bool
+    # lane's here: one word, so one pass and the prefix sum)
+    passes = jax.jit(lambda n, r: receive_both(n, r)[1])
+    say(cost="receive_passes_bool_s", rows=flat_rows,
+        seconds=timed(passes, arrived, sent, reps=2))
+
+
 def timed(fn, *args, reps=3):
     out = fn(*args)
     jax.block_until_ready(out)
@@ -122,6 +238,10 @@ def main() -> int:
         return 2
     say(device=dev.device_kind, platform=dev.platform)
     ok = check_moves()
+    if "--runs" in sys.argv:        # the mesh step's slices and copies alone
+        ok = check_runs(4 * M1) and ok
+        run_costs(4 * M1)
+        return 0 if ok else 1
     for cap in (M1, 16_777_216, 33_554_432):
         costs(cap)
     return 0 if ok else 1

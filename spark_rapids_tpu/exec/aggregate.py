@@ -47,6 +47,25 @@ from .concat import concat_batches
 from ..ops.scan import cumsum_fast
 
 
+def _null_where(xp, col: DeviceColumn, valid) -> DeviceColumn:
+    """A moved column as `gather_column` would have left it: null where
+    `valid` is false or a struct above it is null, zero under every null
+    of a row-aligned lane.  (A node with offsets was gathered, and its
+    spans are settled.)"""
+    v = valid if col.validity is None else valid & col.validity
+    if col.offsets is not None:
+        return DeviceColumn(col.dtype, data=col.data, validity=v,
+                            offsets=col.offsets, children=col.children)
+
+    def zeroed(x):
+        return None if x is None else xp.where(v, x,
+                                               xp.zeros((), dtype=x.dtype))
+    return DeviceColumn(col.dtype, data=zeroed(col.data), validity=v,
+                        data_hi=zeroed(col.data_hi),
+                        children=tuple(_null_where(xp, c, v)
+                                       for c in col.children))
+
+
 def _group_reduce(xp, key_cols: List[DeviceColumn],
                   value_cols: List[DeviceColumn], ops: List[str],
                   cap: int, live, global_agg: bool):
@@ -554,10 +573,15 @@ class TpuHashAggregateExec(Exec):
         so the within-group fold order is content-determined (the
         stable_merge canonical keyed merge).  Nested buffer columns
         (collect_list arrays) contribute no words — their element
-        order is declared order_dependent anyway."""
+        order is declared order_dependent anyway.
+
+        The rows travel with the sort (`carry.sort_rows`: a rank from
+        the words' digits, then a sort pass per 32-bit word of row-aligned
+        lane); only columns with offsets are gathered by the order."""
+        from ..ops import carry
         cap = batch.capacity
         live = xp.arange(cap, dtype=np.int32) < batch.num_rows
-        words: List = [(~live).astype(xp.uint64)]
+        words: List = [~live]  # padding last: one bit of a digit
         for kc in batch.columns[:len(self.grouping)]:
             words += seg.key_words_for_column(xp, kc, live,
                                               for_grouping=True)
@@ -567,10 +591,10 @@ class TpuHashAggregateExec(Exec):
                                                   for_grouping=True)
             except Exception:
                 continue  # nested buffer: no sortable words
-        order = seg.lexsort(xp, words, cap)
-        from ..ops.gather import gather_batch
-        out = gather_batch(xp, batch, order, live[order], batch.num_rows)
-        return DeviceBatch(out.columns, batch.num_rows, batch.names)
+        _, cols, (live_sorted,) = carry.sort_rows(
+            xp, words, batch.columns, cap, extras=[live], need_order=False)
+        return DeviceBatch([_null_where(xp, c, live_sorted) for c in cols],
+                           batch.num_rows, batch.names)
 
     def _evaluate_batch(self, xp, batch: Batch) -> Batch:
         """buffers -> final results (Final/Complete modes)."""

@@ -11,14 +11,23 @@ no handshake protocol.
 Design (static shapes, one compile per schema):
 
   1. Each device stably sorts its rows by destination partition id and
-     computes per-peer counts/starts — the on-device slicing step.
-  2. Every column leaf is gathered into a ``[n_parts, slot]`` send tensor
-     (slot = per-peer row budget; default = local capacity so no row can
-     overflow).  Strings additionally pack their bytes into a
+     computes per-peer counts/starts — the on-device slicing step.  The
+     rows travel with the sort: one rank, then a sort pass per 32-bit word
+     of row-aligned lane (ops/carry.py; a gather is row-at-a-time on this
+     chip and a pass is not).
+  2. In the sorted lane a destination's rows are a run, so its row of
+     the ``[n_parts, slot]`` send tensor is a contiguous slice (slot =
+     per-peer row budget; default = local capacity so no row can
+     overflow).  Columns with offsets are gathered through a
+     ``[n_parts, slot]`` source-row index, built only when one is there;
+     strings additionally pack their bytes into a
      ``[n_parts, char_slot]`` tensor via a vmapped searchsorted layout.
   3. One ``lax.all_to_all`` per leaf rides the ICI mesh axis.
-  4. The receiver stably compacts valid rows to the front; strings are
-     re-assembled into (offsets, chars) form.
+  4. The receiver packs valid rows to the front, peer by peer: each
+     peer's valid rows are a prefix of its row of the receive tensor, so
+     they are ``n_parts`` contiguous copies at the running sums of the
+     received counts.  Strings, arrays and maps are re-assembled into
+     (offsets, children) form through the receive order.
 
 Variable-width nested types (arrays/structs) fall back to the host
 shuffle path, mirroring the reference's fallback to the stock Spark
@@ -36,7 +45,7 @@ import jax.numpy as jnp
 
 from .. import types as t
 from ..columnar.device import DeviceBatch, DeviceColumn
-from ..ops.carry import stable_argsort
+from ..ops.carry import carriable, sort_lanes, stable_argsort
 from ..ops.scan import cumsum_fast
 
 
@@ -245,14 +254,61 @@ def _string_receive(recv_chars, recv_len, ord2, n_parts: int, slot: int):
     return out_chars, out_offs
 
 
+def _row_lanes(col: DeviceColumn, out: list) -> list:
+    """The row-aligned lanes of a column's tree, appended to `out`: every
+    node's validity down to the first node with offsets, and the data
+    words of the nodes without."""
+    if col.validity is not None:
+        out.append(col.validity)
+    if col.offsets is None:
+        out += [x for x in (col.data, col.data_hi) if x is not None]
+        for ch in col.children:
+            _row_lanes(ch, out)
+    return out
+
+
+def _send_runs(x, starts, send_valid, slot: int):
+    """The ``[n_parts, slot]`` send tensor of a lane whose rows are sorted
+    by destination: destination ``p``'s rows are the run that begins at
+    ``starts[p]``, so its row of the tensor is a contiguous slice (the
+    lane padded by ``slot`` zeros, for a run may begin anywhere), cut to
+    the run's length by ``send_valid``."""
+    padded = jnp.concatenate([x, jnp.zeros((slot,), x.dtype)])
+    runs = [jax.lax.dynamic_slice(padded, (starts[p],), (slot,))
+            for p in range(int(starts.shape[0]))]
+    return jnp.where(send_valid, jnp.stack(runs), jnp.zeros((), x.dtype))
+
+
+def _receive_runs(recv, recv_starts, out_live):
+    """A received ``[n_parts, slot]`` tensor with its valid rows packed to
+    the front, peer by peer.  Each peer's valid rows are a prefix of its
+    row of the tensor, so peer ``p``'s row is copied whole to the running
+    sum of the counts before it, in rising ``p``: each copy overwrites
+    the dead tail of the one before, and ``out_live`` zeroes the last
+    one's."""
+    out = recv.reshape(-1)          # peer 0's rows are where they belong
+    for p in range(1, int(recv.shape[0])):
+        out = jax.lax.dynamic_update_slice(out, recv[p], (recv_starts[p],))
+    return jnp.where(out_live, out, jnp.zeros((), out.dtype))
+
+
 def exchange_by_pid(batch: DeviceBatch, pids, n_parts: int, axis_name: str,
                     slot: Optional[int] = None,
                     on_overflow: str = "error"):
     """Redistribute rows so the device at mesh position ``p`` along
-    ``axis_name`` receives every row with ``pids == p``.
+    ``axis_name`` receives every row with ``pids == p``, in source-device
+    order and, within a source, in source order; padding is zero and
+    invalid.
 
     Must be called inside ``shard_map`` over a mesh with that axis (size
     ``n_parts``).  Returns a batch of capacity ``n_parts * slot``.
+
+    No row-aligned lane is gathered: the rows are sorted by destination
+    once (`carry.sort_lanes`: a rank and a sort pass per 32-bit word),
+    sliced into the send tensors (`_send_runs`) and packed on arrival by
+    contiguous copies (`_receive_runs`).  Only a batch that holds a
+    column with offsets builds the ``[n_parts, slot]`` source-row index
+    and the receive order its span layouts read.
 
     The send tensors are ``[n_parts, slot]`` — ``n_parts`` times the
     per-peer budget — so ``slot`` is the exchange's memory knob.  With
@@ -281,28 +337,45 @@ def exchange_by_pid(batch: DeviceBatch, pids, n_parts: int, axis_name: str,
     slot = slot or cap
     live = batch.row_mask()
     pid_key = jnp.where(live, pids.astype(jnp.int32), n_parts)
-    order = stable_argsort(jnp, [pid_key], cap)
     counts, starts = _counts_starts(pid_key, n_parts)
-
     j = jnp.arange(slot, dtype=jnp.int32)
-    send_pos = starts[:, None] + j[None, :]
     send_valid = j[None, :] < counts[:, None]                  # [P, slot]
-    src_row = order[jnp.clip(send_pos, 0, cap - 1)]            # [P, slot]
+
+    # rows sorted by destination, stably: one digit of a few bits
+    has_spans = any(not carriable(c) for c in batch.columns)
+    row_lanes: list = []
+    for c in batch.columns:
+        _row_lanes(c, row_lanes)
+    order, sorted_lanes = sort_lanes(jnp, [pid_key.astype(jnp.uint32)],
+                                     row_lanes, cap, need_order=has_spans)
+    by_pid = {id(x): y for x, y in zip(row_lanes, sorted_lanes)}
 
     a2a = lambda x: wire_all_to_all(x, axis_name, n_parts)  # noqa: E731
 
     recv_valid = a2a(send_valid)
     flat_rows = n_parts * slot
-    valid_flat = recv_valid.reshape(flat_rows)
-    ord2 = stable_argsort(jnp, [~valid_flat], flat_rows)
-    out_total = jnp.sum(valid_flat.astype(jnp.int32))
+    recv_counts = jnp.sum(recv_valid.astype(jnp.int32), axis=1)
+    recv_starts = cumsum_fast(jnp, recv_counts) - recv_counts
+    out_total = jnp.sum(recv_counts)
     out_live = jnp.arange(flat_rows, dtype=jnp.int32) < out_total
 
+    def carried(x):
+        """Row-aligned lane `x` on its new chip."""
+        sent = _send_runs(by_pid[id(x)], starts, send_valid, slot)
+        return _receive_runs(a2a(sent), recv_starts, out_live)
+
+    src_row = ord2 = None
+    if has_spans:
+        # the span layouts index rows: where each send slot's row was,
+        # and where each output row lies in the receive tensor
+        send_pos = starts[:, None] + j[None, :]
+        src_row = order[jnp.clip(send_pos, 0, cap - 1)]        # [P, slot]
+        ord2 = stable_argsort(jnp, [~recv_valid.reshape(flat_rows)],
+                              flat_rows)
+
     def move(col: DeviceColumn) -> DeviceColumn:
-        validity = col.validity if col.validity is not None else \
-            jnp.ones((cap,), bool)
-        v_send = validity[src_row] & send_valid
-        recv_v = a2a(v_send).reshape(flat_rows)[ord2] & out_live
+        # a lane of ones arrives as the rows that arrived
+        recv_v = out_live if col.validity is None else carried(col.validity)
         if isinstance(col.dtype, (t.StringType, t.BinaryType)):
             chars_send, len_send = _string_send(col, src_row, send_valid,
                                                 n_parts, slot)
@@ -337,14 +410,10 @@ def exchange_by_pid(batch: DeviceBatch, pids, n_parts: int, axis_name: str,
                                    jnp.zeros((), rl.dtype))
                          for rl in recv_lanes]
             return rebuild(out_lanes, out_offs, recv_v)
-        data_send = col.data[src_row]
-        out_data = a2a(data_send).reshape(flat_rows)[ord2]
-        out_data = jnp.where(out_live, out_data,
-                             jnp.zeros_like(out_data))
-        new_col = DeviceColumn(col.dtype, data=out_data, validity=recv_v)
+        new_col = DeviceColumn(col.dtype, data=carried(col.data),
+                               validity=recv_v)
         if col.data_hi is not None:
-            hi = a2a(col.data_hi[src_row]).reshape(flat_rows)[ord2]
-            new_col.data_hi = jnp.where(out_live, hi, jnp.zeros_like(hi))
+            new_col.data_hi = carried(col.data_hi)
         return new_col
 
     out = DeviceBatch([move(c) for c in batch.columns], out_total,

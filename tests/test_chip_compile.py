@@ -176,10 +176,13 @@ def test_compact_at_the_largest_bucket(one_chip, record_property):
     assert seconds < 240, "a lane took a new sort signature"
 
 
-def test_exchange_and_aggregate_over_four_chips(mesh4):
+def test_exchange_and_aggregate_over_four_chips(mesh4, record_property):
     """`DistributedAggregate`'s SPMD step (partial aggregate,
     `exchange_by_pid` all_to_all, final aggregate) on a 4-device mesh of
-    the described chips, one 262144-row shard each."""
+    the described chips, one 262144-row shard each.  Its columns are flat,
+    so every lane moves by sort pass, slice or contiguous copy: the
+    compiled step holds no gather."""
+    import time
     from spark_rapids_tpu.expr.aggregates import (AggregateExpression,
                                                   Count, Sum)
     from spark_rapids_tpu.expr.core import AttributeReference as A
@@ -194,5 +197,13 @@ def test_exchange_and_aggregate_over_four_chips(mesh4):
                              lead=(4,))
     step = jax.shard_map(dagg._step, mesh=mesh4, in_specs=P("data"),
                          out_specs=P("data"), check_vma=False)
+    t0 = time.perf_counter()
     compiled = compile_for_chip(step, stacked)
-    assert "all-to-all" in compiled.as_text()
+    seconds = time.perf_counter() - t0
+    text = compiled.as_text()
+    record_property("mesh_step_262144_compile_s", round(seconds, 1))
+    record_property("mesh_step_262144_sorts", text.count(" sort("))
+    print(f"the mesh step at 262144 rows a chip for v5e:2x2: {seconds:.1f} s,"
+          f" {text.count(' sort(')} sorts")
+    assert "all-to-all" in text
+    assert " sort(" in text and " gather(" not in text

@@ -170,6 +170,31 @@ def test_q18sub_over_cut_orders_equals_the_reference(case, n_rows, parts,
         assert len(_programs("ici_reshard")) >= 1
 
 
+@pytest.mark.parametrize("string_key", [False, True],
+                         ids=["flat_columns", "string_key"])
+def test_step_build_record_counts_its_lane_moves(string_key):
+    """The mesh step of a flat-column aggregate moves every lane by sort
+    pass (the exchange and the merge's canonical order gather none), and
+    puts on the wire what it did before PR 28; a key with offsets keeps
+    its gathers, and the record counts them."""
+    s = _session()
+    table, _, _ = _lineitem(5000, seed=28, string_key=string_key)
+    _q18sub(s.create_dataframe(table, num_partitions=4)).collect()
+    steps = [p for p in _programs("DistributedAggregate")
+             if p.get("ici_wire_bytes")]
+    assert steps and all(p["lane_moves_sorted"] > 0 and p["sort_passes"] > 0
+                         for p in steps)
+    if string_key:
+        assert any(p["lane_moves_gathered"] > 0 for p in steps)
+        return
+    # [4, 8192] slots a chip of the slot's valid flag and, for the key and
+    # the partial sum, a data word and a validity byte; three of the four
+    # slices leave each chip (PR 27's figure for these shapes)
+    flat = [p for p in steps
+            if p["ici_wire_bytes"] == N_DEV * 8192 * (1 + 9 + 9) * 3]
+    assert flat and all(p["lane_moves_gathered"] == 0 for p in flat)
+
+
 def test_stage_span_and_wire_bytes_counter():
     s = _session(**{"spark.rapids.tpu.trace.enabled": True})
     table, _, _ = _lineitem(5000, seed=5)

@@ -2,6 +2,8 @@
 CPU mesh of tests/conftest.py (`chip_smoke.py --chips 4` is the same
 mesh on real chips)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -151,6 +153,160 @@ def test_exchange_guard_mode_flags_overflow():
     outs, oks = run_exchange_guarded(
         table, lambda b: b.columns[0].data % N_DEV, slot=32)
     assert not any(oks), oks
+
+
+# -- exchange_by_pid against a NumPy routing, lane by lane, bit for bit -------
+
+X_CAP = 32          # rows a shard
+X_SLOT = 16         # the sub-capacity budget of the guarded cases
+
+
+def _x_payload(kind: str, nulls: bool, rng):
+    """A stacked [N_DEV, X_CAP] column of NumPy lanes of one lane type.
+    Nulls keep whatever data lies under them: the exchange carries a
+    lane as it is."""
+    from spark_rapids_tpu import types as t
+    from spark_rapids_tpu.columnar.device import DeviceColumn
+    shape = (N_DEV, X_CAP)
+
+    def flat(dtype, data):
+        validity = rng.integers(0, 3, shape) > 0 if nulls else None
+        return DeviceColumn(dtype, data=data, validity=validity)
+
+    def doubles():
+        d = rng.standard_normal(shape) * 1e6
+        d[:, :7] = [np.nan, 0.0, -0.0, np.inf, -np.inf, 1 + 2.0**-40, 0.07]
+        return rng.permuted(d, axis=1)
+    makers = {
+        "int64": lambda: flat(t.LONG, rng.integers(-2**62, 2**62, shape)),
+        "float64": lambda: flat(t.DOUBLE, doubles()),
+        "int32": lambda: flat(t.INT, rng.integers(
+            -2**31, 2**31 - 1, shape).astype(np.int32)),
+        "bool": lambda: flat(t.BOOLEAN, rng.integers(0, 2, shape) == 1),
+        "date": lambda: flat(t.DATE, rng.integers(
+            0, 20000, shape).astype(np.int32)),
+    }
+    if kind != "struct":
+        return makers[kind]()
+    st = t.StructType([t.StructField("a", t.LONG),
+                       t.StructField("b", t.DOUBLE)])
+    return DeviceColumn(
+        st, validity=rng.integers(0, 4, shape) > 0 if nulls else None,
+        children=(makers["int64"](), makers["float64"]()))
+
+
+def _x_destinations(case: str, rng):
+    """(pids [N_DEV, X_CAP], rows a shard [N_DEV], slot or None)."""
+    rows = np.array([29, 32, 17, 30], dtype=np.int32)
+    slot = None
+    if case == "uniform":
+        pids = rng.integers(0, N_DEV, (N_DEV, X_CAP))
+    elif case == "all_to_one_chip":
+        # every run starts at 0, and the full shard's fills the slot
+        pids = np.full((N_DEV, X_CAP), 2)
+    elif case == "a_chip_receives_nothing":
+        pids = rng.choice([0, 1, 3], (N_DEV, X_CAP))
+    elif case == "an_empty_shard":
+        pids = rng.integers(0, N_DEV, (N_DEV, X_CAP))
+        rows = np.array([31, 0, 32, 5], dtype=np.int32)
+    elif case == "guard_fits":
+        pids = np.tile(np.arange(X_CAP) % N_DEV, (N_DEV, 1))
+        slot = X_SLOT
+    elif case == "guard_overflows":
+        pids = rng.integers(0, N_DEV, (N_DEV, X_CAP))
+        pids[0, :] = 1                  # 29 rows for a budget of 16
+        pids[2, :5] = 3
+        slot = X_SLOT
+    else:
+        raise AssertionError(case)
+    return pids.astype(np.int32), rows, slot
+
+
+def _x_lanes(col):
+    """Every lane of a row-aligned column tree, validity first; a node
+    without a validity lane counts as all valid."""
+    v = col.validity if col.validity is not None else \
+        np.ones((N_DEV, X_CAP), dtype=bool)
+    out = [v] + ([] if col.data is None else [col.data])
+    for ch in col.children:
+        out += _x_lanes(ch)
+    return out
+
+
+def _x_routed(lane, pids, rows, slot, dest):
+    """NumPy routing of one stacked lane to chip `dest`: its rows in
+    source-chip order, then source order, at most `slot` from a source;
+    the padding zero."""
+    out = np.zeros((N_DEV * slot,), dtype=lane.dtype)
+    parts = [lane[s, :rows[s]][pids[s, :rows[s]] == dest][:slot]
+             for s in range(N_DEV)]
+    got = np.concatenate(parts)
+    out[:got.shape[0]] = got
+    return out, got.shape[0]
+
+
+@functools.lru_cache(maxsize=None)
+def _x_program(slot):
+    """One jitted exchange a slot (jit keys the column tree itself)."""
+    def step(shard):
+        b = jax.tree_util.tree_map(lambda x: x[0], shard)
+        pids = b.columns[0].data
+        if slot is None:
+            out, ok = exchange_by_pid(b, pids, N_DEV, "data"), jnp.bool_(True)
+        else:
+            out, ok = exchange_by_pid(b, pids, N_DEV, "data", slot=slot,
+                                      on_overflow="guard")
+        return jax.tree_util.tree_map(lambda x: x[None], out), ok[None]
+    return jax.jit(shard_map(step, mesh=the_mesh(), in_specs=P("data"),
+                             out_specs=(P("data"), P("data")),
+                             check_vma=False))
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view(f"u{x.dtype.itemsize}") if x.dtype.kind == "f" else x
+
+
+@pytest.mark.parametrize("case", ["uniform", "all_to_one_chip",
+                                  "a_chip_receives_nothing",
+                                  "an_empty_shard", "guard_fits",
+                                  "guard_overflows"])
+@pytest.mark.parametrize("nulls", [False, True], ids=["no_nulls", "nulls"])
+@pytest.mark.parametrize("kind", ["int64", "float64", "int32", "bool",
+                                  "date", "struct"])
+def test_exchange_equals_numpy_routing(kind, nulls, case):
+    """Column by column and bit for bit: each chip holds its rows in
+    source-chip order then source order, zero and invalid behind them,
+    whatever the lane type, the nulls, the destinations and the slot."""
+    from spark_rapids_tpu import types as t
+    from spark_rapids_tpu.columnar.device import DeviceBatch, DeviceColumn
+    rng = np.random.default_rng(
+        [sum(map(ord, kind)), int(nulls), sum(map(ord, case))])
+    pids, rows, slot = _x_destinations(case, rng)
+    payload = _x_payload(kind, nulls, rng)
+    stacked = DeviceBatch(
+        [DeviceColumn(t.INT, data=pids), payload], rows, ["pid", "x"])
+    stacked = jax.tree_util.tree_map(jnp.asarray, stacked)
+    out, oks = _x_program(slot)(stacked)
+    slot = slot or X_CAP
+
+    sent = np.stack([np.bincount(pids[s, :rows[s]], minlength=N_DEV)
+                     for s in range(N_DEV)])
+    assert [bool(x) for x in np.asarray(oks)] == \
+        [bool((sent[s] <= slot).all()) for s in range(N_DEV)]
+    got_cols = [np.asarray(x) for c in out.columns for x in _x_lanes(c)]
+    want_lanes = [pids] + _x_lanes(payload)
+    # the pid column came without a validity lane and leaves with one
+    want_lanes.insert(0, np.ones((N_DEV, X_CAP), dtype=bool))
+    assert len(got_cols) == len(want_lanes)
+    for dest in range(N_DEV):
+        n_here = np.minimum(sent[:, dest], slot).sum()
+        assert int(np.asarray(out.num_rows)[dest]) == n_here
+        for got, lane in zip(got_cols, want_lanes):
+            want, n = _x_routed(lane, pids, rows, slot, dest)
+            assert n == n_here
+            assert got[dest].dtype == want.dtype
+            np.testing.assert_array_equal(_bits(got[dest]), _bits(want))
 
 
 def test_allgather_broadcast():
