@@ -297,13 +297,10 @@ def main(argv=None) -> int:
             facts = run_four_chips(jax.devices(), rows, args.seed)
     finally:
         shutil.rmtree(root, ignore_errors=True)
-    from spark_rapids_tpu.ops.carry import compile_lean_enabled
     emit(programs_compiled=sum(f["programs"] for f in facts),
          cache_hits=sum(f["cache_hits"] for f in facts),
          cache_misses=sum(f["cache_misses"] for f in facts),
          cache_entries_at_end=cache_entries(cache_dir),
-         sort_kernels="compile-lean" if compile_lean_enabled()
-         else "throughput",
          wall_s=round(time.perf_counter() - t_start, 1))
     emit(ok=True, device=bench.device_facts())
     return 0

@@ -428,10 +428,6 @@ dim = pa.table({
 s = (TpuSession.builder()
      .config("spark.rapids.sql.enabled", True)
      .config("spark.rapids.tpu.singleChipFuse", "off")
-     # pin the sort kernel structure: 'auto' decides from the persistent
-     # compile cache's cold/warm state, and the two gate replays must
-     # compile the SAME program set (distinct_programs is deterministic)
-     .config("spark.rapids.tpu.sort.compileLean", "off")
      .config("spark.rapids.tpu.eventLog.dir", eventlog_dir)
      .get_or_create())
 fdf = s.create_dataframe(fact, num_partitions=2)
@@ -652,7 +648,6 @@ def run_jit_gate() -> int:
         s = (TpuSession.builder()
              .config("spark.rapids.sql.enabled", True)
              .config("spark.rapids.tpu.singleChipFuse", "off")
-             .config("spark.rapids.tpu.sort.compileLean", "off")
              .config("spark.rapids.tpu.eventLog.dir", evt)
              .config("spark.rapids.tpu.compile.ledgerDir", hist)
              .get_or_create())
@@ -1977,7 +1972,6 @@ dim = pa.table({
 s = (TpuSession.builder()
      .config("spark.rapids.sql.enabled", True)
      .config("spark.rapids.tpu.singleChipFuse", "off")
-     .config("spark.rapids.tpu.sort.compileLean", "off")
      .config("spark.rapids.tpu.trace.enabled", True)
      .config("spark.rapids.tpu.regress.historyDir", hist_dir)
      .config("spark.rapids.tpu.feedback.enabled", arm == "warm")
@@ -3383,7 +3377,6 @@ def run_hlo_gate() -> int:
         s = (TpuSession.builder()
              .config("spark.rapids.sql.enabled", True)
              .config("spark.rapids.tpu.singleChipFuse", "off")
-             .config("spark.rapids.tpu.sort.compileLean", "off")
              .config("spark.rapids.tpu.eventLog.dir", evt)
              .config("spark.rapids.tpu.compile.ledgerDir", hist)
              .get_or_create())

@@ -176,9 +176,9 @@ def verify_gates() -> List[Tuple[str, str, t.DataType]]:
 # ---------------------------------------------------------------------------
 # The xp-parameterization convention keeps exec// ops/ backend-agnostic:
 # kernels take `xp` and run identically on numpy for the host path.  The
-# few entry points that NEED a jax-only primitive (today: lax.sort's
-# multi-operand stable sort, which numpy has no analogue for — the host
-# path branches around it) register here so the tpuxsan repo rule
+# few entry points that NEED a jax-only primitive (today: the sort
+# passes of ops/carry.py, lax.sort with both operands keys, which numpy
+# has no analogue for — the host path branches around it) register here so the tpuxsan repo rule
 # (TPU-R017, analysis/hloaudit.py) can tell a sanctioned kernel from an
 # accidental bypass.  Keys are package-relative paths; values map the
 # entry-point function name to the one-line reason it is device-only.
@@ -186,26 +186,24 @@ def verify_gates() -> List[Tuple[str, str, t.DataType]]:
 
 DEVICE_KERNELS: Dict[str, Dict[str, str]] = {
     "ops/carry.py": {
-        "sort_rows": "rows moved by lax.sort (lean: a pass per 32-bit "
-                     "word keyed by the rank; else one multi-operand "
-                     "stable sort); host path uses np.lexsort + fancy "
-                     "indexing instead",
+        "sort_rows": "rows moved by lax.sort, a pass per 32-bit word "
+                     "keyed by the rank; host path uses np.lexsort + "
+                     "fancy indexing instead",
         "move_lanes": "a lane put in place by one 2-operand (uint32, "
                       "int32) lax.sort keyed by the destination rank; "
                       "the host path assigns through the rank",
-        "lean_argsort": "compile-lean radix argsort: every pass is one "
-                        "2-operand (uint32, int32) lax.sort",
-        "stable_argsort": "the device argsort in the session's sort "
-                          "mode (lean_argsort or a multi-operand "
-                          "lax.sort); host paths use np.argsort",
+        "stable_argsort": "the device argsort, a radix sort whose "
+                          "every pass is one 2-operand (uint32, int32) "
+                          "lax.sort; host paths use np.argsort",
     },
     "ops/join_kernels.py": {
         "count_matches": "sort-based hash-match counting rides "
-                         "lax.sort's multi-operand form",
+                         "carry's sort passes; the host path uses "
+                         "np.searchsorted",
     },
     "ops/segmented.py": {
-        "lexsort": "multi-word lexicographic sort is lax.sort's "
-                   "is_stable multi-operand mode",
+        "lexsort": "multi-word lexicographic sort is "
+                   "carry.stable_argsort; the host path uses np.lexsort",
     },
 }
 

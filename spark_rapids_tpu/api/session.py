@@ -190,12 +190,9 @@ class TpuSession:
             max_samples=conf.get(cfg.HBM_TIMELINE_MAX_SAMPLES),
             budget_bytes=self.spill_catalog.device_budget
             if self.spill_catalog is not None else 0)
-        from ..ops.carry import set_compile_lean
-        set_compile_lean(conf.get(cfg.SORT_COMPILE_LEAN) == "on")
         # warm-start tier: replay the costliest ledger recipes so first
-        # queries dispatch to ready programs.  Ordered after plugin and
-        # sort-mode init: the replay traces in the session's sort mode
-        # and compiles through the persistent disk cache.
+        # queries dispatch to ready programs.  Ordered after plugin
+        # init: the replay compiles through the persistent disk cache.
         self._prewarm_thread = None
         if ledger_path and conf.get(cfg.JIT_PREWARM_ENABLED) and \
                 conf.get(cfg.COMPILE_OBSERVATORY_ENABLED):

@@ -292,25 +292,6 @@ SINGLE_CHIP_FUSE = conf("spark.rapids.tpu.singleChipFuse").string() \
     .check_values(["auto", "on", "off"]) \
     .create_with_default("auto")
 
-SORT_COMPILE_LEAN = conf("spark.rapids.tpu.sort.compileLean").string() \
-    .doc("Sort-kernel structure tradeoff.  'on' (compile-lean, the "
-         "default): every device sort is built from passes of one "
-         "2-operand (uint32, int32) sort, the signature the TPU "
-         "compiler takes least time over (about 23 s at 4M rows, "
-         "against 87 s for one stable 64-bit sort and 320 s for a "
-         "carry-sort with two payload lanes; v5e compiler, PR 21): "
-         "passes over packed key digits find the rows' ranks, and one "
-         "more pass keyed by the rank moves each 32-bit word of row "
-         "data; nothing is gathered by the order but columns with "
-         "offsets.  'off': payload lanes ride the sort as extra "
-         "lax.sort operands, which a cache-cold query pays for in "
-         "minutes of compile per program.  What 'on' costs warm is in "
-         "PERF.md (PR 26); 'off' has not been measured on the chip.  The "
-         "mode never follows the compile cache's warmth: programs "
-         "cached under one mode would all miss under the other.") \
-    .check_values(["on", "off"]) \
-    .create_with_default("on")
-
 JOIN_SPECULATIVE_SIZING = conf(
     "spark.rapids.tpu.join.speculativeSizing").boolean() \
     .doc("Fuse a hash join's count and expand phases into ONE program by "

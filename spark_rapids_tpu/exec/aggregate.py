@@ -4,7 +4,7 @@ Ref: sql-plugin/.../aggregate.scala (GpuHashAggregateExec / iterator mode
 pipeline at :258-275) — re-designed for TPU as sort+segment-reduce:
 
   1. per batch: evaluate grouping keys + update inputs, encode keys as
-     order-preserving uint64 words, lax.sort (stable, multi-operand),
+     order-preserving uint64 words, stable sort (ops/carry.py),
      boundary-detect, segment-reduce every buffer, compact groups to the
      front — one jitted XLA computation per (schema, capacity);
   2. across batches: concatenate the per-batch partials and run the same
@@ -77,8 +77,8 @@ def _group_reduce(xp, key_cols: List[DeviceColumn],
 
       1. ONE stable sort by the key words.  Every flat lane of the key
          and value columns is moved by `carry.sort_rows`: a sort pass per
-         32-bit word keyed by the row's rank (lean mode), or a payload
-         operand of the one sort; never a gather by the order.
+         32-bit word keyed by the row's rank; never a gather by the
+         order.
       2. Per sum/count: a Hillis-Steele prefix scan + elementwise
          exclusive value — the per-segment total is the difference of the
          exclusive scan at consecutive segment starts.  No 64-bit

@@ -4,8 +4,9 @@ Ref: sql-plugin/.../GpuSortExec.scala:39-534 (single-batch, per-batch and
 out-of-core modes) + SortUtils.scala.
 
 TPU realization: order-preserving uint64 key-word encoding per sort column
-(ops/segmented.key_words_for_column with true string ordering) feeding one
-stable multi-operand lax.sort; rows then move via gather.  Multi-batch
+(ops/segmented.key_words_for_column with true string ordering) feeding
+`carry.sort_rows`: the rank from sort passes over the key's digits, then
+a pass per 32-bit word of row data (ops/carry.py).  Multi-batch
 partitions concatenate before sorting (spillable out-of-core merge arrives
 with the memory framework; the concat path is the reference's
 single-batch-goal mode).

@@ -520,11 +520,7 @@ class WindowExec(Exec):
             flat: List = []
             for _, d, v in per:
                 flat += [d, v]
-            if xp is np or carry.compile_lean_enabled():
-                back = carry.move_lanes(xp, lay.order, flat)
-            else:
-                _, back = carry.sort_lanes(
-                    xp, [lay.order.astype(xp.uint32)], flat, cap)
+            back = carry.move_lanes(xp, lay.order, flat)
             for i, (w, _, _) in enumerate(per):
                 d, v = back[2 * i], back[2 * i + 1]
                 out_dtype = w.resolved_type(cn, ct)

@@ -1,57 +1,49 @@
-"""Row permutations on the device: every lane move is a sort pass.
+"""Row permutations on the device: every sort and every lane move is a
+pass of ONE sort signature.
 
 A gather is row-at-a-time on this chip and a sort is not: a 33,554,432-row
 XLA gather of one 32-bit lane took 0.93 s (0.15 GB/s, 1/5000 of the HBM
 peak; ledger, PR 25) where a sort pass over the same rows takes a tenth
-of it (PERF.md, PR 26).  So every sort-then-permute path in the engine
-(filter compaction, sort exec, group-by, window ordering) moves its row
-data with `lax.sort`, never with `x[order]`.  Columns with span structure
-(strings, arrays, maps: anything with offsets) cannot ride a row
-permutation and keep `gather_column`.
+of it, and a pass beat the gather at every size read (1M rows: 1.9 ms
+against 9.9; 16.7M: 35.6 against 145; 33.5M: 80 against 490-930;
+PERF.md, PR 26).  So every sort-then-permute path in the engine (filter
+compaction, sort exec, group-by, window ordering, the joins' probe) moves
+its row data with `lax.sort`, never with `x[order]`.  Columns with span
+structure (strings, arrays, maps: anything with offsets) cannot ride a
+row permutation and keep `gather_column`.
 
-Two kernel structures do that, chosen by spark.rapids.tpu.sort.compileLean:
+The TPU compiler's time for a `lax.sort` is set by the sort's signature,
+not by how often a program repeats it (asked of the v5e compiler at
+4,194,304 rows, PR 21: one stable (uint64, int32) sort 87 s, three of
+them in one program 90 s; a stable 2-key sort with two more payload
+operands 320 s; an unstable 2-key (uint32, int32) sort 23 s; 31 programs
+built from many-operand sorts took 1,411.6 s of compile against 479.5 s
+from this one).  Every device sort is therefore a pass of that ONE
+cheapest signature (`_sort_pass`, the only `lax.sort` here), whose two
+operands are both keys and always unique, so that it carries no payload:
 
-* on (the default), compile-lean.  The TPU compiler's time for a
-  `lax.sort` is set by the sort's signature, not by how often a program
-  repeats it (asked of the v5e compiler at 4,194,304 rows, PR 21: one
-  stable (uint64, int32) sort 87 s, three of them in one program 90 s; a
-  stable 2-key sort with two more payload operands 320 s; an unstable
-  2-key (uint32, int32) sort 23 s).  Every device sort is therefore a
-  pass of that ONE cheapest signature (`_sort_pass`), whose two operands
-  are both keys and always unique, so that it carries no payload:
-    - `_lean_perm` finds a sort's order and its inverse, the rank, from
-      passes over (key digit, tie-break);
-    - `move_lanes` puts a 32-bit word of row data in its new place with
-      one pass keyed by the rank: `sort((rank, x))[1]` is `x[order]`, bit
-      for bit.  64-bit lanes are two words, up to 32 bool lanes one;
-    - a stable partition (`compact_rows`, a sort by one bool word) has
-      its rank in closed form from a prefix sum, and sorts nothing.
-* off: payloads ride ONE stable multi-operand `lax.sort` as operands.
+  - `_lean_perm` finds a sort's order and its inverse, the rank, from
+    passes over (key digit, tie-break); `stable_argsort` is its order;
+  - `move_lanes` puts a 32-bit word of row data in its new place with
+    one pass keyed by the rank: `sort((rank, x))[1]` is `x[order]`, bit
+    for bit.  64-bit lanes are two words, up to 32 bool lanes one;
+  - a stable partition (`compact_rows`, a sort by one bool word) has
+    its rank in closed form from a prefix sum, and sorts nothing.
 
-The numpy engine mirrors the semantics with fancy indexing per lane.
+That structure is a fact of this module, not a setting: nothing outside
+asks how a sort is built.  The numpy engine mirrors the semantics with
+fancy indexing per lane, and is the reference the tests compare with.
 """
 
 from __future__ import annotations
 
 import threading
-from typing import List, Sequence, Tuple
+from typing import List, Sequence
 
 import numpy as np
 
 from ..columnar.device import DeviceColumn
 from .gather import gather_column
-
-_LEAN = True
-
-
-def set_compile_lean(enabled: bool) -> None:
-    global _LEAN
-    _LEAN = bool(enabled)
-
-
-def compile_lean_enabled() -> bool:
-    return _LEAN
-
 
 # ---------------------------------------------------------------------------
 # What a program's build moved, and how (read by obs/compileprof)
@@ -230,7 +222,7 @@ def _u32_pieces(xp, w) -> list:
     if dt == np.bool_:
         return [(w.astype(xp.uint32), 1)]
     if dt.kind not in "iu":
-        raise TypeError(f"lean sort key words are integers, not {dt}")
+        raise TypeError(f"sort key words are integers, not {dt}")
     bits = 8 * dt.itemsize
     if dt.kind == "i":
         # two's complement orders like unsigned once the sign bit flips
@@ -320,22 +312,10 @@ def _lean_perm(xp, key_words, cap: int, want_order: bool = True,
     return order, rank
 
 
-def lean_argsort(xp, key_words, cap: int):
-    """Stable ascending lexicographic argsort (int32[cap]) by integer key
-    words, most significant first, from passes of the one signature."""
-    return _lean_perm(xp, key_words, cap, want_rank=False)[0]
-
-
 def stable_argsort(xp, key_words, cap: int):
-    """Stable ascending lexicographic argsort (int32[cap]) on the device,
-    in the session's sort mode: `lean_argsort`, or one multi-operand
-    stable `lax.sort`."""
-    if _LEAN or not key_words:
-        return lean_argsort(xp, key_words, cap)
-    from jax import lax
-    iota = xp.arange(cap, dtype=xp.int32)
-    return lax.sort(tuple(key_words) + (iota,), num_keys=len(key_words),
-                    is_stable=True)[-1]
+    """Stable ascending lexicographic argsort (int32[cap]) on the device
+    by integer key words, most significant first."""
+    return _lean_perm(xp, key_words, cap, want_rank=False)[0]
 
 
 def carriable(col: DeviceColumn) -> bool:
@@ -346,11 +326,35 @@ def carriable(col: DeviceColumn) -> bool:
     return all(carriable(c) for c in col.children)
 
 
-def _sort_rows_lean(xp, key_words, cols, cap, extras, need_order):
-    """`sort_rows` from passes of the one signature: the rank, then a
-    move per lane.  Same results as the carry path, far cheaper to
-    compile; span columns are gathered by the order."""
+def _permute_col_np(col: DeviceColumn, order) -> DeviceColumn:
     import jax
+    return jax.tree_util.tree_map(lambda lane: lane[order], col)
+
+
+def sort_rows(xp, key_words: Sequence, cols: Sequence[DeviceColumn],
+              cap: int, extras: Sequence = (), need_order: bool = True):
+    """Stable ascending lexicographic sort by `key_words`; rows of `cols`
+    and the 1-D arrays in `extras` travel with the permutation: the rank,
+    then a move per lane.
+
+    Returns (order:int32[cap], out_cols, out_extras).  Non-carriable
+    columns are gathered by `order` (validity preserved; a permutation
+    never invents nulls).  A caller that drops the order says so with
+    `need_order=False` and may get None: the order costs a pass of its
+    own."""
+    import jax
+    if xp is np:
+        order = np.lexsort(tuple(reversed(list(key_words)))).astype(np.int32)
+        out_extras = [e[order] for e in extras]
+        out_cols = []
+        for c in cols:
+            if carriable(c):
+                out_cols.append(_permute_col_np(c, order))
+            else:
+                ones = np.ones((cap,), dtype=bool)
+                out_cols.append(gather_column(np, c, order, ones))
+        return order, out_cols, out_extras
+
     flats = [jax.tree_util.tree_flatten(c) if carriable(c) else None
              for c in cols]
     lanes = [leaf for f in flats if f is not None for leaf in f[0]]
@@ -369,80 +373,9 @@ def _sort_rows_lean(xp, key_words, cols, cap, extras, need_order):
     return order, out_cols, list(moved)
 
 
-def _permute_col_np(col: DeviceColumn, order) -> DeviceColumn:
-    import jax
-    return jax.tree_util.tree_map(lambda lane: lane[order], col)
-
-
-def sort_rows(xp, key_words: Sequence, cols: Sequence[DeviceColumn],
-              cap: int, extras: Sequence = (), need_order: bool = True):
-    """Stable ascending lexicographic sort by `key_words`; rows of `cols`
-    and the 1-D arrays in `extras` travel with the permutation.
-
-    Returns (order:int32[cap], out_cols, out_extras).  Non-carriable
-    columns are gathered by `order` (validity preserved; a permutation
-    never invents nulls).  A caller that drops the order says so with
-    `need_order=False` and may get None: in lean mode the order costs a
-    pass of its own."""
-    import jax
-    if xp is np:
-        order = np.lexsort(tuple(reversed(list(key_words)))).astype(np.int32)
-        out_extras = [e[order] for e in extras]
-        out_cols = []
-        for c in cols:
-            if carriable(c):
-                out_cols.append(_permute_col_np(c, order))
-            else:
-                ones = np.ones((cap,), dtype=bool)
-                out_cols.append(gather_column(np, c, order, ones))
-        return order, out_cols, out_extras
-
-    if _LEAN:
-        return _sort_rows_lean(xp, key_words, cols, cap, extras, need_order)
-
-    from jax import lax
-    iota = xp.arange(cap, dtype=xp.int32)
-    operands: List = list(key_words) + [iota]
-    # payload slots, deduped by traced-array identity (the same lane may
-    # back several logical columns)
-    slot_of: dict = {}
-    flats: List[Tuple[object, object]] = []  # (treedef, leaf slot indices)
-    for c in cols:
-        if not carriable(c):
-            flats.append((None, None))
-            continue
-        leaves, treedef = jax.tree_util.tree_flatten(c)
-        idxs = []
-        for leaf in leaves:
-            key = id(leaf)
-            if key not in slot_of:
-                slot_of[key] = len(operands)
-                operands.append(leaf)
-            idxs.append(slot_of[key])
-        flats.append((treedef, idxs))
-    extra_idx = []
-    for e in extras:
-        key = id(e)
-        if key not in slot_of:
-            slot_of[key] = len(operands)
-            operands.append(e)
-        extra_idx.append(slot_of[key])
-    res = lax.sort(tuple(operands), num_keys=len(key_words), is_stable=True)
-    order = res[len(key_words)]
-    out_cols = []
-    for c, (treedef, idxs) in zip(cols, flats):
-        if treedef is None:
-            out_cols.append(_gather_span_column(xp, c, order, cap))
-        else:
-            out_cols.append(jax.tree_util.tree_unflatten(
-                treedef, [res[i] for i in idxs]))
-    out_extras = [res[i] for i in extra_idx]
-    return order, out_cols, out_extras
-
-
 def sort_lanes(xp, key_words: Sequence, lanes: Sequence, cap: int,
                need_order: bool = True):
-    """Lane-only carry-sort: returns (order, sorted_lanes)."""
+    """`sort_rows` of lanes alone: returns (order, sorted_lanes)."""
     order, _, out = sort_rows(xp, key_words, (), cap, extras=lanes,
                               need_order=need_order)
     return order, out
@@ -451,13 +384,10 @@ def sort_lanes(xp, key_words: Sequence, lanes: Sequence, cap: int,
 def compact_rows(xp, keep, cols: Sequence[DeviceColumn], cap: int,
                  extras: Sequence = (), need_order: bool = False):
     """Stable partition: rows with keep=True move to the front in
-    original order.  A sort by the one-bit key `~keep`: in lean mode its
-    rank is `compaction_rank`'s closed form and only the lanes' own
-    passes run; otherwise ONE u8-key carry-sort."""
-    if xp is np or not _LEAN:
-        key = (~keep).astype(np.uint8 if xp is np else xp.uint8)
-        return sort_rows(xp, [key], cols, cap, extras=extras)
-    return _sort_rows_lean(xp, [~keep], cols, cap, extras, need_order)
+    original order.  A sort by the one-bit key `~keep`, whose rank is
+    `compaction_rank`'s closed form: only the lanes' own passes run."""
+    return sort_rows(xp, [~keep], cols, cap, extras=extras,
+                     need_order=need_order)
 
 
 def mask_validity(xp, col: DeviceColumn, mask) -> DeviceColumn:

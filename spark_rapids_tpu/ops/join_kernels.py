@@ -1,11 +1,17 @@
-"""Equi-join kernels: sorted-key binary-search probe + pair expansion.
+"""Equi-join kernels: a sort-and-scan probe and pair expansion.
 
 TPU replacement for cuDF's hash join (ref GpuHashJoin.scala /
-JoinGatherer.scala): instead of a device hash table, the build side's keys
-collapse to a single 64-bit combined hash, get sorted once, and each probe
-row finds its match range with two vectorized binary searches
-(searchsorted).  Pair expansion uses the same searchsorted-span technique
-as the string gather — all static shapes.
+JoinGatherer.scala): instead of a device hash table, each side's keys
+collapse to a single 64-bit combined hash.  The build side's hashes are
+sorted once (`carry.stable_argsort`), and ONE stable sort of both sides'
+(hash, side, row) puts every probe row behind the build rows of its hash
+(`carry.sort_lanes`): two scans then give each probe row its match count
+and the start of its run in the sorted build order, and two int32
+scatters put them back at the probe rows' own places.  No per-row binary
+search: a `searchsorted` is log(n) rounds of gathers on this chip (the
+NumPy engine keeps it; it is the reference).  Pair expansion fills each
+probe row's span of the output from its start by a running maximum
+(`scan.fill_rows_from_starts`) — all static shapes.
 
 Two-phase protocol (one host sync, like cuDF sizing its gather maps):
   phase 1 (jitted `count_matches`): per-probe match ranges + totals;
@@ -95,23 +101,17 @@ def count_matches(xp, build_hash, build_live, probe_hash, probe_live):
             np.int32)
         counts = np.where(probe_live, hi - lo, 0).astype(np.int64)
         return order, lo, counts
-    from jax import lax
-    from .carry import compile_lean_enabled, lean_argsort, sort_lanes
+    from .carry import sort_lanes, stable_argsort
     from .scan import cummax_i32, cumsum_fast
     cap_p = probe_hash.shape[0]
-    iota_b = xp.arange(cap_b, dtype=xp.int32)
     allh = xp.concatenate([bh, probe_hash])
     side = xp.concatenate([xp.zeros((cap_b,), xp.uint8),
                            xp.ones((cap_p,), xp.uint8)])
-    idx = xp.concatenate([iota_b, xp.arange(cap_p, dtype=xp.int32)])
-    if compile_lean_enabled():
-        order = lean_argsort(xp, [bh], cap_b)
-        _, (sh, ss, si) = sort_lanes(xp, [allh, side], [allh, side, idx],
-                                     cap_b + cap_p, need_order=False)
-    else:
-        _, order = lax.sort((bh, iota_b), num_keys=1, is_stable=True)
-        sh, ss, si = lax.sort((allh, side, idx), num_keys=2,
-                              is_stable=True)
+    idx = xp.concatenate([xp.arange(cap_b, dtype=xp.int32),
+                          xp.arange(cap_p, dtype=xp.int32)])
+    order = stable_argsort(xp, [bh], cap_b)
+    _, (sh, ss, si) = sort_lanes(xp, [allh, side], [allh, side, idx],
+                                 cap_b + cap_p, need_order=False)
     is_b = (ss == 0).astype(xp.int32)
     from .scan import differs_from_prev
     nb = differs_from_prev(xp, sh)

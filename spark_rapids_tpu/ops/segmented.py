@@ -112,7 +112,7 @@ def key_words_for_column(xp, col: DeviceColumn, live_mask,
                          ascending: bool = True):
     """Sort-key words (most-significant first) for one column.
 
-    Word 0 is the null indicator (bool: one bit of a lean sort's digit;
+    Word 0 is the null indicator (bool: one bit of a sort pass's digit;
     nulls group/sort together);
     remaining words encode the value — uint32 for types that fit 32 bits
     (half the sort-comparator cost on TPU), uint64 otherwise.  Strings

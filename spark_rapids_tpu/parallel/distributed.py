@@ -390,8 +390,7 @@ class DistributedSort:
         from ..ops import carry
         parked = jnp.where(live, w0, maxw)
         sorted_w0 = carry.sort_lanes(jnp, [parked], [parked], cap,
-                                     need_order=False)[1][0] \
-            if carry.compile_lean_enabled() else jnp.sort(parked)
+                                     need_order=False)[1][0]
         n_live = jnp.sum(live.astype(jnp.int32))
         # local splitter candidates at the n_dev-quantiles
         q = (jnp.arange(1, n_dev, dtype=jnp.int32) * n_live) // n_dev

@@ -80,17 +80,7 @@ def try_host_assisted_collect(session, lp) -> Optional[pa.Table]:
         rid_plan = L.Filter(cond, rid_plan)
     rid_plan = L.Sort(lp.orders, True, rid_plan)
     rid_plan = L.Project([AttributeReference(_RID)], rid_plan)
-    # the rid plan needs only the PERMUTATION — the compile-lean sort
-    # (iterated 2-operand passes, ops/carry.py) computes exactly that
-    # without lowering a many-operand carry-sort (minutes of compile for
-    # a shape used by nothing else)
-    from ..ops.carry import compile_lean_enabled, set_compile_lean
-    prev = compile_lean_enabled()
-    set_compile_lean(True)
-    try:
-        rid = session.execute(rid_plan).column(_RID).to_numpy()
-    finally:
-        set_compile_lean(prev)
+    rid = session.execute(rid_plan).column(_RID).to_numpy()
 
     # (partition << 33) + offset -> global row index; LocalScanExec
     # slices the table into ceil(n/p)-row partitions in order

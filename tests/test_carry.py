@@ -17,14 +17,6 @@ from spark_rapids_tpu.columnar.device import DeviceBatch, DeviceColumn
 from spark_rapids_tpu.ops import carry
 
 
-@pytest.fixture(autouse=True)
-def lean_mode():
-    was = carry.compile_lean_enabled()
-    carry.set_compile_lean(True)
-    yield
-    carry.set_compile_lean(was)
-
-
 N = 2500
 _F_SPECIALS = [np.nan, -np.nan, 0.0, -0.0, np.inf, -np.inf]
 
@@ -235,7 +227,7 @@ def test_the_subquerys_key_is_two_digits_at_the_largest_bucket():
 
 
 @pytest.mark.parametrize("case", ["flag_flag_i64", "u8_i32_u16_flag"])
-def test_sort_rows_lean_against_lexsort(case):
+def test_sort_rows_against_lexsort(case):
     n = 3000
     rng = np.random.default_rng(21)
     words = _key_cases(rng, n)[case]

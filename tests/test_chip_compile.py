@@ -60,18 +60,12 @@ def mesh4(topo):
 @pytest.fixture(scope="module", autouse=True)
 def quiet_compiles():
     """The persistent cache off around the compiles (an entry written
-    for a described chip cannot be read back without one and warns),
-    and the session default's sort kernels, whatever an earlier test in
-    this process left."""
+    for a described chip cannot be read back without one and warns)."""
     from jax.experimental.compilation_cache import compilation_cache
-    from spark_rapids_tpu.ops import carry
     was_enabled = jax.config.jax_enable_compilation_cache
-    was_lean = carry.compile_lean_enabled()
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    carry.set_compile_lean(True)
     yield
-    carry.set_compile_lean(was_lean)
     jax.config.update("jax_enable_compilation_cache", was_enabled)
     compilation_cache.reset_cache()
 
@@ -147,12 +141,12 @@ def test_float64_sort_key_needs_no_bit_view(one_chip):
     compile_for_chip(lambda d: seg.encode_float_ordered(jnp, d), f)
 
 
-def test_lean_argsort_pass_at_the_largest_bucket(one_chip):
+def test_argsort_pass_at_the_largest_bucket(one_chip):
     """The one sort signature every device sort is built from."""
     from spark_rapids_tpu.ops import carry
     k = jax.ShapeDtypeStruct((M4,), np.uint64, sharding=one_chip)
     pad = jax.ShapeDtypeStruct((M4,), np.uint8, sharding=one_chip)
-    compile_for_chip(lambda p, w: carry.lean_argsort(jnp, [p, w], M4),
+    compile_for_chip(lambda p, w: carry.stable_argsort(jnp, [p, w], M4),
                      pad, k)
 
 

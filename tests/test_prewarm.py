@@ -61,7 +61,6 @@ def test_prewarm_round_trip(fresh, tmp_path):
     s = (TpuSession.builder()
          .config("spark.rapids.sql.enabled", True)
          .config("spark.rapids.tpu.singleChipFuse", "off")
-         .config("spark.rapids.tpu.sort.compileLean", "off")
          .config("spark.rapids.tpu.compile.ledgerDir", ledger_dir)
          .get_or_create())
     ledger_path = CompileObservatory.get().ledger_path

@@ -40,7 +40,6 @@ def _session() -> TpuSession:
     return (TpuSession.builder()
             .config("spark.rapids.sql.enabled", True)
             .config("spark.rapids.tpu.singleChipFuse", "off")
-            .config("spark.rapids.tpu.sort.compileLean", "off")
             .get_or_create())
 
 

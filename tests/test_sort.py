@@ -50,7 +50,7 @@ def test_sort_multi_partition_global():
     assert_tpu_and_cpu_are_equal_collect(q, ignore_order=False)
 
 
-# -- the compile-lean sort primitive ----------------------------------------
+# -- the sort primitive -------------------------------------------------------
 
 def _lean_words(rng, n):
     dup = rng.integers(0, 2**63, n, dtype=np.uint64)
@@ -80,32 +80,28 @@ def _lean_words(rng, n):
 @pytest.mark.parametrize("case", ["u8", "u64", "u8_u64", "i32", "i64_u8",
                                   "bool_i32_u64", "no_words", "bool",
                                   "bool_bool_i64", "u16_i64_i64"])
-@pytest.mark.parametrize("lean", [True, False])
-def test_stable_argsort_is_numpys_stable_lexsort(case, lean):
-    """Every device sort in the engine goes through this; in lean mode it
-    is a radix sort of (uint32, int32) passes over packed digits."""
+@pytest.mark.parametrize("n", [3000, 4096])
+def test_stable_argsort_is_numpys_stable_lexsort(case, n):
+    """Every device sort in the engine goes through this: a radix sort of
+    (uint32, int32) passes over packed digits.  At a power of two the
+    tie-break fills its `pos_bits` to the last one (row 4095 is all
+    ones under the mask that takes it out of a two-operand digit)."""
     import jax
     import jax.numpy as jnp
     from spark_rapids_tpu.ops import carry
-    n = 3000
     words = _lean_words(np.random.default_rng(5), n)[case]
     want = np.lexsort(tuple(reversed(words))).astype(np.int32) \
         if words else np.arange(n, dtype=np.int32)
-    was = carry.compile_lean_enabled()
-    carry.set_compile_lean(lean)
-    try:
-        got = jax.jit(lambda *ws: carry.stable_argsort(jnp, list(ws), n))(
-            *[jnp.asarray(w) for w in words])
-    finally:
-        carry.set_compile_lean(was)
+    got = jax.jit(lambda *ws: carry.stable_argsort(jnp, list(ws), n))(
+        *[jnp.asarray(w) for w in words])
     assert (np.asarray(got) == want).all()
 
 
-def test_lean_argsort_refuses_float_words():
+def test_stable_argsort_refuses_float_words():
     import jax.numpy as jnp
     from spark_rapids_tpu.ops import carry
     with pytest.raises(TypeError, match="integers"):
-        carry.lean_argsort(jnp, [jnp.ones(4)], 4)
+        carry.stable_argsort(jnp, [jnp.ones(4)], 4)
 
 
 def test_float64_keys_order_without_a_bit_view():

@@ -12,6 +12,7 @@ import pytest
 from spark_rapids_tpu.api import functions as F
 from spark_rapids_tpu.api.column import col
 from spark_rapids_tpu.api.session import TpuSession
+from spark_rapids_tpu.testing.asserts import assert_tables_equal
 
 
 def _session(spec: bool):
@@ -104,27 +105,35 @@ def test_string_payloads_bypass_speculation():
     assert got.equals(want)
 
 
-def test_compile_lean_sort_matches_carry():
-    """ops/carry.py lean mode: iterated 2-operand passes + gathers must
-    permute identically to the payload carry-sort (including stability
-    and span payloads)."""
+@pytest.mark.parametrize("host_assisted", [False, True])
+def test_three_key_sort_with_a_string_matches_the_cpu_engine(host_assisted):
+    """The sort's passes and its span gather against the CPU engine
+    (stability and the string included), over two partitions.  Above the
+    host-assist threshold the collect runs a nested query for the row
+    ids alone: it sorts by the same path."""
     rng = np.random.default_rng(34)
-    n = 4000
+    n = 70_000
     tb = pa.table({
         "k": pa.array(rng.integers(0, 50, n).astype(np.int64)),
         "v": pa.array(rng.integers(-9, 9, n).astype(np.int64)),
         "s": pa.array([f"x{int(i) % 13}" for i in rng.integers(0, 99, n)]),
     })
-    outs = []
-    for lean in ("on", "off"):
-        s = (TpuSession.builder()
-             .config("spark.rapids.sql.enabled", True)
-             .config("spark.rapids.tpu.sort.compileLean", lean)
-             .config("spark.rapids.sql.collect.hostAssisted", False)
-             .get_or_create())
-        outs.append(s.create_dataframe(tb, num_partitions=2)
-                    .sort(col("k"), col("v").desc(), col("s")).collect())
-    assert outs[0].equals(outs[1])
+
+    def q(s):
+        return (s.create_dataframe(tb, num_partitions=2)
+                .sort(col("k"), col("v").desc(), col("s")).collect())
+    s = (TpuSession.builder()
+         .config("spark.rapids.sql.enabled", True)
+         .config("spark.rapids.sql.collect.hostAssisted", host_assisted)
+         .get_or_create())
+    got = q(s)
+    # the assisted collect's last plan is the nested one: the row id alone
+    assert (s.last_plan.output_names == ["__rid__"]) == host_assisted
+    want = q(TpuSession.builder().config("spark.rapids.sql.enabled",
+                                         False).get_or_create())
+    # by value: the assisted path answers the host copy's `string`, the
+    # engines `large_string`
+    assert_tables_equal(want, got, ignore_order=False)
 
 
 def test_speculation_miss_does_not_poison_df_cache():
