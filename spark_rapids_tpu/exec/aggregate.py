@@ -66,13 +66,149 @@ def _null_where(xp, col: DeviceColumn, valid) -> DeviceColumn:
                                        for c in col.children))
 
 
+def _split_op(op: str) -> Tuple[str, bool]:
+    """(base op, whether null rows contribute): `first_any` is `first`
+    over every live row."""
+    return (op[:-4], True) if op.endswith("_any") else (op, False)
+
+
+def _ungrouped_reducible(value_cols: List[DeviceColumn],
+                         ops: List[str]) -> bool:
+    """Whether every op of an ungrouped aggregate is a reduction of its
+    lane under a mask (see `_group_reduce`)."""
+    for vc, op in zip(value_cols, ops):
+        base_op = _split_op(op)[0]
+        if base_op in ("countvalid", "first", "last"):
+            continue
+        wide = vc.data_hi is not None       # decimal128: two words a row
+        if base_op == "sum" and not wide and \
+                not _needs_index_gather(vc.dtype):
+            continue
+        if base_op in ("min", "max") and not wide and \
+                not isinstance(vc.dtype, (t.StringType, t.BinaryType)):
+            continue
+        return False
+    return True
+
+
+def _ieee_sum(xp, finite_sum, n_pi, n_ni, n_nan):
+    """A float sum from the sum of its finite terms and the counts of its
+    +inf, -inf and nan terms, as IEEE addition would have it."""
+    out = xp.where((n_nan > 0) | ((n_pi > 0) & (n_ni > 0)),
+                   xp.full_like(finite_sum, xp.nan), finite_sum)
+    out = xp.where((n_pi > 0) & (n_ni == 0) & (n_nan == 0),
+                   xp.full_like(out, xp.inf), out)
+    return xp.where((n_ni > 0) & (n_pi == 0) & (n_nan == 0),
+                    xp.full_like(out, -xp.inf), out)
+
+
+# minor axis of the two-level float sum: 33,554,432 rows fold as 4,096
+# rows of 8,192, so no addition chain is longer than the wider axis
+_SUM_MINOR = 8192
+
+
+def _sum_two_level(xp, vals):
+    """Sum of a float lane in a fixed two-level shape (rows of
+    `_SUM_MINOR`, then the row sums), so that which values meet in an
+    addition depends on their positions alone and the rounding error
+    grows with the axes' lengths, not with the lane's.  Padding zeros add
+    exactly."""
+    n = vals.shape[0]
+    pad = -n % _SUM_MINOR
+    if pad:
+        vals = xp.concatenate([vals, xp.zeros((pad,), vals.dtype)])
+    return xp.sum(xp.sum(vals.reshape(-1, _SUM_MINOR), axis=1))
+
+
+def _reduce_ungrouped(xp, value_cols: List[DeviceColumn], ops: List[str],
+                      cap: int, live) -> List[DeviceColumn]:
+    """The masked arm of `_group_reduce`: one group, so each op reduces
+    its lane under `contrib` where the rows lie.  Null, inf, nan and
+    empty-input semantics are the sort arm's to the letter; `first` and
+    `last` read the arrival position, which is what that arm's stable
+    sort on `~live` preserved.  Returns one-row columns of capacity
+    `DEFAULT_ROW_BUCKETS[0]`."""
+    out_cap = DEFAULT_ROW_BUCKETS[0]
+    slot0 = xp.arange(out_cap, dtype=np.int32) == 0
+    iota = xp.arange(cap, dtype=np.int32)
+
+    def count(mask):
+        return xp.sum(mask.astype(np.int32), dtype=np.int32)
+
+    def one_row(dtype, value, valid) -> DeviceColumn:
+        v = slot0 & valid
+        return DeviceColumn(dtype, validity=v, data=xp.where(
+            v, value, xp.zeros((), dtype=value.dtype)))
+
+    out_values: List[DeviceColumn] = []
+    for vc, op in zip(value_cols, ops):
+        base_op, any_row = _split_op(op)
+        contrib = live if any_row or vc.validity is None else \
+            vc.validity & live
+        cnt = count(contrib)
+        if base_op == "countvalid":
+            out_values.append(one_row(t.LONG, cnt.astype(np.int64),
+                                      slot0))
+            continue
+        if base_op in ("first", "last") or _needs_index_gather(vc.dtype):
+            # (min/max of a struct, array or map reads the first row, as
+            # in the sort arm)
+            if base_op == "last":
+                idx = xp.max(xp.where(contrib, iota, np.int32(-1)))
+            else:
+                idx = xp.min(xp.where(contrib, iota,
+                                      np.int32(2**31 - 1)))
+            out_values.append(gather_column(
+                xp, vc, xp.where(slot0, idx, np.int32(0)),
+                slot0 & (cnt > 0)))
+            continue
+        data = vc.data
+        if base_op in ("min", "max"):
+            row = seg.masked_argext(xp, data, contrib,
+                                    is_min=(base_op == "min"))
+            out_values.append(one_row(vc.dtype, data[row], cnt > 0))
+            continue
+        vals0 = xp.where(contrib, data, xp.zeros((), dtype=data.dtype))
+        if np.dtype(data.dtype).kind != "f":
+            # exact modulo 2^width, as the scan's difference is
+            out = xp.sum(vals0, dtype=data.dtype)
+        else:
+            # the finite values only; IEEE's inf/nan from their counts
+            # (the chip's float64 is a float32 pair: its add is not
+            # trusted to carry them)
+            finite = _sum_two_level(xp, xp.where(
+                xp.isfinite(vals0), vals0, xp.zeros((), dtype=data.dtype)))
+            out = _ieee_sum(xp, finite, count(contrib & (data == xp.inf)),
+                            count(contrib & (data == -xp.inf)),
+                            count(contrib & xp.isnan(data)))
+        out_values.append(one_row(vc.dtype, out, cnt > 0))
+    return out_values
+
+
 def _group_reduce(xp, key_cols: List[DeviceColumn],
                   value_cols: List[DeviceColumn], ops: List[str],
                   cap: int, live, global_agg: bool):
-    """Core sort+segment kernel.  Returns (out_key_cols, out_value_cols,
+    """Core aggregate kernel.  Returns (out_key_cols, out_value_cols,
     num_groups).
 
-    Kernel structure (see ops/carry.py docstring for the chip
+    Two arms, chosen from what the call can see (`global_agg`, `ops`, the
+    value columns' dtypes), never from a size or a key:
+
+      - **masked reduction** (`_reduce_ungrouped`): an ungrouped
+        aggregate whose every op is reducible (`_ungrouped_reducible`:
+        sum of a flat integer or float lane, countvalid, min/max of a
+        flat numeric lane, first/last and their `_any` forms of any
+        column).  One group needs no order: each op is a reduction of
+        its lane under the mask, and the answer is a ONE-row batch in the
+        smallest row bucket.  No sort, no scan, no compaction.  Q6's sum
+        takes it (update, and the merge of partials alike).
+      - **sort + segment** (below): every grouped call, and an ungrouped
+        one that holds a collect_*, an ordered min/max of strings,
+        binaries or decimal128, or a decimal128 sum: those compact or
+        order values, and one such op sends the whole call here.  Its
+        output keeps the input's capacity.
+
+    Sort + segment structure (see ops/carry.py docstring for the chip
     measurements behind it):
 
       1. ONE stable sort by the key words.  Every flat lane of the key
@@ -91,6 +227,12 @@ def _group_reduce(xp, key_cols: List[DeviceColumn],
          gather; variable-width columns keep the gather-based paths.
     """
     from ..ops import carry
+    if global_agg:
+        reduced = _ungrouped_reducible(value_cols, ops)
+        carry.count_ungrouped(reduced)
+        if reduced:
+            return [], _reduce_ungrouped(xp, value_cols, ops, cap, live), \
+                xp.int32(1)
     # --- sort keys, carrying all row data -----------------------------------
     words: List = [~live]  # padding rows sort last
     for kc in key_cols:
@@ -163,12 +305,8 @@ def _group_reduce(xp, key_cols: List[DeviceColumn],
             sum_jobs.append(dict(kind="count", out=oi, lane=li,
                                  total=total))
             continue
-        if op.endswith("_any"):
-            base_op = op[:-4]
-            contrib = live_sorted
-        else:
-            base_op = op
-            contrib = validity_sorted
+        base_op, any_row = _split_op(op)
+        contrib = live_sorted if any_row else validity_sorted
         is_dec128 = vs.data_hi is not None
         if is_dec128 and base_op == "sum":
             lo_o, hi_o, cnt = seg.segment_sum128(xp, vs.data, vs.data_hi,
@@ -293,15 +431,8 @@ def _group_reduce(xp, key_cols: List[DeviceColumn],
         else:
             out = span_diff(job["lane"], job["total"])
         if "pi" in job:
-            n_pi = span_diff(*job["pi"])
-            n_ni = span_diff(*job["ni"])
-            n_nan = span_diff(*job["nan"])
-            out = xp.where((n_nan > 0) | ((n_pi > 0) & (n_ni > 0)),
-                           xp.full_like(out, xp.nan), out)
-            out = xp.where((n_pi > 0) & (n_ni == 0) & (n_nan == 0),
-                           xp.full_like(out, xp.inf), out)
-            out = xp.where((n_ni > 0) & (n_pi == 0) & (n_nan == 0),
-                           xp.full_like(out, -xp.inf), out)
+            out = _ieee_sum(xp, out, span_diff(*job["pi"]),
+                            span_diff(*job["ni"]), span_diff(*job["nan"]))
         validity_out = (cnt > 0) & slot_valid
         out = xp.where(validity_out, out, xp.zeros_like(out))
         out_values[job["out"]] = DeviceColumn(job["dtype"], data=out,

@@ -53,19 +53,33 @@ class _MoveCounts(threading.local):
     sorted = 0        # lanes moved by sort passes
     gathered = 0      # lanes of span columns moved by gather_column
     passes = 0        # every `_sort_pass`, the orders' own included
+    ungrouped_reduced = 0   # ungrouped aggregates that reduce under a mask
+    ungrouped_sorted = 0    # ungrouped aggregates that sort and compact
 
 
 _COUNTS = _MoveCounts()
 
 
 def lane_move_counts() -> dict:
-    """Lanes moved by sort pass, lanes moved by gather and sort passes
-    traced on this thread so far, under the names a program's build
-    record gives them.  Tracing a program raises them, so the difference
-    around a `lower()` is what that program does."""
+    """Lanes moved by sort pass, lanes moved by gather, sort passes, and
+    the ungrouped aggregates that moved no row at all (or did) traced on
+    this thread so far, under the names a program's build record gives
+    them.  Tracing a program raises them, so the difference around a
+    `lower()` is what that program does."""
     return {"lane_moves_sorted": _COUNTS.sorted,
             "lane_moves_gathered": _COUNTS.gathered,
-            "sort_passes": _COUNTS.passes}
+            "sort_passes": _COUNTS.passes,
+            "ungrouped_reduced": _COUNTS.ungrouped_reduced,
+            "ungrouped_sorted": _COUNTS.ungrouped_sorted}
+
+
+def count_ungrouped(reduced: bool) -> None:
+    """One ungrouped `exec/aggregate._group_reduce` call, by the arm it
+    took: a masked reduction where the rows lie, or the sort arm."""
+    if reduced:
+        _COUNTS.ungrouped_reduced += 1
+    else:
+        _COUNTS.ungrouped_sorted += 1
 
 
 def _gather_span_column(xp, col: DeviceColumn, order, cap: int):

@@ -264,6 +264,19 @@ def _argext_rows(xp, values, seg, num_segments: int, valid, is_min: bool):
     return xp.clip(row, 0, values.shape[0] - 1).astype(xp.int32)
 
 
+def masked_argext(xp, values, valid, is_min: bool):
+    """Row index of the extreme value among the `valid` rows of ONE
+    segment (ties -> first row; 0 where none is valid): `_argext_rows`'
+    tournament over the same ordered words, so the same order of NaN and
+    of -0.0, as plain reductions instead of scatters."""
+    sel = valid
+    for w in _ordered_words32(xp, values, descending=not is_min):
+        sel = sel & (w == xp.min(xp.where(sel, w, _I32_MAX)))
+    iota = xp.arange(values.shape[0], dtype=xp.int32)
+    row = xp.min(xp.where(sel, iota, _I32_MAX))
+    return xp.clip(row, 0, values.shape[0] - 1).astype(xp.int32)
+
+
 def _counts(xp, seg, num_segments: int, valid):
     import jax
     c = jax.ops.segment_sum(valid.astype(xp.int32), seg,

@@ -13,7 +13,8 @@ import jax
 import jax.numpy as jnp
 
 from spark_rapids_tpu import types as t
-from spark_rapids_tpu.columnar.device import DeviceBatch, DeviceColumn
+from spark_rapids_tpu.columnar.device import (DEFAULT_ROW_BUCKETS, DeviceBatch,
+                                              DeviceColumn)
 from spark_rapids_tpu.ops import carry
 
 
@@ -284,10 +285,18 @@ def test_compact_and_group_reduce_lower_without_a_capacity_gather():
         live = jnp.arange(cap, dtype=jnp.int32) < b.num_rows
         return _group_reduce(jnp, [], [b.columns[1]], ["sum"], cap, live,
                              True)
-    for fn in (grouped, ungrouped):
-        text = jax.jit(fn).lower(batch).as_text()
-        assert "stablehlo.sort" in text
-        assert _capacity_gathers(text, cap) == []
+    text = jax.jit(grouped).lower(batch).as_text()
+    assert "stablehlo.sort" in text
+    assert _capacity_gathers(text, cap) == []
+    # one group orders nothing: a reduction under the mask, one row out in
+    # the smallest bucket (Q6's sum)
+    lowered = jax.jit(ungrouped).lower(batch)
+    text = lowered.as_text()
+    assert "stablehlo.sort" not in text
+    assert _capacity_gathers(text, cap) == []
+    _, values, _ = lowered.out_info
+    assert [leaf.shape for leaf in jax.tree_util.tree_leaves(values)] == \
+        [(DEFAULT_ROW_BUCKETS[0],)] * 2
     # the check can see one: the gather path of a string column
     col = jax.tree_util.tree_map(jnp.asarray, _columns()["string"])
     text = jax.jit(lambda c, k: carry.compact_rows(jnp, k, [c], N)).lower(
@@ -333,6 +342,54 @@ def test_a_flat_filter_builds_a_program_that_gathers_no_lane():
     assert last["sort_passes"] == 7     # a:2 b:2 c:1 d:1, six flags: 1
     assert spans and spans[-1].attrs["lane_moves_gathered"] == 0
     assert spans[-1].attrs["lane_moves_sorted"] == 10
+
+
+def _ungrouped_programs(agg):
+    """The records of the aggregate programs that one ungrouped query
+    built, and its answer."""
+    import pyarrow as pa
+    from spark_rapids_tpu.api.session import TpuSession
+    from spark_rapids_tpu.obs.compileprof import CompileObservatory
+    rng = np.random.default_rng(3)
+    n = 700
+    data = {"p": rng.uniform(900.0, 105000.0, n), "d": rng.uniform(0, 0.1, n)}
+    s = TpuSession.builder().config("spark.rapids.sql.enabled", True) \
+        .get_or_create()
+    seen = {(p["key"], p["shape"])
+            for p in CompileObservatory.get().snapshot()["programs"]}
+    out = s.create_dataframe(pa.table(data), num_partitions=1).agg(agg) \
+        .collect()
+    built = [p for p in CompileObservatory.get().snapshot()["programs"]
+             if p["exec"] == "TpuHashAggregateExec" and
+             (p["key"], p["shape"]) not in seen]
+    return built, out, data
+
+
+def test_an_ungrouped_sum_builds_a_program_that_sorts_nothing():
+    """Q6's shape: sum(price * discount) over one batch, no keys."""
+    from spark_rapids_tpu.api import functions as F
+    from spark_rapids_tpu.api.column import col
+    built, out, data = _ungrouped_programs(
+        F.sum(col("p") * col("d")).alias("revenue"))
+    assert len(built) == 1
+    assert built[0]["ungrouped_reduced"] == 1
+    assert built[0]["ungrouped_sorted"] == 0
+    assert built[0]["sort_passes"] == 0
+    assert built[0]["lane_moves_sorted"] == 0
+    assert built[0]["lane_moves_gathered"] == 0
+    want = float((data["p"] * data["d"]).sum())
+    assert abs(out.column("revenue")[0].as_py() - want) <= 1e-12 * want
+
+
+def test_an_ungrouped_collect_list_still_takes_the_sort_arm():
+    from spark_rapids_tpu.api import functions as F
+    from spark_rapids_tpu.api.column import col
+    built, out, data = _ungrouped_programs(F.collect_list(col("p")).alias("l"))
+    assert built
+    assert sum(p["ungrouped_reduced"] for p in built) == 0
+    assert sum(p["ungrouped_sorted"] for p in built) >= 1
+    assert sum(p["sort_passes"] for p in built) > 0
+    assert out.column("l")[0].as_py() == list(data["p"])
 
 
 def test_a_string_column_counts_as_gathered():

@@ -345,3 +345,167 @@ def test_group_reduce_scale_and_skew_differential():
                                                           abs(y)), name
             else:
                 assert x == y, (name, x, y)
+
+
+# -- the ungrouped aggregate: a reduction under the mask, no sort -------------
+# (exec/aggregate._reduce_ungrouped; every case counts on its own)
+
+def _ungrouped_inputs():
+    import numpy as np
+    import pyarrow as pa
+    rng = np.random.default_rng(30)
+    n = 700                                  # 324 padding rows of the bucket
+    i = rng.integers(-(10**15), 10**15, n)
+    f = rng.standard_normal(n) * 10.0 ** rng.integers(-3, 9, n)
+    some = rng.random(n) < 0.3
+    some[[0, n - 1]] = True                  # first and last rows null
+
+    def table(fvals, mask):
+        return pa.table({"i": pa.array(i, mask=mask),
+                         "f": pa.array(fvals, mask=mask)})
+
+    def with_at(values, where):
+        out = f.copy()
+        out[where] = values
+        return out
+    none = np.zeros(n, bool)
+    return {
+        "plain": table(f, none),
+        "nulls": table(f, some),
+        "all_null": table(f, np.ones(n, bool)),
+        "pos_inf": table(with_at(np.inf, [5, 77]), some),
+        "both_infs": table(with_at([np.inf, -np.inf], [5, 77]), some),
+        "nan": table(with_at(np.nan, [3, 400]), some),
+        "nan_and_inf": table(with_at([np.nan, np.inf], [3, 400]), none),
+    }
+
+
+_UNGROUPED_OPS = {
+    "sum_int": lambda: F.sum(col("i")),
+    "sum_float": lambda: F.sum(col("f")),
+    "count": lambda: F.count(col("f")),
+    "count_star": lambda: F.count("*"),
+    "avg": lambda: F.avg(col("f")),
+    "min_int": lambda: F.min(col("i")),
+    "max_int": lambda: F.max(col("i")),
+    "min_float": lambda: F.min(col("f")),
+    "max_float": lambda: F.max(col("f")),
+    "first": lambda: F.first(col("f")),
+    "last": lambda: F.last(col("f")),
+    "first_ignore_nulls": lambda: F.first(col("f"), ignorenulls=True),
+    "last_ignore_nulls": lambda: F.last(col("i"), ignorenulls=True),
+}
+_UNGROUPED_INPUTS = ["plain", "nulls", "all_null", "empty", "pos_inf",
+                     "both_infs", "nan", "nan_and_inf"]
+
+
+@pytest.mark.parametrize("op", sorted(_UNGROUPED_OPS))
+@pytest.mark.parametrize("data", _UNGROUPED_INPUTS)
+def test_ungrouped_reduction_against_the_cpu_engine(data, op):
+    tbl = _ungrouped_inputs()["plain" if data == "empty" else data]
+
+    def q(spark):
+        df = spark.create_dataframe(tbl, num_partitions=1)
+        if data == "empty":
+            df = df.filter(lit(False))
+        return df.agg(_UNGROUPED_OPS[op]().alias("x"))
+    if op == "max_float" and data in ("nan", "nan_and_inf"):
+        # Spark orders NaN above every value; pyarrow's max, the CPU
+        # engine's, skips it: hold the TPU path to Spark
+        import math
+        from spark_rapids_tpu.testing.asserts import with_tpu_session
+        out = with_tpu_session(lambda s: q(s).collect())
+        assert math.isnan(out.column("x")[0].as_py())
+        return
+    assert_tpu_and_cpu_are_equal_collect(q, approximate_float=1e-12)
+
+
+def _reduce_both_arms(data, monkeypatch):
+    """Every reducible op over one input through `_group_reduce`: the
+    masked arm under jit, the masked arm on numpy, and the sort arm under
+    jit (the choice of arm held to "not reducible")."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar.device import batch_to_device
+    from spark_rapids_tpu.exec import aggregate as agg
+    tbl = _ungrouped_inputs()[data]
+    ops = ["sum", "sum", "countvalid", "min", "max", "min", "max", "first",
+           "last", "first_any", "last_any"]
+    names = ["i", "f", "f", "i", "i", "f", "f", "f", "i", "f", "i"]
+
+    def run(xp, batch):
+        live = xp.arange(batch.capacity, dtype=np.int32) < batch.num_rows
+        cols = [batch.columns[batch.names.index(n)] for n in names]
+        _, values, n_groups = agg._group_reduce(xp, [], cols, ops,
+                                                batch.capacity, live, True)
+        return values, n_groups
+    host = batch_to_device(tbl.to_batches()[0], xp=np)
+    dev = batch_to_device(tbl.to_batches()[0], xp=jnp)
+    masked_np = run(np, host)
+    masked = jax.jit(lambda b: run(jnp, b))(dev)
+    monkeypatch.setattr(agg, "_ungrouped_reducible", lambda *_: False)
+    sort_arm = jax.jit(lambda b: run(jnp, b))(dev)
+    return ops, masked_np, masked, sort_arm
+
+
+@pytest.mark.parametrize("data", [d for d in _UNGROUPED_INPUTS
+                                  if d != "empty"])
+def test_masked_arm_answers_as_the_sort_arm(data, monkeypatch):
+    """Null, inf, nan and first/last semantics are the sort arm's to the
+    letter: only a float sum may differ, in its last bits."""
+    import numpy as np
+    from spark_rapids_tpu.columnar.device import DEFAULT_ROW_BUCKETS
+    ops, masked_np, masked, sort_arm = _reduce_both_arms(data, monkeypatch)
+    assert int(masked[1]) == int(masked_np[1]) == int(sort_arm[1]) == 1
+    for op, a, b, c in zip(ops, masked_np[0], masked[0], sort_arm[0]):
+        assert a.capacity == b.capacity == DEFAULT_ROW_BUCKETS[0]
+        assert list(np.asarray(a.validity)[1:]) == \
+            list(np.asarray(b.validity)[1:]) == [False] * (a.capacity - 1)
+        va, vb, vc = (bool(np.asarray(x.validity)[0]) for x in (a, b, c))
+        assert va == vb == vc, op
+        xa, xb, xc = (np.asarray(x.data)[0] for x in (a, b, c))
+        if op == "sum" and xa.dtype.kind == "f" and np.isfinite(xc):
+            scale = float(np.abs(np.nan_to_num(np.asarray(
+                _ungrouped_inputs()[data].column("f")), posinf=0.0,
+                neginf=0.0)).sum())
+            assert abs(xa - xc) <= 1e-13 * scale, op
+            assert abs(xb - xc) <= 1e-13 * scale, op
+        else:
+            assert xa.tobytes() == xb.tobytes() == xc.tobytes(), \
+                (op, xa, xb, xc)
+
+
+def test_ungrouped_mix_with_collect_list_takes_the_sort_arm():
+    """One op that compacts values sends the whole call down the sort
+    arm, which still answers."""
+    from spark_rapids_tpu.ops import carry
+    tbl = _ungrouped_inputs()["nulls"]
+
+    def q(spark):
+        df = spark.create_dataframe(tbl, num_partitions=1)
+        return df.agg(F.sum(col("i")).alias("s"),
+                      F.collect_list(col("i")).alias("l"),
+                      F.max(col("f")).alias("m"))
+    before = carry.lane_move_counts()
+    _, tpu = assert_tpu_and_cpu_are_equal_collect(q, approximate_float=1e-12)
+    after = carry.lane_move_counts()
+    assert after["ungrouped_reduced"] == before["ungrouped_reduced"]
+    assert tpu.column("l")[0].as_py() == \
+        [v for v in tbl.column("i").to_pylist() if v is not None]
+
+
+@pytest.mark.parametrize("parts", [1, 3])
+def test_ungrouped_multi_batch_partial_then_final(parts):
+    """Several batches: each update leaves a one-row partial in the
+    smallest bucket, and the merge reduces the handful of them."""
+    import pyarrow as pa
+    tbl = _ungrouped_inputs()["nulls"]
+    tbl = pa.Table.from_batches(tbl.to_batches(max_chunksize=150))
+
+    def q(spark):
+        df = spark.create_dataframe(tbl, num_partitions=parts)
+        return df.agg(F.sum(col("i")).alias("s"), F.sum(col("f")).alias("sf"),
+                      F.avg(col("f")).alias("a"), F.count("*").alias("c"),
+                      F.min(col("f")).alias("mn"), F.max(col("i")).alias("mx"))
+    assert_tpu_and_cpu_are_equal_collect(q, approximate_float=1e-12)
