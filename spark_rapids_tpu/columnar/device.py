@@ -57,39 +57,141 @@ def bucket_floor(target: int, buckets: Sequence[int]) -> int:
     return floor
 
 
-class DeviceColumn:
-    """One column of device data.  A pytree; static aux is the SQL dtype."""
+#: the widest string that is held as one row-aligned word
+FIXED_WIDTH_MAX = 4
+_WORD_DTYPES = {1: np.uint8, 2: np.uint16, 3: np.uint32, 4: np.uint32}
 
-    __slots__ = ("dtype", "data", "validity", "offsets", "data_hi", "children")
+
+def fixed_word_dtype(width: int):
+    """The unsigned integer that holds a fixed-width string of `width`
+    bytes as one big-endian value."""
+    return _WORD_DTYPES[width]
+
+
+def fixed_word_bytes(word, width: int):
+    """uint8[cap * width]: the bytes of a fixed-width string's word lane,
+    row after row (the general layout's char buffer)."""
+    if width == 1:
+        return word
+    xp = np if isinstance(word, np.ndarray) else jnp
+    dt = fixed_word_dtype(width)
+    parts = [(word >> dt(8 * (width - 1 - k))).astype(np.uint8)
+             for k in range(width)]
+    return xp.stack(parts, axis=1).reshape(-1)
+
+
+def fixed_word_from_bytes(xp, chars, width: int):
+    """The inverse: a word lane from `cap * width` bytes."""
+    if width == 1:
+        return chars
+    dt = fixed_word_dtype(width)
+    b = chars.reshape(-1, width).astype(dt)
+    word = b[:, 0]
+    for k in range(1, width):
+        word = (word << dt(8)) | b[:, k]
+    return word
+
+
+class DeviceColumn:
+    """One column of device data.  A pytree; static aux is the SQL dtype
+    (and, for a fixed-width string, its byte width).
+
+    **Fixed-width strings.**  A string or binary column whose every value
+    has the same byte width w, 1 <= w <= `FIXED_WIDTH_MAX`, and no null
+    (seen from the Arrow offsets at upload) is held as ONE row-aligned
+    lane, `word`: the value itself as a big-endian unsigned integer
+    (uint8 for w = 1, uint16 for 2, uint32 for 3 and 4), so that word
+    order is byte order and word equality is string equality.  Its
+    `fixed_width` is w (static), it stores no offsets (they are
+    `arange * w`) and it moves through sorts and compactions like any
+    other 32-bit word (`ops/carry.py`).  Code that knows only the
+    general layout reads `data` and `offsets` and gets them, computed
+    from the word: every row r is the span [r*w, (r+1)*w), padding rows
+    too (their bytes are zero and their validity false).  A null that an
+    operator makes later (validity false) keeps its w zero bytes."""
+
+    __slots__ = ("dtype", "_data", "validity", "_offsets", "data_hi",
+                 "children", "fixed_width")
 
     def __init__(self, dtype: t.DataType, data=None, validity=None,
                  offsets=None, data_hi=None,
-                 children: Tuple["DeviceColumn", ...] = ()):
+                 children: Tuple["DeviceColumn", ...] = (),
+                 fixed_width: Optional[int] = None):
         self.dtype = dtype
-        self.data = data
+        self._data = data
         self.validity = validity
-        self.offsets = offsets
+        self._offsets = offsets
         self.data_hi = data_hi
         self.children = tuple(children)
+        self.fixed_width = fixed_width
+
+    @classmethod
+    def fixed_string(cls, dtype: t.DataType, word, validity,
+                     width: int) -> "DeviceColumn":
+        """A fixed-width string column from its word lane."""
+        return cls(dtype, data=word, validity=validity, fixed_width=width)
+
+    # -- the general string layout, stored or computed -----------------------
+    @property
+    def data(self):
+        if self.fixed_width is None:
+            return self._data
+        return fixed_word_bytes(self._data, self.fixed_width)
+
+    @property
+    def offsets(self):
+        if self.fixed_width is None:
+            return self._offsets
+        xp = np if isinstance(self._data, np.ndarray) else jnp
+        return xp.arange(int(self._data.shape[0]) + 1,
+                         dtype=np.int32) * np.int32(self.fixed_width)
+
+    def with_word(self, word, validity) -> "DeviceColumn":
+        """This fixed-width string with another word lane and validity."""
+        return DeviceColumn(self.dtype, data=word, validity=validity,
+                            fixed_width=self.fixed_width)
+
+    @property
+    def word(self):
+        """The row-aligned lane of a fixed-width string (None otherwise)."""
+        return None if self.fixed_width is None else self._data
+
+    def stored_lanes(self):
+        """(kind, lane) of the four lanes as they are STORED, in the
+        tree_flatten leaf order; a lane that is absent is None.  A
+        fixed-width string's are its word and its validity."""
+        return (("data", self._data), ("validity", self.validity),
+                ("offsets", self._offsets), ("hi", self.data_hi))
+
+    @property
+    def has_offsets(self) -> bool:
+        """Whether the column STORES offsets, so that its rows cannot ride
+        a row permutation as lanes (a fixed-width string stores none)."""
+        return self._offsets is not None
 
     # -- pytree -------------------------------------------------------------
     def tree_flatten(self):
-        leaves = (self.data, self.validity, self.offsets, self.data_hi,
+        leaves = (self._data, self.validity, self._offsets, self.data_hi,
                   self.children)
-        return leaves, self.dtype
+        aux = self.dtype if self.fixed_width is None else \
+            (self.dtype, self.fixed_width)
+        return leaves, aux
 
     @classmethod
-    def tree_unflatten(cls, dtype, leaves):
+    def tree_unflatten(cls, aux, leaves):
         data, validity, offsets, data_hi, children = leaves
-        return cls(dtype, data, validity, offsets, data_hi, children)
+        dtype, width = aux if isinstance(aux, tuple) else (aux, None)
+        return cls(dtype, data, validity, offsets, data_hi, children, width)
 
     # -- properties ---------------------------------------------------------
     @property
     def capacity(self) -> int:
-        if self.data is not None and not isinstance(self.dtype, (t.StringType, t.BinaryType)):
-            return int(self.data.shape[0])
-        if self.offsets is not None:
-            return int(self.offsets.shape[0]) - 1
+        if self._data is not None and (
+                self.fixed_width is not None or
+                not isinstance(self.dtype, (t.StringType, t.BinaryType))):
+            return int(self._data.shape[0])
+        if self._offsets is not None:
+            return int(self._offsets.shape[0]) - 1
         if self.validity is not None:
             return int(self.validity.shape[0])
         raise ValueError("empty column")
@@ -98,7 +200,9 @@ class DeviceColumn:
         return jnp.arange(self.capacity, dtype=jnp.int32) < num_rows
 
     def __repr__(self):
-        return f"DeviceColumn({self.dtype.name}, cap={self.capacity})"
+        fixed = "" if self.fixed_width is None else \
+            f", fixed_width={self.fixed_width}"
+        return f"DeviceColumn({self.dtype.name}, cap={self.capacity}{fixed})"
 
 
 jax.tree_util.register_pytree_node(
@@ -174,6 +278,8 @@ def shrink_column(col: DeviceColumn, cap: int) -> DeviceColumn:
     capacities (they are byte/element-bucketed, not row-bucketed)."""
     dtype = col.dtype
     validity = None if col.validity is None else col.validity[:cap]
+    if col.fixed_width is not None:
+        return col.with_word(col.word[:cap], validity)
     if isinstance(dtype, (t.StringType, t.BinaryType)):
         return DeviceColumn(dtype, data=col.data, validity=validity,
                             offsets=col.offsets[:cap + 1])
@@ -236,9 +342,29 @@ def _decimal_unscaled(arr: pa.Array) -> Tuple[np.ndarray, np.ndarray]:
     return lo, hi
 
 
+def _uniform_width(offs: np.ndarray, n: int) -> Optional[int]:
+    """The byte width every one of `n` strings has, if they all have the
+    same one and it fits a word (1..FIXED_WIDTH_MAX); else None."""
+    if n == 0:
+        return None
+    w = int(offs[1] - offs[0])
+    if not 1 <= w <= FIXED_WIDTH_MAX or int(offs[n]) != n * w:
+        return None
+    if not np.array_equal(offs[:n + 1],
+                          np.arange(n + 1, dtype=offs.dtype) * w):
+        return None
+    return w
+
+
 def column_to_device(arr: pa.Array, dtype: t.DataType, cap: int,
                      char_buckets: Sequence[int] = DEFAULT_CHAR_BUCKETS,
-                     xp=jnp) -> DeviceColumn:
+                     xp=jnp, fixed_width_strings: bool = True
+                     ) -> DeviceColumn:
+    """One Arrow array as a device column of capacity `cap`.  A string or
+    binary array without nulls whose values all have one byte width of
+    1..FIXED_WIDTH_MAX becomes a fixed-width column (`DeviceColumn`'s
+    docstring); `fixed_width_strings=False` keeps the general layout (the
+    tests hold the two to each other)."""
     if isinstance(arr, pa.ChunkedArray):
         arr = arr.combine_chunks()
     n = len(arr)
@@ -260,6 +386,12 @@ def column_to_device(arr: pa.Array, dtype: t.DataType, cap: int,
                                   count=base + nbytes)[base:]
         else:
             chars = np.zeros(0, dtype=np.uint8)
+        width = _uniform_width(offs, n) \
+            if fixed_width_strings and not arr.null_count else None
+        if width is not None:
+            word = fixed_word_from_bytes(np, chars[:nbytes], width)
+            return DeviceColumn.fixed_string(
+                dtype, xp.asarray(_np_pad(word, cap)), validity, width)
         char_cap = bucket_for(max(nbytes, 1), char_buckets)
         offs_p = np.full((cap + 1,), offs[-1] if n else 0, dtype=np.int32)
         offs_p[:n + 1] = offs
@@ -288,7 +420,7 @@ def column_to_device(arr: pa.Array, dtype: t.DataType, cap: int,
         child = larr.values[base: base + int(offs[-1])]
         child_cap = bucket_for(len(child), DEFAULT_ROW_BUCKETS)
         child_col = column_to_device(child, dtype.element_type, child_cap,
-                                     char_buckets, xp)
+                                     char_buckets, xp, False)
         offs_p = np.full((cap + 1,), offs[-1] if n else 0, dtype=np.int32)
         offs_p[:n + 1] = offs
         return DeviceColumn(dtype, validity=validity,
@@ -324,9 +456,9 @@ def column_to_device(arr: pa.Array, dtype: t.DataType, cap: int,
         nkv = int(offs[-1]) if n else 0
         child_cap = bucket_for(max(nkv, 1), DEFAULT_ROW_BUCKETS)
         kcol = column_to_device(keys_src.slice(base, nkv), dtype.key_type,
-                                child_cap, char_buckets, xp)
+                                child_cap, char_buckets, xp, False)
         vcol = column_to_device(items_src.slice(base, nkv), dtype.value_type,
-                                child_cap, char_buckets, xp)
+                                child_cap, char_buckets, xp, False)
         offs_p = np.full((cap + 1,), offs[-1] if n else 0, dtype=np.int32)
         offs_p[:n + 1] = offs
         return DeviceColumn(dtype, validity=validity,
@@ -337,7 +469,7 @@ def column_to_device(arr: pa.Array, dtype: t.DataType, cap: int,
         children = []
         for i, f in enumerate(dtype.fields):
             children.append(column_to_device(arr.field(i), f.data_type, cap,
-                                             char_buckets, xp))
+                                             char_buckets, xp, False))
         return DeviceColumn(dtype, validity=validity, children=tuple(children))
 
     if isinstance(dtype, t.NullType):
@@ -362,7 +494,8 @@ def batch_to_device(rb: pa.RecordBatch,
                     row_buckets: Sequence[int] = DEFAULT_ROW_BUCKETS,
                     char_buckets: Sequence[int] = DEFAULT_CHAR_BUCKETS,
                     capacity: Optional[int] = None, xp=jnp,
-                    device=None) -> DeviceBatch:
+                    device=None, fixed_width_strings: bool = True
+                    ) -> DeviceBatch:
     """Upload an Arrow RecordBatch, padding to a capacity bucket.
 
     With ``xp=jnp`` this is the host->device crossing: the span
@@ -380,7 +513,8 @@ def batch_to_device(rb: pa.RecordBatch,
         for i, f in enumerate(rb.schema):
             dtype = from_arrow_type(f.type)
             cols.append(column_to_device(rb.column(i), dtype, cap,
-                                         char_buckets, xp))
+                                         char_buckets, xp,
+                                         fixed_width_strings))
         return DeviceBatch(cols, n, names=rb.schema.names)
 
     if xp is not jnp:
@@ -401,7 +535,8 @@ def batch_to_device(rb: pa.RecordBatch,
         nbytes = sum(int(leaf.nbytes)
                      for leaf in jax.tree_util.tree_leaves(batch)
                      if isinstance(leaf, jax.Array))
-        sp.set(bytes=nbytes)
+        sp.set(bytes=nbytes, fixed_width_string_cols=sum(
+            1 for c in batch.columns if c.fixed_width is not None))
     m.counter("tpu_upload_bytes_total",
               "bytes placed on the device by batch_to_device, validity "
               "lanes included").inc(nbytes)
@@ -418,8 +553,14 @@ def column_to_arrow(col: DeviceColumn, n: int) -> pa.Array:
     dtype = col.dtype
 
     if isinstance(dtype, (t.StringType, t.BinaryType)):
-        offs = np.asarray(col.offsets)[:n + 1].astype(np.int64)
-        chars = np.asarray(col.data)
+        if col.fixed_width is not None:
+            # the word lane's first n values as bytes; offsets are a ramp
+            w = col.fixed_width
+            chars = fixed_word_bytes(np.asarray(col.word)[:n], w)
+            offs = np.arange(n + 1, dtype=np.int64) * w
+        else:
+            offs = np.asarray(col.offsets)[:n + 1].astype(np.int64)
+            chars = np.asarray(col.data)
         nbytes = int(offs[-1]) if n else 0
         pa_type = pa.large_binary() if isinstance(dtype, t.BinaryType) else pa.large_string()
         arr = pa.Array.from_buffers(

@@ -126,14 +126,9 @@ class FetchLayoutError(RuntimeError):
 def _walk_lanes(col: DeviceColumn):
     """Yield (kind, lane) for every present lane: data, validity, offsets,
     data_hi, then children recursively — the tree_flatten leaf order."""
-    if col.data is not None:
-        yield ("data", col.data)
-    if col.validity is not None:
-        yield ("validity", col.validity)
-    if col.offsets is not None:
-        yield ("offsets", col.offsets)
-    if col.data_hi is not None:
-        yield ("hi", col.data_hi)
+    for kind, lane in col.stored_lanes():
+        if lane is not None:
+            yield (kind, lane)
     for ch in col.children:
         yield from _walk_lanes(ch)
 
@@ -152,7 +147,8 @@ def _var_sizes(col: DeviceColumn, n) -> List:
     out: List = []
     dt = col.dtype
     if isinstance(dt, (t.StringType, t.BinaryType)):
-        out.append(col.offsets[n].astype(jnp.int64))
+        if col.fixed_width is None:     # (a fixed width varies nothing)
+            out.append(col.offsets[n].astype(jnp.int64))
     elif isinstance(dt, t.ArrayType):
         m = col.offsets[n]
         out.append(m.astype(jnp.int64))
@@ -186,8 +182,7 @@ def _lane_stats(col: DeviceColumn, n) -> List:
     stats: List = []
 
     def visit(c: DeviceColumn, live_n):
-        for kind, lane in [("data", c.data), ("validity", c.validity),
-                           ("offsets", c.offsets), ("hi", c.data_hi)]:
+        for kind, lane in c.stored_lanes():
             if lane is None:
                 continue
             dt = _np_dtype_of(lane)
@@ -314,6 +309,8 @@ def _shrink_column(col: DeviceColumn, out_cap: int, var_caps) -> DeviceColumn:
     dt = col.dtype
     validity = None if col.validity is None else \
         _slice_or_pad(col.validity, out_cap)
+    if col.fixed_width is not None:
+        return col.with_word(_slice_or_pad(col.word, out_cap), validity)
     if isinstance(dt, (t.StringType, t.BinaryType)):
         char_cap = next(var_caps)
         return DeviceColumn(dt, data=_slice_or_pad(col.data, char_cap),
@@ -365,12 +362,10 @@ def _make_shrink_pack_fn(out_cap: int, var_caps: Tuple[int, ...],
         pi = iter(plan)
 
         def visit(c: DeviceColumn, orig: DeviceColumn, live_n):
-            for kind in ("data", "validity", "offsets", "hi"):
-                attr = "data_hi" if kind == "hi" else kind
-                leaf = getattr(c, attr)
+            for (kind, leaf), (_, oleaf) in zip(c.stored_lanes(),
+                                                 orig.stored_lanes()):
                 if leaf is None:
                     continue
-                oleaf = getattr(orig, attr)
                 step = next(pi)
                 if step[0] == "skip":
                     continue
@@ -453,6 +448,9 @@ def _unpack_column(col: DeviceColumn, rd: _BufReader, out_cap: int,
         raw = rd.take(cap, wire)
         return raw.astype(np.bool_) if ldt == np.bool_ else raw
 
+    if col.fixed_width is not None:
+        word = lane(col.word, out_cap)
+        return col.with_word(word, lane(col.validity, out_cap))
     if isinstance(dt, (t.StringType, t.BinaryType)):
         char_cap = next(var_caps)
         data = lane(col.data, char_cap)
@@ -488,13 +486,15 @@ def _unpack_column(col: DeviceColumn, rd: _BufReader, out_cap: int,
 
 def _schema_key(batch: DeviceBatch) -> tuple:
     def col_key(c: DeviceColumn):
-        return (repr(c.dtype), None if c.data is None else
-                (str(c.data.dtype), tuple(c.data.shape)),
-                c.validity is not None,
-                None if c.offsets is None else
-                (str(c.offsets.dtype), tuple(c.offsets.shape)),
-                None if c.data_hi is None else str(c.data_hi.dtype),
-                tuple(col_key(ch) for ch in c.children))
+        (_, data), _, (_, offsets), _ = c.stored_lanes()
+        key = (repr(c.dtype), None if data is None else
+               (str(data.dtype), tuple(data.shape)),
+               c.validity is not None,
+               None if offsets is None else
+               (str(offsets.dtype), tuple(offsets.shape)),
+               None if c.data_hi is None else str(c.data_hi.dtype),
+               tuple(col_key(ch) for ch in c.children))
+        return key if c.fixed_width is None else key + (c.fixed_width,)
     return tuple(col_key(c) for c in batch.columns)
 
 
@@ -570,7 +570,8 @@ def fetch_batch(batch: DeviceBatch,
     def walk(col: DeviceColumn, it):
         dt = col.dtype
         if isinstance(dt, (t.StringType, t.BinaryType)):
-            var_caps.append(bucket_for(int(next(it)), char_buckets))
+            if col.fixed_width is None:
+                var_caps.append(bucket_for(int(next(it)), char_buckets))
         elif isinstance(dt, t.ArrayType):
             m = int(next(it))
             var_caps.append(bucket_for(m, row_buckets))

@@ -29,7 +29,8 @@ import pyarrow.compute as pc
 
 from .. import types as t
 from ..columnar.device import (DEFAULT_ROW_BUCKETS, DeviceBatch, DeviceColumn,
-                               batch_to_arrow, batch_to_device, bucket_for)
+                               batch_to_arrow, batch_to_device, bucket_for,
+                               shrink_column)
 from ..expr.aggregates import (COMPLETE, FINAL, PARTIAL, AggregateExpression,
                                AggregateFunction, ApproximatePercentile,
                                Average, CollectList, CollectSet, Count,
@@ -53,13 +54,15 @@ def _null_where(xp, col: DeviceColumn, valid) -> DeviceColumn:
     of a row-aligned lane.  (A node with offsets was gathered, and its
     spans are settled.)"""
     v = valid if col.validity is None else valid & col.validity
-    if col.offsets is not None:
+    if col.has_offsets:
         return DeviceColumn(col.dtype, data=col.data, validity=v,
                             offsets=col.offsets, children=col.children)
 
     def zeroed(x):
         return None if x is None else xp.where(v, x,
                                                xp.zeros((), dtype=x.dtype))
+    if col.fixed_width is not None:
+        return col.with_word(zeroed(col.word), v)
     return DeviceColumn(col.dtype, data=zeroed(col.data), validity=v,
                         data_hi=zeroed(col.data_hi),
                         children=tuple(_null_where(xp, c, v)
@@ -185,6 +188,37 @@ def _reduce_ungrouped(xp, value_cols: List[DeviceColumn], ops: List[str],
     return out_values
 
 
+#: values a key column of these types can take, its null apart
+_KEY_VALUES = {t.BooleanType: 2, t.ByteType: 1 << 8, t.ShortType: 1 << 16}
+
+
+def _group_bound(key_cols: List[DeviceColumn]) -> Optional[int]:
+    """The most groups these key columns can form, where their types say:
+    a fixed-width string of w bytes has 256**w values, a boolean 2, a
+    byte 256, a short 65,536, each and one more for the null.  None where
+    a key is unbounded (an int64, a double, a general string)."""
+    bound = 1
+    for kc in key_cols:
+        if kc.fixed_width is not None:
+            values = 1 << (8 * kc.fixed_width)
+        else:
+            values = _KEY_VALUES.get(type(kc.dtype))
+        if values is None:
+            return None
+        bound *= values + 1
+    return bound
+
+
+def _group_capacity(key_cols: List[DeviceColumn], cap: int) -> int:
+    """Output capacity of a grouped `_group_reduce` over `cap` slots: the
+    row bucket that holds `_group_bound`, where that is below `cap`; else
+    `cap`.  Static: read from the key columns' types at trace time."""
+    bound = _group_bound(key_cols)
+    if bound is None:
+        return cap
+    return min(cap, bucket_for(bound, DEFAULT_ROW_BUCKETS))
+
+
 def _group_reduce(xp, key_cols: List[DeviceColumn],
                   value_cols: List[DeviceColumn], ops: List[str],
                   cap: int, live, global_agg: bool):
@@ -206,7 +240,14 @@ def _group_reduce(xp, key_cols: List[DeviceColumn],
         one that holds a collect_*, an ordered min/max of strings,
         binaries or decimal128, or a decimal128 sum: those compact or
         order values, and one such op sends the whole call here.  Its
-        output keeps the input's capacity.
+        output keeps the input's capacity, unless the key columns' types
+        bound the group count below it (`_group_capacity`: two one-byte
+        string keys form at most 257 x 257 groups): the groups are
+        compacted to the front, so the boundary compaction's lanes and
+        every output are cut to that bound's row bucket, exactly, with no
+        host read and no speculation.  What reads a four-group answer
+        (a sort, a HAVING filter, the fetch) then runs at 262,144 slots
+        and not at the table's 33,554,432.
 
     Sort + segment structure (see ops/carry.py docstring for the chip
     measurements behind it):
@@ -402,6 +443,12 @@ def _group_reduce(xp, key_cols: List[DeviceColumn],
 
     # --- ONE compaction: boundary rows -> slot positions --------------------
     _, _, comp = carry.compact_rows(xp, new_group, (), cap, extras=lanes)
+    out_cap = cap if global_agg else _group_capacity(key_cols, cap)
+    if out_cap < cap:
+        # every group's slot lies below the bound: the rest is padding
+        comp = [c[:out_cap] for c in comp]
+        iota_slots = iota_slots[:out_cap]
+        slot_valid = slot_valid[:out_cap]
 
     def span_next(lane_idx, total):
         """Per-slot value from the NEXT slot's compacted lane entry; the
@@ -449,6 +496,10 @@ def _group_reduce(xp, key_cols: List[DeviceColumn],
             col = jax.tree_util.tree_unflatten(
                 treedef, [comp[i] for i in lidx])
             out_keys.append(carry.mask_validity(xp, col, slot_valid))
+    if out_cap < cap:
+        # (the ops reduced before the compaction wrote at `cap`)
+        out_values = [v if v.capacity == out_cap
+                      else shrink_column(v, out_cap) for v in out_values]
     return out_keys, out_values, num_groups
 
 
@@ -820,6 +871,21 @@ class TpuHashAggregateExec(Exec):
         hold = 3.0 * (pp if pp <= budget else budget)
         return MemoryEffects(hold=hold, note="aggregate: spill-managed")
 
+    def _note_rebucket(self, in_capacity: int, out: Batch) -> None:
+        """A reduce whose output came back in a smaller row bucket than
+        its input (`_group_capacity`): the event `aggregate.rebucket` and
+        the counter `tpu_aggregate_output_rebucket_total`."""
+        if out.capacity >= in_capacity:
+            return
+        from ..obs import metrics as m
+        from ..obs.tracer import trace_event
+        trace_event("aggregate.rebucket", op=type(self).__name__,
+                    mode=self.mode, in_capacity=in_capacity,
+                    out_capacity=out.capacity)
+        m.counter("tpu_aggregate_output_rebucket_total",
+                  "grouped aggregate outputs cut to the row bucket of "
+                  "their key columns' static group bound").inc(1)
+
     def execute_partition(self, pid, ctx) -> Iterator[Batch]:
         xp = self.xp
         on_tpu = self.placement == TPU
@@ -851,6 +917,7 @@ class TpuHashAggregateExec(Exec):
                     else:
                         out = self._jit_update(first)
                     maybe_sync(out)
+                self._note_rebucket(first.capacity, out)
                 self.metrics[NUM_OUTPUT_ROWS] += out.num_rows
                 self.metrics[NUM_OUTPUT_BATCHES] += 1
                 yield out
@@ -866,6 +933,7 @@ class TpuHashAggregateExec(Exec):
                     else:
                         out = b  # FINAL: merge happens below
                     maybe_sync(out)
+                self._note_rebucket(b.capacity, out)
                 # accumulated partials are spillable (ref aggregate.scala's
                 # spillable batch accumulation before merge)
                 partials.append(spill.register(out, SpillPriority.INPUT))
@@ -912,6 +980,7 @@ class TpuHashAggregateExec(Exec):
                                                  self._merge_batch(np,
                                                                    merged_in))
                     maybe_sync(out)
+                self._note_rebucket(merged_in.capacity, out)
                 self.metrics[NUM_OUTPUT_ROWS] += out.num_rows
                 self.metrics[NUM_OUTPUT_BATCHES] += 1
                 yield out

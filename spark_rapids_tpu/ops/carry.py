@@ -9,8 +9,9 @@ against 9.9; 16.7M: 35.6 against 145; 33.5M: 80 against 490-930;
 PERF.md, PR 26).  So every sort-then-permute path in the engine (filter
 compaction, sort exec, group-by, window ordering, the joins' probe) moves
 its row data with `lax.sort`, never with `x[order]`.  Columns with span
-structure (strings, arrays, maps: anything with offsets) cannot ride a
-row permutation and keep `gather_column`.
+structure (strings, arrays, maps: anything that stores offsets) cannot
+ride a row permutation and keep `gather_column`; a fixed-width string
+(`columnar/device.DeviceColumn`) stores none and is a lane like any other.
 
 The TPU compiler's time for a `lax.sort` is set by the sort's signature,
 not by how often a program repeats it (asked of the v5e compiler at
@@ -42,6 +43,7 @@ from typing import List, Sequence
 
 import numpy as np
 
+from .. import types as t
 from ..columnar.device import DeviceColumn
 from .gather import gather_column
 
@@ -55,22 +57,27 @@ class _MoveCounts(threading.local):
     passes = 0        # every `_sort_pass`, the orders' own included
     ungrouped_reduced = 0   # ungrouped aggregates that reduce under a mask
     ungrouped_sorted = 0    # ungrouped aggregates that sort and compact
+    strings_aligned = 0     # string columns moved as row-aligned lanes
+    strings_gathered = 0    # string columns moved by offsets and gather
 
 
 _COUNTS = _MoveCounts()
 
 
 def lane_move_counts() -> dict:
-    """Lanes moved by sort pass, lanes moved by gather, sort passes, and
-    the ungrouped aggregates that moved no row at all (or did) traced on
-    this thread so far, under the names a program's build record gives
-    them.  Tracing a program raises them, so the difference around a
-    `lower()` is what that program does."""
+    """Lanes moved by sort pass, lanes moved by gather, sort passes, the
+    ungrouped aggregates that moved no row at all (or did), and the string
+    columns a `sort_rows` moved as row-aligned lanes (fixed-width) or by
+    offsets and gather, traced on this thread so far, under the names a
+    program's build record gives them.  Tracing a program raises them, so
+    the difference around a `lower()` is what that program does."""
     return {"lane_moves_sorted": _COUNTS.sorted,
             "lane_moves_gathered": _COUNTS.gathered,
             "sort_passes": _COUNTS.passes,
             "ungrouped_reduced": _COUNTS.ungrouped_reduced,
-            "ungrouped_sorted": _COUNTS.ungrouped_sorted}
+            "ungrouped_sorted": _COUNTS.ungrouped_sorted,
+            "string_cols_row_aligned": _COUNTS.strings_aligned,
+            "string_cols_gathered": _COUNTS.strings_gathered}
 
 
 def count_ungrouped(reduced: bool) -> None:
@@ -82,9 +89,14 @@ def count_ungrouped(reduced: bool) -> None:
         _COUNTS.ungrouped_sorted += 1
 
 
+def _is_string(col: DeviceColumn) -> bool:
+    return isinstance(col.dtype, (t.StringType, t.BinaryType))
+
+
 def _gather_span_column(xp, col: DeviceColumn, order, cap: int):
     import jax
     _COUNTS.gathered += len(jax.tree_util.tree_leaves(col))
+    _COUNTS.strings_gathered += _is_string(col)
     return gather_column(xp, col, order, xp.ones((cap,), dtype=bool))
 
 
@@ -147,12 +159,11 @@ def _f64_join_bits(w0, w1):
 
 
 def _to_words(xp, x):
-    """(int32 words, rebuild) of one non-bool lane: the 32-bit pieces
-    that move, and the function that makes the lane of them again."""
+    """(int32 words, rebuild) of one lane of 32 or 64 bits: the 32-bit
+    pieces that move, and the function that makes the lane of them again.
+    (Narrower lanes share words: `move_lanes`.)"""
     from jax import lax
     dt = np.dtype(x.dtype)
-    if dt.kind in "iu" and dt.itemsize < 4:
-        return [x.astype(xp.int32)], lambda ws: ws[0].astype(dt)
     if dt.itemsize == 4 and dt.kind in "iuf":
         return [_as_i32(x)], lambda ws: lax.bitcast_convert_type(ws[0], dt)
     if dt.kind in "iu" and dt.itemsize == 8:
@@ -175,11 +186,21 @@ def _to_words(xp, x):
     raise TypeError(f"no sort-pass move for a lane of {dt}")
 
 
+def _narrow_bits(x) -> int:
+    """Bits of a lane that shares a word with others: 1 for a bool, 8 or
+    16 for a narrow integer, 0 for a lane that fills words of its own."""
+    dt = np.dtype(x.dtype)
+    if dt == np.bool_:
+        return 1
+    return 8 * dt.itemsize if dt.kind in "iu" and dt.itemsize < 4 else 0
+
+
 def move_lanes(xp, rank, lanes: Sequence) -> List:
     """Every 1-D lane of `lanes` with row r at `rank[r]` (`rank` a
     permutation of 0..cap-1): `x[order]` for the order whose inverse
     `rank` is, bit for bit, without a gather.  One sort pass per 32-bit
-    word of distinct lane; bool lanes travel 32 to a word."""
+    word of distinct lane; bool lanes and 8- and 16-bit integers share
+    words."""
     if xp is np:
         out = []
         for x in lanes:
@@ -202,15 +223,39 @@ def move_lanes(xp, rank, lanes: Sequence) -> List:
         return _sort_pass(rk, word)[1]
 
     out_u: List = [None] * len(uniq)
-    flags = [i for i, x in enumerate(uniq) if np.dtype(x.dtype) == np.bool_]
-    for at in range(0, len(flags), 32):
-        group = flags[at:at + 32]
-        packed = uniq[group[0]].astype(xp.uint32)
-        for bit, i in enumerate(group[1:], start=1):
-            packed = packed | (uniq[i].astype(xp.uint32) << np.uint32(bit))
+    # lanes narrower than a word share one: bools a bit each (first, so
+    # that a call with no narrow integer packs as it always did), then
+    # 8- and 16-bit integers (a fixed-width string's one or two bytes)
+    bits_of = [_narrow_bits(x) for x in uniq]
+    small = [(i, 1) for i, b in enumerate(bits_of) if b == 1]
+    small += [(i, b) for i, b in enumerate(bits_of) if b > 1]
+    groups: List[list] = []
+    room = 0
+    for i, bits in small:
+        if bits > room:
+            groups.append([])
+            room = 32
+        groups[-1].append((i, 32 - room, bits))
+        room -= bits
+    for group in groups:
+        packed = None
+        for i, shift, bits in group:
+            x = uniq[i]
+            if bits > 1:    # two's complement bits, without the sign's run
+                x = x.astype(np.dtype(f"u{bits // 8}"))
+            part = x.astype(xp.uint32)
+            if shift:
+                part = part << np.uint32(shift)
+            packed = part if packed is None else packed | part
         packed = moved(_as_i32(packed))
-        for bit, i in enumerate(group):
-            out_u[i] = ((packed >> np.int32(bit)) & np.int32(1)) != 0
+        for i, shift, bits in group:
+            piece = (packed >> np.int32(shift)) & np.int32((1 << bits) - 1)
+            if bits == 1:
+                out_u[i] = piece != 0
+            else:
+                dt = np.dtype(uniq[i].dtype)
+                out_u[i] = piece.astype(np.dtype(f"u{bits // 8}")) \
+                    .astype(dt)
     for i, x in enumerate(uniq):
         if out_u[i] is None:
             words, rebuild = _to_words(xp, x)
@@ -333,9 +378,10 @@ def stable_argsort(xp, key_words, cap: int):
 
 
 def carriable(col: DeviceColumn) -> bool:
-    """True when every lane of the column is row-aligned (no offsets
-    anywhere in the tree), so a row permutation is just a lane permute."""
-    if col.offsets is not None:
+    """True when every lane of the column is row-aligned (no stored
+    offsets anywhere in the tree: a fixed-width string stores none), so a
+    row permutation is just a lane permute."""
+    if col.has_offsets:
         return False
     return all(carriable(c) for c in col.children)
 
@@ -371,6 +417,8 @@ def sort_rows(xp, key_words: Sequence, cols: Sequence[DeviceColumn],
 
     flats = [jax.tree_util.tree_flatten(c) if carriable(c) else None
              for c in cols]
+    _COUNTS.strings_aligned += sum(
+        1 for c, f in zip(cols, flats) if f is not None and _is_string(c))
     lanes = [leaf for f in flats if f is not None for leaf in f[0]]
     lanes += list(extras)
     order, rank = _lean_perm(
@@ -409,6 +457,8 @@ def mask_validity(xp, col: DeviceColumn, mask) -> DeviceColumn:
     restores the 'padding rows are invalid' batch contract after a
     carry permutation moved rows past num_rows."""
     validity = mask if col.validity is None else (col.validity & mask)
+    if col.fixed_width is not None:
+        return col.with_word(col.word, validity)
     # children of span columns are child-cap aligned — only row-aligned
     # (struct) children can take the row mask
     children = col.children if col.offsets is not None else tuple(

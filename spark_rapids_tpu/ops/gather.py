@@ -51,6 +51,12 @@ def gather_column(xp, col: DeviceColumn, indices, valid,
     else:
         new_valid = valid
 
+    if col.fixed_width is not None:
+        # a fixed-width string is one row-aligned lane: no spans to repack
+        word = xp.where(new_valid, col.word[idx],
+                        xp.zeros((), dtype=col.word.dtype))
+        return col.with_word(word, new_valid)
+
     if isinstance(dtype, (t.StringType, t.BinaryType)):
         cap = out_char_cap or int(col.data.shape[0])
         new_offs, src_pos, in_range = gather_spans(

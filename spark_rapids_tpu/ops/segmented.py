@@ -117,14 +117,19 @@ def key_words_for_column(xp, col: DeviceColumn, live_mask,
     remaining words encode the value — uint32 for types that fit 32 bits
     (half the sort-comparator cost on TPU), uint64 otherwise.  Strings
     use content hashes when only grouping (equality) is needed, or
-    prefix words for true ordering."""
+    prefix words for true ordering; a fixed-width string
+    (`DeviceColumn.fixed_width`) is its own one exact word for both."""
     dtype = col.dtype
     validity = col.validity
     if validity is None:
         validity = xp.ones((col.capacity,), dtype=bool)
     null_word = validity if nulls_first else ~validity
     words = [null_word]
-    if isinstance(dtype, (t.StringType, t.BinaryType)):
+    if col.fixed_width is not None:
+        # the value itself, big-endian: word order is byte order and word
+        # equality is string equality, for grouping and for ordering alike
+        words.append(col.word)
+    elif isinstance(dtype, (t.StringType, t.BinaryType)):
         if for_grouping:
             h1, h2 = sops.string_hashes(xp, col.offsets, col.data)
             words += [h1, h2]
