@@ -10,10 +10,20 @@ table; each one's kernel is then lowered for a batch of shapes at `rows`
 capacity, the two `char(1)` keys fixed-width strings (one uint8 lane) or,
 with `general`, in the layout of offsets and bytes.  Prints one JSON object
 a program: the build counters (`ops/carry.lane_move_counts`), the compile
-seconds, the count of `sort(` and `gather(` in the compiled text, and the
-compiler's memory figures.  The aggregate's and the sort's inputs are at
-the capacity the operator below them hands up.  A compile is not a chip
-run: no time here is a device time.
+seconds, the count of `sort(`, `gather(`, `conditional(` and `while(` in the
+compiled text, and the compiler's memory figures.  The aggregate's and the
+sort's inputs are at the capacity the operator below them hands up.  A
+compile is not a chip run: no time here is a device time.
+
+The aggregate's `sort(` stays 45 with the dense arm in the program (PR 32):
+Q1's two `char(1)` keys send `_group_reduce` down the dense arm, which holds
+the sort arm behind a `conditional(` for a batch with more groups than it
+walks, so the text keeps that branch's passes and `sort_passes` at build
+counts them.  That the dense arm is there shows as `grouped_dense` 1, one
+`conditional(` and two `while(` (the walk of the codes, the walk of the
+groups); that it RAN shows only on the chip: no
+`jit_TpuHashAggregateExec.complete/sort.N` among a traced run's device
+operations.
 """
 
 from __future__ import annotations
@@ -41,7 +51,7 @@ from spark_rapids_tpu import types as t  # noqa: E402
 from spark_rapids_tpu.columnar.device import (  # noqa: E402
     DEFAULT_CHAR_BUCKETS, DeviceBatch, DeviceColumn, bucket_for)
 
-OPCODES = ("sort", "gather")
+OPCODES = ("sort", "gather", "conditional", "while")
 
 
 def abstract_batch(names, dtypes, cap: int, sharding, fixed: bool):

@@ -123,20 +123,63 @@ def _sum_two_level(xp, vals):
     return xp.sum(xp.sum(vals.reshape(-1, _SUM_MINOR), axis=1))
 
 
-def _reduce_ungrouped(xp, value_cols: List[DeviceColumn], ops: List[str],
-                      cap: int, live) -> List[DeviceColumn]:
-    """The masked arm of `_group_reduce`: one group, so each op reduces
-    its lane under `contrib` where the rows lie.  Null, inf, nan and
+def _reads_row(vc: DeviceColumn, op: str) -> bool:
+    """Whether `_masked_op` answers with a row of `vc` to read and not
+    with a value: first and last, and min/max of a struct, array or map
+    (the first row, as in the sort arm)."""
+    return _split_op(op)[0] in ("first", "last") or \
+        _needs_index_gather(vc.dtype)
+
+
+def _masked_op(xp, vc: DeviceColumn, op: str, mask, iota):
+    """One reducible op (`_ungrouped_reducible`) over the rows of ONE
+    group, `mask`, where they lie: (result, contributing rows), both
+    scalars.  The result is the value, or the row's index where
+    `_reads_row`; it means nothing where no row contributes (the caller
+    nulls it), but a `countvalid` always counts.  Null, inf, nan and
     empty-input semantics are the sort arm's to the letter; `first` and
     `last` read the arrival position, which is what that arm's stable
-    sort on `~live` preserved.  Returns one-row columns of capacity
+    sort preserves."""
+    def count(m):
+        return xp.sum(m.astype(np.int32), dtype=np.int32)
+
+    base_op, any_row = _split_op(op)
+    contrib = mask if any_row or vc.validity is None else \
+        vc.validity & mask
+    cnt = count(contrib)
+    if base_op == "countvalid":
+        return cnt.astype(np.int64), cnt
+    if _reads_row(vc, op):
+        if base_op == "last":
+            return xp.max(xp.where(contrib, iota, np.int32(-1))), cnt
+        return xp.min(xp.where(contrib, iota, np.int32(2**31 - 1))), cnt
+    data = vc.data
+    if base_op in ("min", "max"):
+        row = seg.masked_argext(xp, data, contrib,
+                                is_min=(base_op == "min"))
+        return data[row], cnt
+    vals0 = xp.where(contrib, data, xp.zeros((), dtype=data.dtype))
+    if np.dtype(data.dtype).kind != "f":
+        # exact modulo 2^width, as the scan's difference is
+        return xp.sum(vals0, dtype=data.dtype), cnt
+    # the finite values only; IEEE's inf/nan from their counts (the
+    # chip's float64 is a float32 pair: its add is not trusted to carry
+    # them)
+    finite = _sum_two_level(xp, xp.where(
+        xp.isfinite(vals0), vals0, xp.zeros((), dtype=data.dtype)))
+    return _ieee_sum(xp, finite, count(contrib & (data == xp.inf)),
+                     count(contrib & (data == -xp.inf)),
+                     count(contrib & xp.isnan(data))), cnt
+
+
+def _reduce_ungrouped(xp, value_cols: List[DeviceColumn], ops: List[str],
+                      cap: int, live) -> List[DeviceColumn]:
+    """The masked arm of `_group_reduce`: one group, so each op is
+    `_masked_op` under `live`.  Returns one-row columns of capacity
     `DEFAULT_ROW_BUCKETS[0]`."""
     out_cap = DEFAULT_ROW_BUCKETS[0]
     slot0 = xp.arange(out_cap, dtype=np.int32) == 0
     iota = xp.arange(cap, dtype=np.int32)
-
-    def count(mask):
-        return xp.sum(mask.astype(np.int32), dtype=np.int32)
 
     def one_row(dtype, value, valid) -> DeviceColumn:
         v = slot0 & valid
@@ -145,67 +188,42 @@ def _reduce_ungrouped(xp, value_cols: List[DeviceColumn], ops: List[str],
 
     out_values: List[DeviceColumn] = []
     for vc, op in zip(value_cols, ops):
-        base_op, any_row = _split_op(op)
-        contrib = live if any_row or vc.validity is None else \
-            vc.validity & live
-        cnt = count(contrib)
-        if base_op == "countvalid":
-            out_values.append(one_row(t.LONG, cnt.astype(np.int64),
-                                      slot0))
-            continue
-        if base_op in ("first", "last") or _needs_index_gather(vc.dtype):
-            # (min/max of a struct, array or map reads the first row, as
-            # in the sort arm)
-            if base_op == "last":
-                idx = xp.max(xp.where(contrib, iota, np.int32(-1)))
-            else:
-                idx = xp.min(xp.where(contrib, iota,
-                                      np.int32(2**31 - 1)))
+        value, cnt = _masked_op(xp, vc, op, live, iota)
+        if _split_op(op)[0] == "countvalid":
+            out_values.append(one_row(t.LONG, value, slot0))
+        elif _reads_row(vc, op):
             out_values.append(gather_column(
-                xp, vc, xp.where(slot0, idx, np.int32(0)),
+                xp, vc, xp.where(slot0, value, np.int32(0)),
                 slot0 & (cnt > 0)))
-            continue
-        data = vc.data
-        if base_op in ("min", "max"):
-            row = seg.masked_argext(xp, data, contrib,
-                                    is_min=(base_op == "min"))
-            out_values.append(one_row(vc.dtype, data[row], cnt > 0))
-            continue
-        vals0 = xp.where(contrib, data, xp.zeros((), dtype=data.dtype))
-        if np.dtype(data.dtype).kind != "f":
-            # exact modulo 2^width, as the scan's difference is
-            out = xp.sum(vals0, dtype=data.dtype)
         else:
-            # the finite values only; IEEE's inf/nan from their counts
-            # (the chip's float64 is a float32 pair: its add is not
-            # trusted to carry them)
-            finite = _sum_two_level(xp, xp.where(
-                xp.isfinite(vals0), vals0, xp.zeros((), dtype=data.dtype)))
-            out = _ieee_sum(xp, finite, count(contrib & (data == xp.inf)),
-                            count(contrib & (data == -xp.inf)),
-                            count(contrib & xp.isnan(data)))
-        out_values.append(one_row(vc.dtype, out, cnt > 0))
+            out_values.append(one_row(vc.dtype, value, cnt > 0))
     return out_values
 
 
-#: values a key column of these types can take, its null apart
-_KEY_VALUES = {t.BooleanType: 2, t.ByteType: 1 << 8, t.ShortType: 1 << 16}
+#: bits of a key column's value, for the types whose values are so few
+_KEY_BITS = {t.BooleanType: 1, t.ByteType: 8, t.ShortType: 16}
+
+
+def _key_bits(kc: DeviceColumn) -> Optional[int]:
+    """Bits that hold every value of a key column, where its type says:
+    8 a byte of a fixed-width string, 1 for a boolean, 8 for a byte, 16
+    for a short.  None where the key is unbounded (an int64, a double, a
+    general string)."""
+    if kc.fixed_width is not None:
+        return 8 * kc.fixed_width
+    return _KEY_BITS.get(type(kc.dtype))
 
 
 def _group_bound(key_cols: List[DeviceColumn]) -> Optional[int]:
-    """The most groups these key columns can form, where their types say:
-    a fixed-width string of w bytes has 256**w values, a boolean 2, a
-    byte 256, a short 65,536, each and one more for the null.  None where
-    a key is unbounded (an int64, a double, a general string)."""
+    """The most groups these key columns can form, where their types say
+    (`_key_bits`): every value of every column, and one more each for the
+    null.  None where a key is unbounded."""
     bound = 1
     for kc in key_cols:
-        if kc.fixed_width is not None:
-            values = 1 << (8 * kc.fixed_width)
-        else:
-            values = _KEY_VALUES.get(type(kc.dtype))
-        if values is None:
+        bits = _key_bits(kc)
+        if bits is None:
             return None
-        bound *= values + 1
+        bound *= (1 << bits) + 1
     return bound
 
 
@@ -219,38 +237,276 @@ def _group_capacity(key_cols: List[DeviceColumn], cap: int) -> int:
     return min(cap, bucket_for(bound, DEFAULT_ROW_BUCKETS))
 
 
+# most groups the dense arm of `_group_reduce` walks.  Both arms' costs
+# are linear in the slots and in the ops, so their ratio at one shape is
+# the ratio: at Q1's (33,554,432 slots, two char(1) keys, seven float
+# sums and four counts) the sort arm takes 4,013 ms whatever the groups,
+# and the dense arm 39.7 ms for 4 groups, 198.4 for 64 and 739.2 for 256
+# (2.9 ms a group, 0.2 of them to find it; `devtools/
+# chip_dense_groups.py`, my chip runs, PR 32): at the cap it still wins
+# by five, which leaves a factor of two for op mixes nobody has timed.
+_DENSE_GROUPS_MAX = 256
+
+# groups the dense arm reduces in ONE pass over the lanes: what does not
+# depend on the group (a lane's finite values, its inf and nan flags) is
+# worked out once a pass, and the lanes are read once.  At Q1's shape, 4
+# groups / 64 groups: 1 a pass (a loop over the groups) 64.9 / 842.5 ms,
+# 4: 35.6 / 298.8, 8: 39.7 / 198.4, 16: 48.8 / 150.1, 64 (every slot at
+# once) 133.1 / 143.9 (my chip runs, PR 32): 8 is within 4 ms of the
+# best at four groups and within two fifths of it at sixty-four.
+_DENSE_WALK = 8
+
+#: the code of no row: a key's code is at most 31 bits (`_dense_eligible`)
+_NO_CODE = np.uint32(0xFFFFFFFF)
+
+
+def _dense_eligible(key_cols: List[DeviceColumn],
+                    value_cols: List[DeviceColumn], ops: List[str]) -> bool:
+    """Whether a grouped call may take the dense arm of `_group_reduce`:
+    every key column's type bounds its values (`_key_bits`), the columns'
+    null bits and values pack into one code below `_NO_CODE`, and every
+    op reduces under a mask."""
+    bits = [_key_bits(kc) for kc in key_cols]
+    return None not in bits and sum(b + 1 for b in bits) < 32 and \
+        _ungrouped_reducible(value_cols, ops)
+
+
+def _key_code(xp, key_cols: List[DeviceColumn], live):
+    """The key as ONE uint32 lane: column after column, the first the
+    most significant, a null bit (0 for a null, so nulls come first) above
+    the value's `_key_bits`, which rise as the sort arm's word of that
+    column does (`seg.key_words_for_column`).  So rising code order is the
+    sort arm's group order, and a code holds every byte of its key.  A row
+    outside `live` reads `_NO_CODE`."""
+    code = None
+    for kc in key_cols:
+        bits = _key_bits(kc)
+        if kc.fixed_width is not None:
+            field = kc.word.astype(xp.uint32)
+        elif bits == 1:
+            field = kc.data.astype(xp.uint32)
+        else:   # a signed integer, from its least value up
+            field = (kc.data.astype(xp.int32)
+                     + np.int32(1 << (bits - 1))).astype(xp.uint32)
+        valid = np.uint32(1) if kc.validity is None else \
+            kc.validity.astype(xp.uint32)
+        field = field | (valid << np.uint32(bits))
+        code = field if code is None else \
+            (code << np.uint32(bits + 1)) | field
+    return xp.where(live, code, _NO_CODE)
+
+
+def _keys_of_codes(xp, key_cols: List[DeviceColumn], codes,
+                   slot_valid) -> List[DeviceColumn]:
+    """`_key_code` undone: the key columns whose row i is the key of
+    `codes[i]`, null outside `slot_valid`."""
+    out = []
+    for kc in reversed(key_cols):
+        bits = _key_bits(kc)
+        field = codes & np.uint32((1 << bits) - 1)
+        valid = slot_valid & ((codes >> np.uint32(bits)) & np.uint32(1) != 0)
+        codes = codes >> np.uint32(bits + 1)
+        if kc.fixed_width is not None:
+            word = field.astype(kc.word.dtype)
+            out.append(kc.with_word(
+                xp.where(slot_valid, word, xp.zeros((), word.dtype)), valid))
+            continue
+        if bits == 1:
+            data = field != 0
+        else:
+            data = (field.astype(xp.int32)
+                    - np.int32(1 << (bits - 1))).astype(kc.data.dtype)
+        out.append(DeviceColumn(kc.dtype, validity=valid, data=xp.where(
+            slot_valid, data, xp.zeros((), data.dtype))))
+    return out[::-1]
+
+
+def _loop(xp, cond, body, state):
+    """`state` after `body` as long as `cond`: a loop of the device's own
+    under a trace, whose trip count the program finds; Python's on the
+    host."""
+    if xp is np:
+        while cond(state):
+            state = body(state)
+        return state
+    return jax.lax.while_loop(cond, body, state)
+
+
+def _put(xp, arr, i, value):
+    """`arr` with `value` at `i`."""
+    if xp is np:
+        arr = arr.copy()
+        arr[i] = value
+        return arr
+    return arr.at[i].set(value)
+
+
+def _distinct_codes(xp, code, slots: int):
+    """(codes, n): the first `slots + 1` distinct values of the `code`
+    lane below `_NO_CODE`, rising, and how many were found.  No sort: the
+    least code, then the least above it, each a masked `min` over the one
+    uint32 lane, until a step finds nothing."""
+    def more(state):
+        n, cur, _ = state
+        return (cur != _NO_CODE) & (n <= slots)
+
+    def step(state):
+        n, cur, codes = state
+        above = xp.min(xp.where(code > cur, code, _NO_CODE))
+        return n + np.int32(1), above, _put(xp, codes, n, cur)
+    n, _, codes = _loop(xp, more, step, (
+        np.int32(0), xp.min(code), xp.full((slots + 1,), _NO_CODE)))
+    return codes, n
+
+
+def _each_code(xp, fn, some_codes):
+    """`fn` of each of a few codes, the results stacked along a new first
+    axis: under a trace ONE pass over the lanes answers for all of them."""
+    if xp is np:
+        return jax.tree_util.tree_map(
+            lambda *rows: np.stack(rows), *[fn(g) for g in some_codes])
+    return jax.vmap(fn)(some_codes)
+
+
+def _reduce_dense(xp, key_cols: List[DeviceColumn],
+                  value_cols: List[DeviceColumn], ops: List[str], cap: int,
+                  code, codes, num_groups, out_cap: int):
+    """The dense arm of `_group_reduce`: group i is the rows whose `code`
+    is `codes[i]`, and each op is `_masked_op` under that mask,
+    `_DENSE_WALK` groups a pass over the lanes, for as many passes as hold
+    the `num_groups` that are there.  The key columns are read back from
+    the codes.  Returns (out_key_cols, out_value_cols) of capacity
+    `out_cap`, as the sort arm does."""
+    iota = xp.arange(cap, dtype=np.int32)
+    passes = -(-int(codes.shape[0]) // _DENSE_WALK)
+
+    def slot_lane(a, n, fill=0):
+        """The first `n` of a lane of slots, or the lane filled up to `n`."""
+        return a[:n] if n <= a.shape[0] else xp.concatenate(
+            [a, xp.full((n - a.shape[0],), fill, a.dtype)])
+    walk = slot_lane(codes, passes * _DENSE_WALK, _NO_CODE) \
+        .reshape(passes, _DENSE_WALK)
+
+    def result_dtype(vc, op):
+        if _split_op(op)[0] == "countvalid":
+            return np.int64
+        return np.int32 if _reads_row(vc, op) else vc.data.dtype
+
+    def one_pass(state):
+        at, acc = state
+        # (the code of no row is the padding rows': no group)
+        reduced = _each_code(xp, lambda g: [
+            _masked_op(xp, vc, op, (code == g) & (g != _NO_CODE), iota)
+            for vc, op in zip(value_cols, ops)], walk[at])
+        return at + np.int32(1), [
+            (_put(xp, results, at, r), _put(xp, counts, at, c))
+            for (results, counts), (r, c) in zip(acc, reduced)]
+    _, acc = _loop(
+        xp, lambda state: state[0] * np.int32(_DENSE_WALK) < num_groups,
+        one_pass, (np.int32(0), [
+            (xp.zeros(walk.shape, result_dtype(vc, op)),
+             xp.zeros(walk.shape, np.int32))
+            for vc, op in zip(value_cols, ops)]))
+
+    slot_valid = xp.arange(out_cap, dtype=np.int32) < num_groups
+    out_values = []
+    for (results, counts), vc, op in zip(acc, value_cols, ops):
+        results = slot_lane(results.reshape(-1), out_cap)
+        valid = slot_valid & (slot_lane(counts.reshape(-1), out_cap) > 0)
+        if _split_op(op)[0] == "countvalid":
+            out_values.append(DeviceColumn(t.LONG, data=results,
+                                           validity=slot_valid))
+        elif _reads_row(vc, op):
+            out_values.append(gather_column(xp, vc, results, valid))
+        else:
+            out_values.append(DeviceColumn(
+                vc.dtype, validity=valid, data=xp.where(
+                    valid, results, xp.zeros((), results.dtype))))
+    return _keys_of_codes(xp, key_cols, slot_lane(codes, out_cap),
+                          slot_valid), out_values
+
+
 def _group_reduce(xp, key_cols: List[DeviceColumn],
                   value_cols: List[DeviceColumn], ops: List[str],
                   cap: int, live, global_agg: bool):
     """Core aggregate kernel.  Returns (out_key_cols, out_value_cols,
     num_groups).
 
-    Two arms, chosen from what the call can see (`global_agg`, `ops`, the
-    value columns' dtypes), never from a size or a key:
+    Three arms, chosen from what the call can see (`global_agg`, `ops`,
+    the key and value columns' types, and a count the program itself
+    takes), never from a size, a name or a setting:
 
       - **masked reduction** (`_reduce_ungrouped`): an ungrouped
         aggregate whose every op is reducible (`_ungrouped_reducible`:
         sum of a flat integer or float lane, countvalid, min/max of a
         flat numeric lane, first/last and their `_any` forms of any
         column).  One group needs no order: each op is a reduction of
-        its lane under the mask, and the answer is a ONE-row batch in the
-        smallest row bucket.  No sort, no scan, no compaction.  Q6's sum
-        takes it (update, and the merge of partials alike).
-      - **sort + segment** (below): every grouped call, and an ungrouped
-        one that holds a collect_*, an ordered min/max of strings,
+        its lane under the mask (`_masked_op`), and the answer is a
+        ONE-row batch in the smallest row bucket.  No sort, no scan, no
+        compaction.  Q6's sum takes it (update, and the merge of
+        partials alike).
+      - **dense** (`_reduce_dense`): a grouped aggregate with the same
+        ops whose key columns' types bound their values so far that the
+        whole key is one 32-bit code (`_dense_eligible`: two `char(1)`,
+        a boolean and a byte, a short).  The groups that are THERE are
+        found without a sort (`_distinct_codes`: the least code, the
+        least above it, ...), in the sort arm's group order, and each is
+        one more masked reduction; its key is read back from its code.
+        Q1's four groups take it.  A type bound is not a count: a short
+        may hold 60,000 groups, so when more than `_DENSE_GROUPS_MAX`
+        codes turn up the same program takes the sort arm instead
+        (`lax.cond` on the count found: no host read, no second run; a
+        Python `if` on the CPU engine).
+      - **sort + segment** (`_sort_segment`): every other grouped call
+        (an int64, a double or a general string among the keys), and any
+        call that holds a collect_*, an ordered min/max of strings,
         binaries or decimal128, or a decimal128 sum: those compact or
-        order values, and one such op sends the whole call here.  Its
-        output keeps the input's capacity, unless the key columns' types
-        bound the group count below it (`_group_capacity`: two one-byte
-        string keys form at most 257 x 257 groups): the groups are
-        compacted to the front, so the boundary compaction's lanes and
-        every output are cut to that bound's row bucket, exactly, with no
-        host read and no speculation.  What reads a four-group answer
-        (a sort, a HAVING filter, the fetch) then runs at 262,144 slots
-        and not at the table's 33,554,432.
+        order values, and one such op sends the whole call there.
 
-    Sort + segment structure (see ops/carry.py docstring for the chip
-    measurements behind it):
+    A grouped call's output keeps the input's capacity, unless the key
+    columns' types bound the group count below it (`_group_capacity`: two
+    one-byte string keys form at most 257 x 257 groups): then every
+    output is cut to that bound's row bucket, exactly, with no host read
+    and no speculation.  What reads a four-group answer (a sort, a HAVING
+    filter, the fetch) then runs at 262,144 slots and not at the table's
+    33,554,432.
+    """
+    from ..ops import carry
+    if global_agg:
+        reduced = _ungrouped_reducible(value_cols, ops)
+        carry.count_ungrouped(reduced)
+        if reduced:
+            return [], _reduce_ungrouped(xp, value_cols, ops, cap, live), \
+                xp.int32(1)
+        return _sort_segment(xp, key_cols, value_cols, ops, cap, live, True)
+    dense = _dense_eligible(key_cols, value_cols, ops)
+    carry.count_grouped(dense)
+    if not dense:
+        return _sort_segment(xp, key_cols, value_cols, ops, cap, live,
+                             False)
+    out_cap = _group_capacity(key_cols, cap)
+    slots = min(_DENSE_GROUPS_MAX, out_cap)
+    code = _key_code(xp, key_cols, live)
+    codes, found = _distinct_codes(xp, code, slots)
+
+    def few():
+        # (the count in the type the sort arm's sum gives it)
+        return *_reduce_dense(xp, key_cols, value_cols, ops, cap, code,
+                              codes, found, out_cap), found.astype(jnp.int_)
+
+    def many():
+        return _sort_segment(xp, key_cols, value_cols, ops, cap, live,
+                             False)
+    if xp is np:
+        return few() if found <= slots else many()
+    return jax.lax.cond(found <= slots, few, many)
+
+
+def _sort_segment(xp, key_cols: List[DeviceColumn],
+                  value_cols: List[DeviceColumn], ops: List[str],
+                  cap: int, live, global_agg: bool):
+    """The sort arm of `_group_reduce` (see ops/carry.py docstring for
+    the chip measurements behind it):
 
       1. ONE stable sort by the key words.  Every flat lane of the key
          and value columns is moved by `carry.sort_rows`: a sort pass per
@@ -263,17 +519,12 @@ def _group_reduce(xp, key_cols: List[DeviceColumn],
          rebuild IEEE inf/nan from per-segment special-value counts.
       3. ONE compaction (`carry.compact_rows`) moves the boundary rows
          (and all per-op scan lanes + flat key lanes) to the slot
-         positions.
+         positions; the groups lie at the front, so its lanes and every
+         output are cut to `_group_capacity`.
       4. min/max/first/last use int32 scatter tournaments + one row
          gather; variable-width columns keep the gather-based paths.
     """
     from ..ops import carry
-    if global_agg:
-        reduced = _ungrouped_reducible(value_cols, ops)
-        carry.count_ungrouped(reduced)
-        if reduced:
-            return [], _reduce_ungrouped(xp, value_cols, ops, cap, live), \
-                xp.int32(1)
     # --- sort keys, carrying all row data -----------------------------------
     words: List = [~live]  # padding rows sort last
     for kc in key_cols:

@@ -57,6 +57,8 @@ class _MoveCounts(threading.local):
     passes = 0        # every `_sort_pass`, the orders' own included
     ungrouped_reduced = 0   # ungrouped aggregates that reduce under a mask
     ungrouped_sorted = 0    # ungrouped aggregates that sort and compact
+    grouped_dense = 0       # grouped aggregates that hold the dense arm
+    grouped_sorted = 0      # grouped aggregates that are the sort arm alone
     strings_aligned = 0     # string columns moved as row-aligned lanes
     strings_gathered = 0    # string columns moved by offsets and gather
 
@@ -66,7 +68,8 @@ _COUNTS = _MoveCounts()
 
 def lane_move_counts() -> dict:
     """Lanes moved by sort pass, lanes moved by gather, sort passes, the
-    ungrouped aggregates that moved no row at all (or did), and the string
+    ungrouped aggregates that moved no row at all (or did), the grouped
+    ones that hold the dense arm (or the sort arm alone), and the string
     columns a `sort_rows` moved as row-aligned lanes (fixed-width) or by
     offsets and gather, traced on this thread so far, under the names a
     program's build record gives them.  Tracing a program raises them, so
@@ -76,6 +79,8 @@ def lane_move_counts() -> dict:
             "sort_passes": _COUNTS.passes,
             "ungrouped_reduced": _COUNTS.ungrouped_reduced,
             "ungrouped_sorted": _COUNTS.ungrouped_sorted,
+            "grouped_dense": _COUNTS.grouped_dense,
+            "grouped_sorted": _COUNTS.grouped_sorted,
             "string_cols_row_aligned": _COUNTS.strings_aligned,
             "string_cols_gathered": _COUNTS.strings_gathered}
 
@@ -87,6 +92,17 @@ def count_ungrouped(reduced: bool) -> None:
         _COUNTS.ungrouped_reduced += 1
     else:
         _COUNTS.ungrouped_sorted += 1
+
+
+def count_grouped(dense: bool) -> None:
+    """One grouped `exec/aggregate._group_reduce` call, by what it holds:
+    the dense arm (masked reductions of the groups found, with the sort
+    arm behind a conditional for more groups than it walks), or the sort
+    arm alone."""
+    if dense:
+        _COUNTS.grouped_dense += 1
+    else:
+        _COUNTS.grouped_sorted += 1
 
 
 def _is_string(col: DeviceColumn) -> bool:

@@ -392,6 +392,50 @@ def test_an_ungrouped_collect_list_still_takes_the_sort_arm():
     assert out.column("l")[0].as_py() == list(data["p"])
 
 
+def _grouped_programs(key_values):
+    """The records of the aggregate programs that one grouped query built
+    over a key column of `key_values`."""
+    import pyarrow as pa
+    from spark_rapids_tpu.api import functions as F
+    from spark_rapids_tpu.api.column import col
+    from spark_rapids_tpu.api.session import TpuSession
+    from spark_rapids_tpu.obs.compileprof import CompileObservatory
+    rng = np.random.default_rng(4)
+    n = 700
+    data = {"k": rng.choice(key_values, n), "p": rng.uniform(1.0, 9.0, n)}
+    s = TpuSession.builder().config("spark.rapids.sql.enabled", True) \
+        .get_or_create()
+    seen = {(p["key"], p["shape"])
+            for p in CompileObservatory.get().snapshot()["programs"]}
+    out = s.create_dataframe(pa.table(data), num_partitions=1) \
+        .group_by(col("k")).agg(F.sum(col("p")).alias("s"),
+                                F.count("*").alias("n")).collect()
+    assert out.num_rows == len(key_values)
+    return [p for p in CompileObservatory.get().snapshot()["programs"]
+            if p["exec"] == "TpuHashAggregateExec" and
+            (p["key"], p["shape"]) not in seen]
+
+
+def test_a_bounded_key_builds_a_program_that_holds_the_dense_arm():
+    """Q1's shape: a one-byte string key.  The sort arm is in the program
+    too, behind the conditional, and its passes are counted."""
+    built = _grouped_programs(np.array(["A", "N", "R"]))
+    assert len(built) == 1
+    assert built[0]["grouped_dense"] == 1
+    assert built[0]["grouped_sorted"] == 0
+    assert built[0]["sort_passes"] > 0
+    assert built[0]["lane_moves_gathered"] == 0
+    assert built[0]["ungrouped_reduced"] == built[0]["ungrouped_sorted"] == 0
+
+
+def test_an_int64_key_builds_the_sort_arm_alone():
+    built = _grouped_programs(np.array([3, 1 << 40, -7]))
+    assert len(built) == 1
+    assert built[0]["grouped_dense"] == 0
+    assert built[0]["grouped_sorted"] == 1
+    assert built[0]["sort_passes"] > 0
+
+
 def test_a_string_column_counts_as_gathered():
     programs, _ = _filter_programs(with_string=True)
     assert programs

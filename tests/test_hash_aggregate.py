@@ -509,3 +509,295 @@ def test_ungrouped_multi_batch_partial_then_final(parts):
                       F.avg(col("f")).alias("a"), F.count("*").alias("c"),
                       F.min(col("f")).alias("mn"), F.max(col("i")).alias("mx"))
     assert_tpu_and_cpu_are_equal_collect(q, approximate_float=1e-12)
+
+
+# -- a few groups of a bounded key: the dense arm, no sort --------------------
+# (exec/aggregate._reduce_dense; every case counts on its own)
+
+_DENSE_N, _DENSE_CAP = 700, 1024
+_DENSE_OPS = ["sum", "sum", "countvalid", "min", "max", "min", "max",
+              "first", "last", "first_any", "last_any"]
+_DENSE_LANES = ["i", "f", "f", "i", "i", "f", "f", "f", "i", "f", "i"]
+_DENSE_KEYS = ["two_char1", "bool_x_byte", "short", "char2_with_nulls"]
+_DENSE_INPUTS = ["plain", "nulls", "inf_nan", "one_group", "empty",
+                 "all_null_group", "padding_in_the_middle"]
+
+
+def _dense_keys(kind, rng, one_group, null_keys):
+    """Key columns of `kind` over the capacity, a few distinct values."""
+    import numpy as np
+    from spark_rapids_tpu import types as t
+    from spark_rapids_tpu.columnar.device import DeviceColumn
+
+    def pick(values, dtype):
+        values = np.asarray(values, dtype)
+        return values[:1].repeat(_DENSE_CAP) if one_group else \
+            rng.choice(values, _DENSE_CAP)
+
+    def validity():
+        if not null_keys:
+            return np.ones(_DENSE_CAP, bool)
+        return rng.random(_DENSE_CAP) > 0.15
+
+    def string(words, dtype, width):
+        valid = validity()
+        return DeviceColumn.fixed_string(
+            t.STRING, np.where(valid, pick(words, dtype), 0).astype(dtype),
+            valid, width)
+
+    def flat(dtype, values, np_dtype):
+        valid = validity()
+        return DeviceColumn(dtype, validity=valid, data=np.where(
+            valid, pick(values, np_dtype), 0).astype(np_dtype))
+    if kind == "two_char1":
+        return [string([65, 78, 82], np.uint8, 1),
+                string([70, 79], np.uint8, 1)]
+    if kind == "bool_x_byte":
+        return [flat(t.BOOLEAN, [True, False], bool),
+                flat(t.BYTE, [-128, -3, 0, 5, 127], np.int8)]
+    if kind == "short":
+        return [flat(t.SHORT, [-32768, -1, 0, 1, 300, 32767], np.int16)]
+    # a fixed-width string takes nulls only from an operator above the
+    # scan: always some here
+    valid = rng.random(_DENSE_CAP) > 0.2
+    words = pick([0x4142, 0x4143, 0x5A5A, 0x0001, 0xFFFF], np.uint16)
+    return [DeviceColumn.fixed_string(
+        t.STRING, np.where(valid, words, 0).astype(np.uint16), valid, 2)]
+
+
+def _dense_case(keys, data):
+    """(key columns, value columns by lane name, live) on the host."""
+    import numpy as np
+    from spark_rapids_tpu import types as t
+    from spark_rapids_tpu.columnar.device import DeviceColumn
+    rng = np.random.default_rng(
+        32 + 7 * _DENSE_KEYS.index(keys) + _DENSE_INPUTS.index(data))
+    key_cols = _dense_keys(keys, rng, one_group=(data == "one_group"),
+                           null_keys=(data == "nulls"))
+    i = rng.integers(-(10**15), 10**15, _DENSE_CAP)
+    f = rng.standard_normal(_DENSE_CAP) * \
+        10.0 ** rng.integers(-3, 9, _DENSE_CAP)
+    valid = np.ones(_DENSE_CAP, bool)
+    if data in ("nulls", "inf_nan"):
+        valid = rng.random(_DENSE_CAP) > 0.3
+    if data == "inf_nan":
+        f[rng.choice(_DENSE_CAP, 40, replace=False)] = \
+            rng.choice([np.inf, -np.inf, np.nan], 40)
+    if data == "all_null_group":
+        first = key_cols[0]
+        lane = first.word if first.fixed_width is not None else first.data
+        valid = lane != lane[0]
+    live = np.arange(_DENSE_CAP) < _DENSE_N
+    if data == "empty":
+        live = np.zeros(_DENSE_CAP, bool)
+    if data == "padding_in_the_middle":
+        live = rng.random(_DENSE_CAP) > 0.4
+    values = {"i": DeviceColumn(t.LONG, data=np.where(valid, i, 0),
+                                validity=valid),
+              "f": DeviceColumn(t.DOUBLE, data=np.where(valid, f, 0.0),
+                                validity=valid)}
+    return key_cols, values, live
+
+
+def _group_reduce_three_ways(key_cols, value_cols, ops, live, monkeypatch):
+    """`_group_reduce` as it chooses under jit, on the CPU engine, and
+    with the dense arm's cap at no group at all (so that any row sends
+    the conditional down its sort branch), under jit."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from spark_rapids_tpu.exec import aggregate as agg
+
+    def run(xp, keys, values, live):
+        return agg._group_reduce(xp, keys, values, ops, _DENSE_CAP, live,
+                                 False)
+    dev = jax.tree_util.tree_map(jnp.asarray, (key_cols, value_cols, live))
+    on_host = run(np, key_cols, value_cols, live)
+    chosen = jax.jit(lambda a: run(jnp, *a))(dev)
+    monkeypatch.setattr(agg, "_DENSE_GROUPS_MAX", 0)
+    sort_arm = jax.jit(lambda a: run(jnp, *a))(dev)
+    return on_host, chosen, sort_arm
+
+
+def _assert_same_groups(ops, value_cols, live, results):
+    """Group order, keys, validity, counts and integer results byte-equal
+    over the groups; float sums within 1e-13 of the terms' absolute sum."""
+    import numpy as np
+    n = int(results[-1][2])
+    assert [int(r[2]) for r in results] == [n] * len(results)
+    want_keys, want_values, _ = results[-1]
+    for keys, values, _ in results[:-1]:
+        for a, b in zip(keys, want_keys):
+            assert a.capacity == b.capacity and a.dtype == b.dtype
+            lane = "word" if a.fixed_width is not None else "data"
+            assert np.asarray(getattr(a, lane))[:n].tobytes() == \
+                np.asarray(getattr(b, lane))[:n].tobytes()
+            assert np.asarray(a.validity).tobytes() == \
+                np.asarray(b.validity).tobytes()
+        for op, vc, a, b in zip(ops, value_cols, values, want_values):
+            assert a.capacity == b.capacity and a.dtype == b.dtype
+            va, vb = np.asarray(a.validity), np.asarray(b.validity)
+            assert va.tobytes() == vb.tobytes(), op
+            xa = np.asarray(a.data)[:n][va[:n]]
+            xb = np.asarray(b.data)[:n][va[:n]]
+            if op == "sum" and xa.dtype.kind == "f":
+                terms = np.asarray(vc.data)[live & np.asarray(vc.validity)]
+                scale = float(np.abs(terms[np.isfinite(terms)]).sum())
+                finite = np.isfinite(xb)
+                assert xa[~finite].tobytes() == xb[~finite].tobytes(), op
+                assert np.all(np.abs(xa[finite] - xb[finite])
+                              <= 1e-13 * scale), op
+            else:
+                assert xa.tobytes() == xb.tobytes(), (op, xa, xb)
+
+
+@pytest.mark.parametrize("data", _DENSE_INPUTS)
+@pytest.mark.parametrize("keys", _DENSE_KEYS)
+def test_dense_arm_answers_as_the_sort_arm(keys, data, monkeypatch):
+    """A bounded key with a few groups: the dense arm (the groups found
+    by masked mins, each reduced under its mask, its key read from its
+    code) against the sort arm and the CPU engine."""
+    import numpy as np
+    from spark_rapids_tpu.ops import carry
+    key_cols, lanes, live = _dense_case(keys, data)
+    value_cols = [lanes[name] for name in _DENSE_LANES]
+    before = carry.lane_move_counts()
+    results = _group_reduce_three_ways(key_cols, value_cols, _DENSE_OPS,
+                                       live, monkeypatch)
+    after = carry.lane_move_counts()
+    assert after["grouped_dense"] - before["grouped_dense"] == 3
+    assert after["grouped_sorted"] == before["grouped_sorted"]
+    _assert_same_groups(_DENSE_OPS, value_cols, live, results)
+    n = int(results[0][2])
+    assert (n == 0) == (data == "empty")
+    # (the fixed-width string with nulls always has its null group)
+    assert data != "one_group" or n == 1 + (keys == "char2_with_nulls")
+    if data == "all_null_group":
+        # the group is there, its count 0 and its sum null
+        total, count = results[1][1][1], results[1][1][2]
+        assert not np.asarray(total.validity)[:n].all()
+        assert 0 in np.asarray(count.data)[:n].tolist()
+        assert np.asarray(count.validity)[:n].all()
+
+
+def test_more_groups_than_the_dense_arm_walks_take_the_sort_branch(
+        monkeypatch):
+    """A short bounds its groups at 65,537; one more distinct key than
+    `_DENSE_GROUPS_MAX` and the same program answers from the sort arm
+    (the CPU engine, whose count is concrete, calls it)."""
+    import numpy as np
+    from spark_rapids_tpu import types as t
+    from spark_rapids_tpu.columnar.device import DeviceColumn
+    from spark_rapids_tpu.exec import aggregate as agg
+    rng = np.random.default_rng(64)
+    distinct = agg._DENSE_GROUPS_MAX + 1
+    data = (rng.permutation(_DENSE_CAP) % distinct * 200 - 31000) \
+        .astype(np.int16)
+    live = np.ones(_DENSE_CAP, bool)
+    key = DeviceColumn(t.SHORT, data=data, validity=live)
+    _, lanes, _ = _dense_case("short", "nulls")
+    value_cols = [lanes[name] for name in _DENSE_LANES]
+    calls = []
+    sort_segment = agg._sort_segment
+    monkeypatch.setattr(agg, "_sort_segment", lambda xp, *a: (
+        calls.append(xp), sort_segment(xp, *a))[1])
+    results = _group_reduce_three_ways([key], value_cols, _DENSE_OPS, live,
+                                       monkeypatch)
+    assert calls[0] is np and len(calls) == 3
+    assert int(results[1][2]) == distinct
+    _assert_same_groups(_DENSE_OPS, value_cols, live, results)
+    got = np.asarray(results[1][0][0].data)[:distinct]
+    assert got.tolist() == sorted(set(data[live].tolist()))
+
+
+def test_an_unbounded_key_lowers_without_a_conditional():
+    """An int64 key is the sort arm alone, as it was: the choice is made
+    from the key's type while the program is traced, and nothing of the
+    dense arm is in the text.  A short key holds both."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from spark_rapids_tpu import types as t
+    from spark_rapids_tpu.columnar.device import DeviceColumn
+    from spark_rapids_tpu.exec import aggregate as agg
+    _, lanes, live = _dense_case("short", "plain")
+
+    def text(key):
+        args = jax.tree_util.tree_map(jnp.asarray, (key, lanes["f"], live))
+        return jax.jit(lambda k, v, m: agg._group_reduce(
+            jnp, [k], [v, v], ["sum", "countvalid"], _DENSE_CAP, m,
+            False)).lower(*args).as_text()
+    ones = np.ones(_DENSE_CAP, bool)
+    unbounded = text(DeviceColumn(
+        t.LONG, data=np.arange(_DENSE_CAP) % 5, validity=ones))
+    bounded = text(DeviceColumn(
+        t.SHORT, data=(np.arange(_DENSE_CAP) % 5).astype(np.int16),
+        validity=ones))
+    # (a `stablehlo.case` is also how a float64 lane's move asks which
+    # platform it is lowered for: the sort arm has some of its own)
+    assert "stablehlo.while" not in unbounded
+    assert "stablehlo.sort" in unbounded and "stablehlo.sort" in bounded
+    assert bounded.count("stablehlo.while") == 2
+    assert bounded.count("stablehlo.case") > unbounded.count("stablehlo.case")
+
+
+def _bounded_key_table():
+    import numpy as np
+    import pyarrow as pa
+    rng = np.random.default_rng(132)
+    n = 900
+    return pa.table({
+        "r": pa.array(rng.choice(["A", "N", "R"], n)),
+        "s": pa.array(rng.choice(["F", "O"], n)),
+        "i": pa.array(rng.integers(-(10**12), 10**12, n),
+                      mask=rng.random(n) < 0.2),
+        "f": pa.array(rng.standard_normal(n) * 1e4,
+                      mask=rng.random(n) < 0.2)})
+
+
+def test_grouped_mix_with_collect_list_takes_the_sort_arm():
+    """One op that compacts values sends a bounded-key call down the sort
+    arm alone, which still answers."""
+    from spark_rapids_tpu.ops import carry
+    tbl = _bounded_key_table()
+
+    def q(spark):
+        df = spark.create_dataframe(tbl, num_partitions=1)
+        return df.group_by(col("r"), col("s")).agg(
+            F.sum(col("i")).alias("s_i"),
+            F.collect_list(col("i")).alias("l"),
+            F.max(col("f")).alias("m")).order_by(col("r"), col("s"))
+    before = carry.lane_move_counts()
+    _, tpu = assert_tpu_and_cpu_are_equal_collect(q, approximate_float=1e-12)
+    after = carry.lane_move_counts()
+    assert after["grouped_dense"] == before["grouped_dense"]
+    assert after["grouped_sorted"] > before["grouped_sorted"]
+    assert tpu.num_rows == 6
+
+
+@pytest.mark.parametrize("parts", [1, 3])
+def test_bounded_key_multi_batch_partial_then_final(parts):
+    """Several batches: each update leaves its few groups as a partial,
+    and the merge (sums of sums and counts, all reducible) takes the dense
+    arm too."""
+    import pyarrow as pa
+    from spark_rapids_tpu.ops import carry
+    tbl = _bounded_key_table()
+    tbl = pa.Table.from_batches(tbl.to_batches(max_chunksize=200))
+
+    def q(spark):
+        df = spark.create_dataframe(tbl, num_partitions=parts)
+        return df.group_by(col("r"), col("s")).agg(
+            F.sum(col("i")).alias("s_i"), F.sum(col("f")).alias("s_f"),
+            F.avg(col("f")).alias("a"), F.count("*").alias("c"),
+            F.count(col("i")).alias("c_i"), F.min(col("f")).alias("mn"),
+            F.max(col("i")).alias("mx")).order_by(col("r"), col("s"))
+    before = carry.lane_move_counts()
+    _, tpu = assert_tpu_and_cpu_are_equal_collect(q, approximate_float=1e-12)
+    after = carry.lane_move_counts()
+    assert after["grouped_dense"] > before["grouped_dense"]
+    if parts == 1:
+        # (across partitions the keys come back from the host shuffle in
+        # the general string layout, which bounds nothing)
+        assert after["grouped_sorted"] == before["grouped_sorted"]
+    assert tpu.num_rows == 6
