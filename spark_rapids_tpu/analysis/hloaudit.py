@@ -11,9 +11,9 @@ interp's row/byte states for the plan-side twin:
   keeps compile counts finite also pads every launch; when the interp
   says a subtree's live rows are a sliver of the bucket it lands in,
   most of the memory traffic is padding.  Repairable: the pre-flight
-  re-buckets the nearest filter through the existing speculative-sizing
-  machinery (the guarded shrink re-executes on a missed guess, exactly
-  like join speculation).
+  re-buckets the nearest filter through the speculative-sizing
+  machinery (ExecContext's deferred guards: the guarded shrink
+  re-executes on a missed guess).
 * **host round-trips inside programs** (TPU-L019) — a host callback or
   send/recv lowered INTO a compiled program serializes every launch on
   the host; found by parsing the persisted StableHLO, not by guessing
@@ -57,8 +57,8 @@ L018 = register_rule(
     "traffic (and HBM residency) is padding.  Repairable: the "
     "pre-flight re-buckets the nearest filter speculatively — output "
     "shrinks to a right-sized bucket under a deferred guard, and a "
-    "missed guess re-executes without speculation (the join "
-    "speculative-sizing machinery).  The runtime twin is the "
+    "missed guess re-executes without speculation.  The runtime twin "
+    "is the "
     "tpu_pad_waste_bytes_total{exec} counter booked by obs/tracer.py.")
 
 L019 = register_rule(

@@ -11,11 +11,12 @@ the reader:
   * **strategy_switch** — the measured exchange output is off the
     predicted size by at least ``feedback.replan.misestimateFactor``
     (either direction): pin ``no_speculation`` on the query's execution
-    context so the reduce-side join runs exact two-phase sizing instead
-    of gambling on a capacity guess it would lose.  This supersedes the
-    after-the-fact ``SpeculativeSizingMiss`` retry on this path — the
-    misestimate is caught from the map statistics, not from a failed
-    guard after the join already ran.
+    context so the operators still to run size their outputs exactly (a
+    filter's armed re-bucket is skipped) instead of gambling on a
+    capacity guess they would lose.  This supersedes the after-the-fact
+    ``SpeculativeSizingMiss`` retry on this path — the misestimate is
+    caught from the map statistics, not from a failed guard after the
+    reduce side already ran.
   * **oc_repair** — re-run the abstract interpreter over the plan with
     the exchange's row estimate overridden by the measured one; if the
     re-derived peak-HBM bound overshoots the admission budget, force
@@ -176,8 +177,8 @@ def _replan(ctx: ReplanContext, read_node, shuffle_id: int,
 
     if tripped and ctx.exec_ctx is not None and \
             not ctx.exec_ctx.task_context.get("no_speculation"):
-        # exact two-phase sizing for every operator still to run — the
-        # reduce-side join shares this context
+        # exact sizing for every operator still to run — the reduce
+        # side shares this context
         ctx.exec_ctx.task_context["no_speculation"] = True
         sink("strategy_switch")
 

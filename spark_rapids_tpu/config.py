@@ -292,17 +292,6 @@ SINGLE_CHIP_FUSE = conf("spark.rapids.tpu.singleChipFuse").string() \
     .check_values(["auto", "on", "off"]) \
     .create_with_default("auto")
 
-JOIN_SPECULATIVE_SIZING = conf(
-    "spark.rapids.tpu.join.speculativeSizing").boolean() \
-    .doc("Fuse a hash join's count and expand phases into ONE program by "
-         "guessing the output capacity (the probe side's capacity — exact "
-         "whenever no probe row matches more than one build row).  The "
-         "guess is validated by a deferred device guard that rides the "
-         "result fetch, so the common case pays ZERO sizing round trips; "
-         "a miss re-executes the query with exact sizing.  Flat (non-"
-         "string) schemas and inner/left joins only.") \
-    .create_with_default(True)
-
 HOST_ASSISTED_COLLECT = conf(
     "spark.rapids.sql.collect.hostAssisted").boolean() \
     .doc("When a collect's plan is a global sort (over optional filters/"

@@ -757,7 +757,7 @@ class DeviceToHostExec(Exec):
                     out, gvals = fetch_batch(b, extra_scalars=guards)
                     if not all(int(v) for v in gvals):
                         raise SpeculativeSizingMiss(
-                            "join capacity guess undershot")
+                            "capacity guess undershot")
                 else:
                     out = fetch_batch(b)
                 self.metrics[NUM_OUTPUT_ROWS] += int(out.num_rows)

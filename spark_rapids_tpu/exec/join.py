@@ -8,10 +8,16 @@ joins exactly like the reference (RapidsConf replaceSortMergeJoin).
 
 TPU realization (ops/join_kernels.py): build side concatenates and its
 combined 64-bit key hash sorts once; each probe batch runs a jitted
-count phase (binary-search match ranges + exact output sizing incl.
-string bytes), one host sync picks the bucketed output capacity, and a
-jitted expand phase materializes gather maps for both sides — the
-static-shape answer to cuDF's dynamic gather maps.
+count phase (match ranges + exact output sizing incl. string bytes) and a
+jitted expand phase that materializes gather maps for both sides at a
+bucketed output capacity — the static-shape answer to cuDF's dynamic
+gather maps.
+
+A join's output capacity follows the data: for every probe batch the host
+waits for the count program's sizes (one blocking fetch, `join.size`),
+picks the output's buckets, and dispatches the expand program keyed by
+them.  Parameter sets that leave a join's output in the same buckets run
+the same two programs, so a warm join builds nothing.
 
 CpuJoinExec is an independent pyarrow Table.join implementation (CPU
 fallback engine + differential oracle).
@@ -35,9 +41,10 @@ from ..expr.core import (BoundReference, EvalContext, Expression,
                          bind_expression)
 from ..expr.predicates import And, EqualTo
 from ..ops import join_kernels as jk
+from ..ops.carry import count_join_gathers
 from ..ops.gather import gather_batch, gather_column
 from .base import (maybe_sync,  # noqa: F401
-                   NUM_OUTPUT_BATCHES, NUM_OUTPUT_ROWS, OP_TIME, TPU, Batch,
+                   NUM_OUTPUT_BATCHES, NUM_OUTPUT_ROWS, OP_TIME, Batch,
                    Exec, MetricTimer, process_jit, schema_sig, semantic_sig)
 from .concat import concat_batches
 from .filter_common import apply_filter, compact
@@ -45,6 +52,45 @@ from ..ops.scan import cumsum_fast
 
 JOIN_TYPES = ("inner", "left", "right", "full", "left_semi", "left_anti",
               "cross")
+
+
+def _sizing_fetch(sizes, probe: Batch, build: Batch) -> tuple:
+    """The join's host sync: wait for the count's sizes and pick the
+    output's buckets, (out_cap, probe span caps, build span caps)."""
+    from ..columnar.fetch import fetch_array
+    from ..obs import metrics as m
+    from ..obs.tracer import trace_span
+    with trace_span("join.size") as sp:
+        sizes = fetch_array(sizes)         # one round trip
+        ntotal = int(sizes[0])
+        if ntotal >= (1 << 31):
+            # expand_pairs builds pair offsets in int32; a wrap
+            # would silently corrupt gather indices
+            raise RuntimeError(
+                f"join expansion of {ntotal} rows exceeds the "
+                f"2^31-1 per-batch capacity; split the inputs")
+        out_cap = bucket_for(max(ntotal, 1), DEFAULT_ROW_BUCKETS)
+        sp.set(total=ntotal, out_capacity=out_cap)
+    m.counter("tpu_join_sizing_fetches_total",
+              "blocking fetches of a join's output sizes, one a probe "
+              "batch").inc()
+
+    def span_cap(x, c):
+        """Output child capacity for a span column: char bucket for
+        strings, row bucket for array/map child rows; 0 = not a span
+        column."""
+        if isinstance(c.dtype, (t.StringType, t.BinaryType)):
+            return bucket_for(max(int(x), 1), DEFAULT_CHAR_BUCKETS)
+        if isinstance(c.dtype, (t.ArrayType, t.MapType)):
+            return bucket_for(max(int(x), 1), DEFAULT_ROW_BUCKETS)
+        return 0
+
+    n_p = len(probe.columns)
+    return (out_cap,
+            tuple(span_cap(x, c)
+                  for x, c in zip(sizes[1:1 + n_p], probe.columns)),
+            tuple(span_cap(x, c)
+                  for x, c in zip(sizes[1 + n_p:], build.columns)))
 
 
 def split_equi_condition(cond: Optional[Expression], left_names, right_names
@@ -87,6 +133,18 @@ def split_equi_condition(cond: Optional[Expression], left_names, right_names
     for c in residual:
         res = c if res is None else And(res, c)
     return lkeys, rkeys, res
+
+
+def _gather_pairs(xp, probe: Batch, pidx, pvalid, pchar_caps,
+                  build: Batch, bidx, bvalid, bchar_caps):
+    """Both sides' columns through the (probe, build) pair maps, row by
+    row; a traced program counts them (`join_cols_gathered`)."""
+    if xp is not np:
+        count_join_gathers(len(probe.columns) + len(build.columns))
+    return ([gather_column(xp, c, pidx, pvalid, cc)
+             for c, cc in zip(probe.columns, pchar_caps)],
+            [gather_column(xp, c, bidx, bvalid, cc)
+             for c, cc in zip(build.columns, bchar_caps)])
 
 
 class HashJoinExec(Exec):
@@ -174,16 +232,25 @@ class HashJoinExec(Exec):
         return f"HashJoin {self.how} on [{ks}]"
 
     # --- phase 1: count + sizing -------------------------------------------
-    def _count(self, xp, build: Batch, probe: Batch):
+    def _count(self, xp, build: Batch, probe: Batch,
+               need_matched: bool = True):
+        """(order, lo, counts, sizes, matched): each probe row's run of
+        the hash-sorted build order, the output's sizes as ONE int64
+        vector (rows, then the span bytes or child rows of every probe and
+        build column), and, where a right or full join will emit the
+        unmatched build rows (`need_matched`), the build rows some probe
+        row matched, else None."""
         bctx = EvalContext(xp, build)
         pctx = EvalContext(xp, probe)
         bkeys = [k.eval(bctx).col for k in self.right_keys]
         pkeys = [k.eval(pctx).col for k in self.left_keys]
-        blive = bctx.row_mask()
         plive = pctx.row_mask()
-        bh = jk.combined_key_hash(xp, bkeys, build.capacity, side="build")
-        ph = jk.combined_key_hash(xp, pkeys, probe.capacity, side="probe")
-        order, lo, counts = jk.count_matches(xp, bh, blive, ph, plive)
+        bh, bnull = jk.combined_key_hash(xp, bkeys, build.capacity)
+        ph, pnull = jk.combined_key_hash(xp, pkeys, probe.capacity)
+        # a null key matches nothing; a probe row that has one is still a
+        # row of a left join's output
+        order, lo, counts = jk.count_matches(
+            xp, bh, bctx.row_mask() & ~bnull, ph, plive & ~pnull)
         outer = self.how in ("left", "full")
         eff = xp.maximum(counts, 1) if outer else counts
         eff = xp.where(plive, eff, 0)
@@ -211,8 +278,9 @@ class HashJoinExec(Exec):
                 bbytes.append(xp.sum(xp.where(plive, per, 0)))
             else:
                 bbytes.append(xp.int64(0) if xp is not np else np.int64(0))
-        matched = jk.build_matched_flags(xp, order, lo, counts, plive,
-                                         build.capacity)
+        matched = jk.build_matched_flags(
+            xp, order, lo, counts, plive, build.capacity) \
+            if need_matched else None
         # all host-needed sizes ride ONE array so the caller pays a single
         # device round trip, not one per column
         sizes = xp.stack([xp.asarray(total, dtype=xp.int64)]
@@ -229,9 +297,15 @@ class HashJoinExec(Exec):
                 semantic_sig(self._bound_condition))
 
     @property
+    def _emits_unmatched_build(self) -> bool:
+        return self.how in ("right", "full")
+
+    @property
     def _jit_count(self):
-        return process_jit(self._jit_key + ("count",),
-                           lambda: lambda b, p: self._count(jnp, b, p))
+        need = self._emits_unmatched_build
+        return process_jit(
+            self._jit_key + ("count",),
+            lambda: lambda b, p: self._count(jnp, b, p, need_matched=need))
 
     # --- phase 2: expansion -------------------------------------------------
     def _expand(self, xp, build: Batch, probe: Batch, order, lo, counts,
@@ -239,21 +313,40 @@ class HashJoinExec(Exec):
         plive = xp.arange(probe.capacity, dtype=np.int32) < probe.num_rows
         (pidx, bidx, pair_valid, pvalid, bvalid, total) = jk.expand_pairs(
             xp, order, lo, counts, plive, out_cap, self.how)
-        lcols = [gather_column(xp, c, pidx, pvalid, cc)
-                 for c, cc in zip(probe.columns, pchar_caps)]
-        rcols = [gather_column(xp, c, bidx, bvalid, cc)
-                 for c, cc in zip(build.columns, bchar_caps)]
+        lcols, rcols = _gather_pairs(xp, probe, pidx, pvalid, pchar_caps,
+                                     build, bidx, bvalid, bchar_caps)
         return DeviceBatch(lcols + rcols, total, self.output_names)
 
-    def _expand_call(self, xp, build, probe, order, lo, counts, out_cap,
-                     pchar_caps, bchar_caps):
+    def _expand_sized(self, xp, build: Batch, probe: Batch, order, lo,
+                      counts, caps: tuple) -> Batch:
+        """Phase 2 at the buckets `caps` = (out_cap, probe span caps,
+        build span caps) that `_sizing_fetch` picked: the pairs, the
+        columns gathered through them, the residual condition."""
+        out_cap, pchar_caps, bchar_caps = caps
+        if self._bound_condition is not None and self.how == "left":
+            # its output never exceeds the sizing bound (eff counts
+            # already include the null-extension rows, and the repair
+            # only shrinks)
+            return self._expand_left_cond(xp, build, probe, order, lo,
+                                          counts, out_cap, pchar_caps,
+                                          bchar_caps)
+        out = self._expand(xp, build, probe, order, lo, counts,
+                           out_cap, pchar_caps, bchar_caps)
+        if self._bound_condition is not None and self.how == "inner":
+            pctx = EvalContext(xp, out)
+            out = apply_filter(xp, out, self._bound_condition.eval(pctx),
+                               self.output_names)
+        return out
+
+    def _expand_call(self, xp, build, probe, order, lo, counts,
+                     caps: tuple) -> Batch:
         if xp is np:
-            return self._expand(np, build, probe, order, lo, counts,
-                                out_cap, pchar_caps, bchar_caps)
-        key = self._jit_key + ("expand", out_cap, tuple(pchar_caps),
-                               tuple(bchar_caps))
-        fn = process_jit(key, lambda: lambda b, p, o, l, c: self._expand(
-            jnp, b, p, o, l, c, out_cap, pchar_caps, bchar_caps))
+            return self._expand_sized(np, build, probe, order, lo, counts,
+                                      caps)
+        fn = process_jit(
+            self._jit_key + ("expand",) + caps,
+            lambda: lambda b, p, o, l, c: self._expand_sized(
+                jnp, b, p, o, l, c, caps))
         return fn(build, probe, order, lo, counts)
 
     # --- conditional left join ---------------------------------------------
@@ -270,10 +363,8 @@ class HashJoinExec(Exec):
         plive = xp.arange(probe.capacity, dtype=np.int32) < probe.num_rows
         (pidx, bidx, pair_valid, pvalid, bvalid, total) = jk.expand_pairs(
             xp, order, lo, counts, plive, out_cap, "left")
-        lcols = [gather_column(xp, c, pidx, pvalid, cc)
-                 for c, cc in zip(probe.columns, pchar_caps)]
-        rcols = [gather_column(xp, c, bidx, bvalid, cc)
-                 for c, cc in zip(build.columns, bchar_caps)]
+        lcols, rcols = _gather_pairs(xp, probe, pidx, pvalid, pchar_caps,
+                                     build, bidx, bvalid, bchar_caps)
         out = DeviceBatch(lcols + rcols, total, self.output_names)
         ctx = EvalContext(xp, out)
         v = self._bound_condition.eval(ctx)
@@ -307,20 +398,6 @@ class HashJoinExec(Exec):
         out = DeviceBatch(fixed, total, self.output_names)
         return compact(xp, out, keep, self.output_names)
 
-    def _expand_left_cond_call(self, xp, build, probe, order, lo, counts,
-                               out_cap, pchar_caps, bchar_caps):
-        if xp is np:
-            return self._expand_left_cond(np, build, probe, order, lo,
-                                          counts, out_cap, pchar_caps,
-                                          bchar_caps)
-        key = self._jit_key + ("expand_leftcond", out_cap,
-                               tuple(pchar_caps), tuple(bchar_caps))
-        fn = process_jit(key, lambda: lambda b, p, o, l, c:
-                         self._expand_left_cond(jnp, b, p, o, l, c,
-                                                out_cap, pchar_caps,
-                                                bchar_caps))
-        return fn(build, probe, order, lo, counts)
-
     # --- unmatched build rows for right/full --------------------------------
     def _unmatched_build(self, xp, build: Batch, matched_any) -> Batch:
         keep = (xp.arange(build.capacity, dtype=np.int32) < build.num_rows) \
@@ -333,45 +410,6 @@ class HashJoinExec(Exec):
                  for dt in self.children[0].output_types]
         return DeviceBatch(lcols + list(compacted.columns), n,
                            self.output_names)
-
-    # --- speculative sizing: count+expand fused, zero sizing syncs ----------
-    def _spec_supported(self, build: Batch, probe: Batch) -> bool:
-        """Speculation needs a capacity guess that is usually right and a
-        truncation that a single guard detects: flat fixed-width lanes
-        (span columns would need char-cap guesses too) and join types
-        whose output rides the (probe, build) gather maps only."""
-        if self.how not in ("inner", "left"):
-            return False
-        def flat(c):
-            return c.offsets is None and c.data_hi is None and \
-                not c.children
-        return all(flat(c) for c in probe.columns) and \
-            all(flat(c) for c in build.columns)
-
-    def _spec_join(self, build: Batch, probe: Batch, out_cap: int):
-        """One fused program: count, expand at the guessed capacity, and
-        the guard `total <= out_cap` (validated later from the result
-        fetch — a miss means truncated output, never surfaced)."""
-        order, lo, counts, sizes, _ = self._count(jnp, build, probe)
-        zeros_p = [0] * len(probe.columns)
-        zeros_b = [0] * len(build.columns)
-        if self._bound_condition is not None and self.how == "left":
-            # the conditional-left expand+repair kernel fuses in too;
-            # its output never exceeds the sizing bound (eff counts
-            # already include the null-extension rows, and the repair
-            # only shrinks)
-            out = self._expand_left_cond(jnp, build, probe, order, lo,
-                                         counts, out_cap, zeros_p,
-                                         zeros_b)
-        else:
-            out = self._expand(jnp, build, probe, order, lo, counts,
-                               out_cap, zeros_p, zeros_b)
-            if self._bound_condition is not None and self.how == "inner":
-                pctx = EvalContext(jnp, out)
-                out = apply_filter(jnp, out,
-                                   self._bound_condition.eval(pctx),
-                                   self.output_names)
-        return out, sizes[0] <= np.int64(out_cap)
 
     def _collect_build(self, pid, ctx) -> Batch:
         """Materialize the build side as ONE device batch: this
@@ -397,106 +435,66 @@ class HashJoinExec(Exec):
                               right.output_types) \
             if len(build_batches) > 1 else build_batches[0]
 
-    def execute_partition(self, pid, ctx) -> Iterator[Batch]:
-        from .. import config as cfg
+    def _probe_batch(self, build: Batch, probe: Batch):
+        """One probe batch against the build: (the joined batch, the build
+        rows it matched or None).  The span `join.probe` covers the
+        dispatch of its programs and, inside it, `join.size` the wait for
+        the sizes."""
+        from ..obs import metrics as m
+        from ..obs.tracer import trace_span
         xp = self.xp
-        on_tpu = self.placement == TPU
-        speculate = (on_tpu and ctx.speculation_enabled and
-                     ctx.conf.get(cfg.JOIN_SPECULATIVE_SIZING))
-        build = self._collect_build(pid, ctx)
+        with trace_span("join.probe", how=self.how,
+                        probe_capacity=int(probe.capacity),
+                        build_capacity=int(build.capacity)) as sp:
+            if xp is np:
+                order, lo, counts, sizes, matched = self._count(
+                    np, build, probe, self._emits_unmatched_build)
+            else:
+                (order, lo, counts, sizes,
+                 matched) = self._jit_count(build, probe)
+            if self.how in ("left_semi", "left_anti"):
+                live = xp.arange(probe.capacity, dtype=np.int32) < \
+                    probe.num_rows
+                hit = counts > 0
+                keep = (hit if self.how == "left_semi" else ~hit) & live
+                out, path = compact(xp, probe, keep, self.output_names), \
+                    "count"
+            else:
+                caps = _sizing_fetch(sizes, probe, build)
+                out, path = self._expand_call(xp, build, probe, order, lo,
+                                              counts, caps), "two_phase"
+                sp.set(out_capacity=caps[0])
+            sp.set(path=path)
+        m.counter("tpu_join_probe_batches_total",
+                  "probe batches joined, by how the output was sized: "
+                  "two_phase (a blocking fetch of the count's sizes, then "
+                  "the expansion at their buckets), count (semi and anti "
+                  "joins size nothing)",
+                  ("path",)).labels(path=path).inc()
+        return out, matched
+
+    def execute_partition(self, pid, ctx) -> Iterator[Batch]:
+        from ..obs.tracer import trace_span
+        xp = self.xp
+        with trace_span("join.build") as sp:
+            build = self._collect_build(pid, ctx)
+            # (a build that a filter or a join made keeps its row count
+            # on the device: the span does not wait for it)
+            if isinstance(build.num_rows, (int, np.integer)):
+                sp.set(rows=int(build.num_rows))
+            sp.set(capacity=int(build.capacity))
         matched_acc = None
         for probe in self.children[0].execute_partition(pid, ctx):
-            if speculate and self._spec_supported(build, probe):
-                # guess: output rows <= probe capacity (exact when build
-                # keys are unique — the FK->PK case); the deferred guard
-                # rides the result fetch, so the sizing round trip that
-                # serializes every other join disappears entirely
-                out_cap = int(probe.capacity)
-                with MetricTimer(self.metrics[OP_TIME]):
-                    fn = process_jit(
-                        self._jit_key + ("spec", out_cap),
-                        lambda: lambda b, p: self._spec_join(b, p, out_cap))
-                    out, guard = fn(build, probe)
-                    ctx.add_spec_guard(guard)
-                    maybe_sync(out)
-                self.metrics[NUM_OUTPUT_ROWS] += out.num_rows
-                self.metrics[NUM_OUTPUT_BATCHES] += 1
-                yield out
-                continue
             with MetricTimer(self.metrics[OP_TIME]):
-                if on_tpu:
-                    (order, lo, counts, sizes,
-                     matched) = self._jit_count(build, probe)
-                else:
-                    (order, lo, counts, sizes,
-                     matched) = self._count(np, build, probe)
-                if self.how in ("right", "full"):
+                out, matched = self._probe_batch(build, probe)
+                if matched is not None:
                     matched_acc = matched if matched_acc is None else \
                         (matched_acc | matched)
-                if self.how == "left_semi":
-                    keep = counts > 0
-                    live = xp.arange(probe.capacity, dtype=np.int32) < \
-                        probe.num_rows
-                    yield compact(xp, probe, keep & live, self.output_names)
-                    continue
-                if self.how == "left_anti":
-                    live = xp.arange(probe.capacity, dtype=np.int32) < \
-                        probe.num_rows
-                    yield compact(xp, probe, (counts == 0) & live,
-                                  self.output_names)
-                    continue
-                if self.how == "right":
-                    # planned flipped; only unmatched emission remains here
-                    pass
-                from ..columnar.fetch import fetch_array
-                sizes = fetch_array(sizes)         # one round trip
-                ntotal = int(sizes[0])
-                if ntotal >= (1 << 31):
-                    # expand_pairs builds pair offsets in int32; a wrap
-                    # would silently corrupt gather indices
-                    raise RuntimeError(
-                        f"join expansion of {ntotal} rows exceeds the "
-                        f"2^31-1 per-batch capacity; split the inputs")
-                pbytes = sizes[1:1 + len(probe.columns)]
-                bbytes = sizes[1 + len(probe.columns):]
-                out_cap = bucket_for(max(ntotal, 1), DEFAULT_ROW_BUCKETS)
-
-                def span_cap(x, c):
-                    """Output child capacity for a span column: char
-                    bucket for strings, row bucket for array/map child
-                    rows; 0 = not a span column."""
-                    if isinstance(c.dtype, (t.StringType, t.BinaryType)):
-                        return bucket_for(max(int(x), 1),
-                                          DEFAULT_CHAR_BUCKETS)
-                    if isinstance(c.dtype, (t.ArrayType, t.MapType)):
-                        return bucket_for(max(int(x), 1),
-                                          DEFAULT_ROW_BUCKETS)
-                    return 0
-
-                pchar_caps = [span_cap(x, c)
-                              for x, c in zip(pbytes, probe.columns)]
-                bchar_caps = [span_cap(x, c)
-                              for x, c in zip(bbytes, build.columns)]
-                if self._bound_condition is not None and \
-                        self.how == "left":
-                    out = self._expand_left_cond_call(
-                        xp, build, probe, order, lo, counts, out_cap,
-                        pchar_caps, bchar_caps)
-                else:
-                    out = self._expand_call(xp, build, probe, order, lo,
-                                            counts, out_cap, pchar_caps,
-                                            bchar_caps)
-                    if self._bound_condition is not None and \
-                            self.how == "inner":
-                        pctx = EvalContext(xp, out)
-                        pred = self._bound_condition.eval(pctx)
-                        out = apply_filter(xp, out, pred,
-                                           self.output_names)
                 maybe_sync(out)
             self.metrics[NUM_OUTPUT_ROWS] += out.num_rows
             self.metrics[NUM_OUTPUT_BATCHES] += 1
             yield out
-        if self.how in ("right", "full") and matched_acc is not None:
+        if matched_acc is not None:
             out = self._unmatched_build(xp, build, matched_acc)
             if int(out.num_rows):
                 yield out

@@ -19,12 +19,19 @@ deliberately conservative):
   the scalar's VALUE.  Divide/Pmod and friends stay value-keyed
   (zero-divisor handling), as do decimal / boolean literals (scale
   logic and ``bool()`` coercion concretize the value).
-* string literals hoist as uint8 char arrays whose BYTE LENGTH stays
-  in the jit key (array shape is static under tracing anyway); the
-  string evaluators reachable from the whitelisted parents derive
-  hashes / order keys / broadcast columns on DEVICE from the traced
-  chars, so only same-length strings share a program — `'abc' = s`
-  and `'xyz' = s` dispatch to one executable.
+* string literals hoist as traced uint8 chars; the string evaluators
+  reachable from the whitelisted parents derive hashes / order keys /
+  broadcast columns on DEVICE from them.  Under a comparison (=, <=>,
+  <, <=, >, >=, IN) the chars ride padded to a length bucket
+  (`string_pad_len`: 16, 32, 64, ... bytes) with the byte length beside
+  them as a traced scalar (`StringParam`), so only the BUCKET is in the
+  jit key: `s = 'BUILDING'` and `s = 'AUTOMOBILE'` dispatch to one
+  executable, as a dashboard that walks a column's values needs (the
+  hashes and prefix words read the chars through offsets [0, length],
+  so the padding is never seen).  Under If / CaseWhen value arms the
+  literal is tiled into a column, whose layout follows the length: there
+  the exact byte length stays in the key and only same-length strings
+  share a program.
 * non-null values only: null literals flow through evaluator validity
   short-circuits that branch on ``is_null``.
 * a parameterized tree may key a jit entry ONLY where the parameter
@@ -38,7 +45,7 @@ deliberately conservative):
 
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+from typing import List, NamedTuple, Sequence, Tuple
 
 import numpy as np
 
@@ -61,6 +68,30 @@ PARAM_PARENTS = (EqualTo, EqualNullSafe, LessThan, LessThanOrEqual,
                  # (xp.full / device gather — no host branching)
                  If, CaseWhen)
 
+# parents that read a string operand through offsets and a length, so
+# that a hoisted literal may ride padded (`StringParam`)
+_STRING_PAD_PARENTS = (EqualTo, EqualNullSafe, LessThan, LessThanOrEqual,
+                       GreaterThan, GreaterThanOrEqual, In)
+_STRING_PAD_MIN = 16
+
+
+def string_pad_len(n: int) -> int:
+    """The length bucket of an n-byte hoisted string: the next power of
+    two, 16 at the least."""
+    bucket = _STRING_PAD_MIN
+    while bucket < n:
+        bucket *= 2
+    return bucket
+
+
+class StringParam(NamedTuple):
+    """A hoisted string literal under a comparison, as it rides into the
+    program: the utf-8 chars padded with zeros to their length bucket,
+    and the byte length."""
+    chars: object     # uint8[string_pad_len(length)]
+    length: object    # int32 scalar
+
+
 # value domains whose evaluators never concretize the scalar: fixed-
 # width numerics and the day/microsecond integer encodings
 _PARAM_DTYPES = (t.ByteType, t.ShortType, t.IntegerType, t.LongType,
@@ -75,10 +106,13 @@ class ParamLiteral(LeafExpression):
     replaced; the semantic signature deliberately EXCLUDES the value —
     that is the whole point."""
 
-    def __init__(self, slot: int, dtype: t.DataType, value):
+    def __init__(self, slot: int, dtype: t.DataType, value,
+                 padded: bool = False):
         self.slot = slot
         self.dtype = dtype
         self.value = value
+        #: a string under a comparison: rides as a `StringParam`
+        self.padded = padded
 
     def data_type(self):
         return self.dtype
@@ -89,10 +123,12 @@ class ParamLiteral(LeafExpression):
 
     def _semantic_sig_(self):
         if isinstance(self.dtype, t.StringType):
-            # byte length stays in the key: the chars ride as a traced
-            # uint8 array whose (static) shape is the length anyway
+            # the traced uint8 array's (static) shape stays in the key:
+            # the length bucket where the chars ride padded, else the
+            # byte length
             return ("ParamLiteral", self.slot, repr(self.dtype),
-                    len(self.value))
+                    ("pad", string_pad_len(len(self.value)))
+                    if self.padded else len(self.value))
         return ("ParamLiteral", self.slot, repr(self.dtype))
 
     def sql(self):
@@ -118,12 +154,18 @@ def _eligible(lit: Expression) -> bool:
     return isinstance(lit.dtype, t.StringType) and len(lit.value) > 0
 
 
-def _np_param(lit):
+def _np_param(lit, padded: bool = False):
     """The slot's call-time value: an np scalar typed from the literal's
-    DataType (strings: the utf-8 chars as a uint8 array) so the jit
-    dispatch signature is value-independent."""
+    DataType (strings: the utf-8 chars as a uint8 array, or padded with
+    their length as a `StringParam`) so the jit dispatch signature is
+    value-independent."""
     if isinstance(lit.dtype, t.StringType):
-        return np.frombuffer(lit.value, dtype=np.uint8)
+        chars = np.frombuffer(lit.value, dtype=np.uint8)
+        if not padded:
+            return chars
+        room = np.zeros(string_pad_len(len(chars)), np.uint8)
+        room[:len(chars)] = chars
+        return StringParam(room, np.int32(len(chars)))
     return np.dtype(t.to_np_dtype(lit.dtype)).type(lit.value)
 
 
@@ -131,10 +173,11 @@ def _rewrite(e: Expression, values: List) -> Expression:
     new_children = []
     changed = False
     hoist = isinstance(e, PARAM_PARENTS)
+    padded = isinstance(e, _STRING_PAD_PARENTS)
     for c in e.children:
         if hoist and _eligible(c):
-            values.append(_np_param(c))
-            nc = ParamLiteral(len(values) - 1, c.dtype, c.value)
+            values.append(_np_param(c, padded))
+            nc = ParamLiteral(len(values) - 1, c.dtype, c.value, padded)
         else:
             nc = _rewrite(c, values)
         changed |= nc is not c
@@ -147,9 +190,9 @@ def _rewrite(e: Expression, values: List) -> Expression:
         new_items, items_changed = [], False
         for it in e.items:
             if _eligible(it):
-                values.append(_np_param(it))
+                values.append(_np_param(it, True))
                 new_items.append(ParamLiteral(len(values) - 1,
-                                              it.dtype, it.value))
+                                              it.dtype, it.value, True))
                 items_changed = True
             else:
                 new_items.append(it)
@@ -193,4 +236,4 @@ def param_values(trees: Sequence[Expression]) -> Tuple:
     for b in trees:
         visit(b)
     lits.sort(key=lambda p: p.slot)
-    return tuple(_np_param(p) for p in lits)
+    return tuple(_np_param(p, p.padded) for p in lits)

@@ -108,12 +108,25 @@ def _float_like(dt):
     return isinstance(dt, (t.FloatType, t.DoubleType))
 
 
+def _is_param_string(value) -> bool:
+    """A hoisted string parameter: traced chars (expr/params.py)."""
+    from .params import StringParam
+    return isinstance(value, StringParam) or hasattr(value, "shape")
+
+
 def _param_chars(xp, value):
-    """A hoisted string parameter's traced uint8 chars as a 1-string
-    column (offsets [0, len]); len is static (array shape)."""
+    """A hoisted string parameter as a 1-string column: (offsets
+    [0, len], chars, len).  Under a comparison the chars come padded to
+    their length bucket with the length traced beside them
+    (`params.StringParam`); else the length is the array's."""
+    from .params import StringParam
+    if isinstance(value, StringParam):
+        arr = xp.asarray(value.chars, dtype=xp.uint8)
+        ln = xp.asarray(value.length, dtype=xp.int32)
+        return xp.stack([xp.zeros((), xp.int32), ln]), arr, ln
     arr = xp.asarray(value, dtype=xp.uint8)
-    offs = xp.asarray(np.array([0, int(arr.shape[0])], dtype=np.int32))
-    return offs, arr
+    ln = np.int32(int(arr.shape[0]))
+    return xp.asarray(np.array([0, ln], dtype=np.int32)), arr, ln
 
 
 def _string_eq_data(ctx: EvalContext, lv: Value, rv: Value):
@@ -124,12 +137,11 @@ def _string_eq_data(ctx: EvalContext, lv: Value, rv: Value):
     col, scalar = (lv, rv) if isinstance(lv, ColumnValue) else (rv, lv)
     c1, c2 = sops.string_hashes(xp, col.col.offsets, col.col.data)
     lens = sops.lengths(xp, col.col.offsets)
-    if hasattr(scalar.value, "shape"):
+    if _is_param_string(scalar.value):
         # ParamLiteral string: chars are a traced array, so the hashes
         # must come from the device kernel, not host-side key derivation
-        offs, arr = _param_chars(xp, scalar.value)
+        offs, arr, ln = _param_chars(xp, scalar.value)
         s1, s2 = sops.string_hashes(xp, offs, arr)
-        ln = np.int32(int(arr.shape[0]))
         return (lens == ln) & (c1 == s1[0]) & (c2 == s2[0])
     sval = scalar.value if isinstance(scalar.value, bytes) else \
         (scalar.value or b"")
@@ -145,8 +157,8 @@ def _string_order_lt(ctx: EvalContext, lv: Value, rv: Value, or_equal: bool):
         if isinstance(v, ColumnValue):
             cols = sops.order_keys(xp, v.col.offsets, v.col.data)
             return cols
-        if hasattr(v.value, "shape"):  # ParamLiteral string (traced)
-            offs, arr = _param_chars(xp, v.value)
+        if _is_param_string(v.value):  # ParamLiteral string (traced)
+            offs, arr, _ = _param_chars(xp, v.value)
             cols = sops.order_keys(xp, offs, arr)
             return [xp.broadcast_to(c, (ctx.capacity,)) for c in cols]
         words, _, _, ln = scalar_string_keys(

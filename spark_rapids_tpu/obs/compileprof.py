@@ -171,7 +171,7 @@ def _exec_kind(key: tuple) -> str:
 #: exec/basic.py, parallel/distributed.py)
 _ROLES = frozenset((
     "update", "merge", "merge_eval", "eval", "complete", "sortkeys",
-    "rowpos", "count", "expand", "unmatched", "spec"))
+    "rowpos", "count", "expand", "unmatched"))
 
 
 #: the SPMD steps are keyed by the stage class that traces them

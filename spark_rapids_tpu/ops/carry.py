@@ -61,6 +61,7 @@ class _MoveCounts(threading.local):
     grouped_sorted = 0      # grouped aggregates that are the sort arm alone
     strings_aligned = 0     # string columns moved as row-aligned lanes
     strings_gathered = 0    # string columns moved by offsets and gather
+    join_gathered = 0       # columns a join gathered through its pair maps
 
 
 _COUNTS = _MoveCounts()
@@ -82,7 +83,8 @@ def lane_move_counts() -> dict:
             "grouped_dense": _COUNTS.grouped_dense,
             "grouped_sorted": _COUNTS.grouped_sorted,
             "string_cols_row_aligned": _COUNTS.strings_aligned,
-            "string_cols_gathered": _COUNTS.strings_gathered}
+            "string_cols_gathered": _COUNTS.strings_gathered,
+            "join_cols_gathered": _COUNTS.join_gathered}
 
 
 def count_ungrouped(reduced: bool) -> None:
@@ -92,6 +94,13 @@ def count_ungrouped(reduced: bool) -> None:
         _COUNTS.ungrouped_reduced += 1
     else:
         _COUNTS.ungrouped_sorted += 1
+
+
+def count_join_gathers(columns: int) -> None:
+    """Columns of both sides that a join's expansion gathers row by row
+    through its (probe, build) pair maps (`exec/join.HashJoinExec._expand`
+    calls `ops/gather.gather_column`, which no other count here sees)."""
+    _COUNTS.join_gathered += columns
 
 
 def count_grouped(dense: bool) -> None:

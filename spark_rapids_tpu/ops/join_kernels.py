@@ -19,10 +19,24 @@ Two-phase protocol (one host sync, like cuDF sizing its gather maps):
   phase 2 (jitted `expand_pairs`): materialize (probe_idx, build_idx,
   probe_valid, build_valid) gather maps at that static capacity.
 
-Key hashing: per-column 64-bit words (value hash or content hash for
-strings) mixed with a splitmix-style combiner.  Equal keys always collide
-onto equal hashes; unequal keys collide with probability ~2^-64 —
-documented, same tradeoff as the string-equality design.
+Key hashing, and what "equal" means (`combined_key_hash`): per-column
+64-bit words (the value for integers, an ordered encoding for floats, a
+content hash for strings) mixed with a splitmix-style combiner.  Equal
+keys always land on equal hashes.
+
+  - ONE integer-typed key (bool, byte .. long, date, timestamp): the hash
+    is a composition of bijections of the 64-bit value (`_mix64` is
+    splitmix64's finaliser, three xor-shifts and two odd multiplications;
+    `h0 ^ (w + c)` with constants `h0`, `c`), so unequal keys have unequal
+    hashes and the join is EXACT (tests/test_join.py holds it to that).
+  - Several key columns, floats' NaNs aside, and strings: unequal keys
+    collide with probability about 2^-64 a pair, the same trade as the
+    string-equality design.
+
+No hash VALUE means anything: rows that must not match (slots past a
+batch's rows, rows with a null key) are kept apart by flags that ride the
+sort beside the hash, never by a parking value or a sentinel that a live
+key's hash could equal.
 """
 
 from __future__ import annotations
@@ -36,8 +50,8 @@ from .scan import cumsum_fast
 
 _MIX = np.uint64(0xBF58476D1CE4E5B9)
 _MIX2 = np.uint64(0x94D049BB133111EB)
-_NULL_BUILD = np.uint64(0x9E3779B97F4A7C15)   # sentinel: build-side null key
-_NULL_PROBE = np.uint64(0xC2B2AE3D27D4EB4F)   # distinct: probe-side null key
+_H0 = np.uint64(0x12345678DEADBEEF)
+_GOLDEN = np.uint64(0x9E3779B97F4A7C15)
 
 
 def _mix64(xp, h):
@@ -46,13 +60,14 @@ def _mix64(xp, h):
     return h ^ (h >> np.uint64(31))
 
 
-def combined_key_hash(xp, key_cols, cap, null_matches: bool = False,
-                      side: str = "build"):
-    """uint64[cap] combined hash over the key columns; rows with any null
-    key get a side-specific sentinel so nulls never match (unless
-    null_matches, for null-safe equality)."""
+def combined_key_hash(xp, key_cols, cap):
+    """(uint64[cap] combined hash over the key columns, bool[cap] rows
+    with a null in any key).  A null key matches nothing: the caller takes
+    those rows out of the matching by the flag (`HashJoinExec._count`),
+    whatever their hash.  For one integer-typed column the hash is an
+    injective function of the value (module doc)."""
     from .segmented import encode_float_ordered, encode_int_ordered
-    h = xp.full((cap,), np.uint64(0x12345678DEADBEEF), dtype=xp.uint64)
+    h = xp.full((cap,), _H0, dtype=xp.uint64)
     any_null = xp.zeros((cap,), dtype=bool)
     for col in key_cols:
         dtype = col.dtype
@@ -65,15 +80,16 @@ def combined_key_hash(xp, key_cols, cap, null_matches: bool = False,
             w = xp.zeros((cap,), dtype=xp.uint64)
         else:
             w = _mix64(xp, encode_int_ordered(xp, col.data))
-        h = _mix64(xp, h ^ (w + np.uint64(0x9E3779B97F4A7C15) +
+        h = _mix64(xp, h ^ (w + _GOLDEN +
                             (h << np.uint64(6)) + (h >> np.uint64(2))))
         if col.validity is not None:
             any_null = any_null | ~col.validity
-    if not null_matches:
-        sentinel = _NULL_BUILD if side == "build" else _NULL_PROBE
-        h = xp.where(any_null, sentinel + xp.arange(cap, dtype=xp.uint64)
-                     * xp.uint64(2654435761), h)
-    return h
+    return h, any_null
+
+
+# the combined sort's side lane: within one hash, the build rows that can
+# match sort first, then the probe rows, then the build rows that cannot
+_SIDE_BUILD, _SIDE_PROBE, _SIDE_DEAD = 0, 1, 2
 
 
 def count_matches(xp, build_hash, build_live, probe_hash, probe_live):
@@ -81,20 +97,23 @@ def count_matches(xp, build_hash, build_live, probe_hash, probe_live):
 
     Returns (sorted_build_order, lo, counts) where build rows
     sorted_build_order[lo[i]:lo[i]+counts[i]] match probe row i.
+    `build_live` / `probe_live` are the rows that may match (in the batch
+    and with no null key); a row outside them matches nothing whatever
+    its hash, because liveness rides the sorts as a flag: the build order
+    is by (dead, hash), so the live rows are its prefix in hash order.
 
     TPU path: ONE combined stable sort over (hash, side, index) finds
-    every probe row's build run — within a hash segment build rows sort
-    first, so a probe row's running build count minus the count at the
-    segment start is exactly its match count, and the count at the
+    every probe row's build run — within a hash segment live build rows
+    sort first, so a probe row's running build count minus the count at
+    the segment start is exactly its match count, and the count at the
     segment start is its `lo` into the hash-sorted build order.  A
     per-position binary search (searchsorted) would cost ~log(n) gather
     rounds; this is one sort + two scans + two int32 scatters."""
     cap_b = build_hash.shape[0]
-    # park dead build rows at +inf end
-    bh = xp.where(build_live, build_hash, xp.uint64(0xFFFFFFFFFFFFFFFF))
+    dead = ~build_live
     if xp is np:
-        order = np.argsort(bh, kind="stable").astype(np.int32)
-        sorted_h = bh[order]
+        order = np.lexsort((build_hash, dead)).astype(np.int32)
+        sorted_h = build_hash[order[:int(np.count_nonzero(build_live))]]
         lo = np.searchsorted(sorted_h, probe_hash, side="left").astype(
             np.int32)
         hi = np.searchsorted(sorted_h, probe_hash, side="right").astype(
@@ -104,15 +123,16 @@ def count_matches(xp, build_hash, build_live, probe_hash, probe_live):
     from .carry import sort_lanes, stable_argsort
     from .scan import cummax_i32, cumsum_fast
     cap_p = probe_hash.shape[0]
-    allh = xp.concatenate([bh, probe_hash])
-    side = xp.concatenate([xp.zeros((cap_b,), xp.uint8),
-                           xp.ones((cap_p,), xp.uint8)])
+    allh = xp.concatenate([build_hash, probe_hash])
+    side = xp.concatenate([
+        xp.where(build_live, xp.uint8(_SIDE_BUILD), xp.uint8(_SIDE_DEAD)),
+        xp.full((cap_p,), _SIDE_PROBE, xp.uint8)])
     idx = xp.concatenate([xp.arange(cap_b, dtype=xp.int32),
                           xp.arange(cap_p, dtype=xp.int32)])
-    order = stable_argsort(xp, [bh], cap_b)
+    order = stable_argsort(xp, [dead, build_hash], cap_b)
     _, (sh, ss, si) = sort_lanes(xp, [allh, side], [allh, side, idx],
                                  cap_b + cap_p, need_order=False)
-    is_b = (ss == 0).astype(xp.int32)
+    is_b = (ss == _SIDE_BUILD).astype(xp.int32)
     from .scan import differs_from_prev
     nb = differs_from_prev(xp, sh)
     n_all = cap_b + cap_p
@@ -123,9 +143,10 @@ def count_matches(xp, build_hash, build_live, probe_hash, probe_live):
     # broadcast the segment-start value (bexcl is non-decreasing)
     seg_start_excl = cummax_i32(xp, xp.where(nb, bexcl, xp.int32(-1)))
     cnt_row = bexcl - seg_start_excl        # builds before row in its seg
-    # probe rows sort after every build row of their segment, so cnt_row
-    # IS the match count; scatter (lo, cnt) to original probe positions
-    probe_tgt = xp.where(ss == 1, si, xp.int32(cap_p))
+    # probe rows sort after every live build row of their segment, so
+    # cnt_row IS the match count; scatter (lo, cnt) to original probe
+    # positions
+    probe_tgt = xp.where(ss == _SIDE_PROBE, si, xp.int32(cap_p))
     lo = xp.zeros((cap_p,), xp.int32).at[probe_tgt].set(
         seg_start_excl, mode="drop", unique_indices=True)
     cnt = xp.zeros((cap_p,), xp.int32).at[probe_tgt].set(

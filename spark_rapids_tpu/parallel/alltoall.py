@@ -317,7 +317,7 @@ def exchange_by_pid(batch: DeviceBatch, pids, n_parts: int, axis_name: str,
     ``on_overflow='guard'`` a sub-capacity slot is admitted and the
     return becomes ``(batch, ok)`` where ``ok`` is this shard's
     device-side bool that NO destination overflowed its budget — the
-    speculative-sizing pattern (exec/join.py's deferred guard): the
+    speculative-sizing pattern (exec/base.py's deferred guards): the
     caller checks every shard's guard after the fetch and re-runs with
     ``slot=capacity`` on a miss, paying hash-shard-balanced joins
     ~``slot/capacity`` of the full exchange footprint."""

@@ -161,9 +161,10 @@ class AQEShuffleReadExec(Exec):
             ctx.verify_spec_guards()
         except SpeculativeSizingMiss:
             # The map stage ran under this PRIVATE context, so its
-            # guards never reach the session's speculation-retry: a
-            # speculative join feeding this exchange undershot and the
-            # catalog now holds TRUNCATED blocks.  Heal locally — drop
+            # guards never reach the session's speculation-retry: an
+            # operator's capacity guess (a filter's armed re-bucket)
+            # under this exchange undershot and the catalog now holds
+            # TRUNCATED blocks.  Heal locally — drop
             # the bad shuffle and rewrite it exactly, no speculation.
             from ..obs import metrics as m
             m.counter("tpu_shuffle_map_rewrites_total",

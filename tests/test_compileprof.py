@@ -359,8 +359,8 @@ def test_no_program_of_the_main_path_is_a_lambda(built_programs):
      "TpuHashAggregateExec.sortkeys"),
     (("3.2.0", "HashJoinExec", "inner", (), "expand", 1024, (), ()),
      "HashJoinExec.expand"),
-    (("3.2.0", "HashJoinExec", "inner", (), "spec", 4096),
-     "HashJoinExec.spec"),
+    (("3.2.0", "HashJoinExec", "inner", (), "count"),
+     "HashJoinExec.count"),
     (("3.2.0", "fetch_pack", (("x", "long"),), 1024, (), ()),
      "fetch_pack"),
     (("3.2.0", "DistributedAggregate", "data", (0, 1)),

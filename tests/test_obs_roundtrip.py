@@ -120,9 +120,9 @@ def test_failure_flushes_with_error_status(tmp_path, monkeypatch):
     assert not app.sql_executions[1].failed
 
 
-def test_trace_covers_speculation_retry(tmp_path):
-    # a traced query that speculates must leave a clean, sealed trace
-    # whether or not the guess held (no dangling spans from attempt 1)
+def test_trace_of_a_join_is_sealed(tmp_path):
+    # a traced join (its build, probe and sizing spans, the blocking
+    # fetch inside them) must leave a clean, sealed trace
     s = _session(tmp_path)
     left = s.create_dataframe(_table(128))
     right = s.create_dataframe(pa.table({

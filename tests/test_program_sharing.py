@@ -175,14 +175,45 @@ def test_string_literal_twins_share_programs(obs):
     assert out1.num_rows == out2.num_rows == 128
 
 
+def test_string_lengths_of_one_bucket_share_programs(obs):
+    """Under a comparison the chars ride padded to a length bucket with
+    the byte length traced beside them: literals of 3, 7 and 16 bytes
+    dispatch to one executable and each finds its own rows (a dashboard
+    that walks a column's values builds nothing after the first)."""
+    s = _session()
+    vals = ["red", "reddish", "red\x00", "a-sixteen-byte-s"]
+    df = s.create_dataframe(pa.table({
+        "s": pa.array([vals[i % 4] for i in range(512)]),
+        "v": pa.array(np.arange(512, dtype=np.int64))}))
+    def filters_built():
+        return sum(1 for p in obs.snapshot()["programs"]
+                   if p["exec"] == "FilterExec")
+    first = df.filter(col("s") == "red").collect()
+    assert filters_built() == 1
+    outs = {v: df.filter(col("s") == v).collect() for v in vals}
+    none = df.filter(col("s") == "re").collect()
+    below = df.filter(col("s") < "red").collect()
+    below_long = df.filter(col("s") < "reddish").collect()
+    # (the ordering comparison is a program of its own, built once; an
+    # answer of another shape may build a fetch program)
+    assert filters_built() == 2
+    assert first.num_rows == 128 and none.num_rows == 0
+    for v, out in outs.items():
+        assert set(out.column("s").to_pylist()) == {v}, v
+        assert out.num_rows == 128
+    assert set(below.column("s").to_pylist()) == {"a-sixteen-byte-s"}
+    assert set(below_long.column("s").to_pylist()) == {
+        "a-sixteen-byte-s", "red", "red\x00"}
+
+
 def test_string_length_change_must_compile(obs):
-    """Anti-vacuity: a DIFFERENT byte length is a different traced
+    """Anti-vacuity: a byte length of ANOTHER bucket is a different traced
     shape and must fork the key space (honest recompile)."""
     s = _session()
     df = s.create_dataframe(_stable())
     df.filter(col("s") == "red").collect()
     snap1 = obs.snapshot()
-    out = df.filter(col("s") == "reddish").collect()
+    out = df.filter(col("s") == "reddish-beyond-sixteen").collect()
     snap2 = obs.snapshot()
     assert snap2["builds"] > snap1["builds"]
     assert out.num_rows == 0

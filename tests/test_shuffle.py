@@ -72,14 +72,14 @@ def test_multi_partition_join():
 
 
 def test_expanding_join_through_exchange_is_exact():
-    """Regression: a speculative hash join whose output EXCEEDS the
-    probe batch capacity, feeding a shuffle exchange that materializes
-    under the AQE reader's private ExecContext.  The failed sizing
-    guard used to die with that private context, so the catalog kept
-    the TRUNCATED map blocks and the query silently lost rows (each
-    partition contributed exactly its capacity-bucket of join output).
-    The reader must now verify the guards itself and rewrite the map
-    stage without speculation."""
+    """Regression: a hash join whose output EXCEEDS the probe batch
+    capacity, feeding a shuffle exchange that materializes under the AQE
+    reader's private ExecContext.  When the join guessed its output's
+    capacity, the failed guard died with that private context, so the
+    catalog kept the TRUNCATED map blocks and the query silently lost
+    rows (each partition contributed exactly its capacity-bucket of join
+    output).  The join now sizes its output from the count's sizes; the
+    reader still verifies whatever guards its map stage registered."""
     from spark_rapids_tpu.api.session import TpuSession
     from spark_rapids_tpu.obs import metrics as m
     rng = np.random.default_rng(11)
