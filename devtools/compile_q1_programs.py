@@ -1,7 +1,8 @@
 """Off the chip: the three programs of TPC-H Q1 (`benchmarks/queries/q1.py`:
 `FilterExec`, `TpuHashAggregateExec` in COMPLETE mode, `SortExec`) lowered
 from shapes for a described `v5e:2x2` topology and compiled for one chip, to
-reckon their memory and count their sorts before a chip call is spent:
+reckon their memory and count their sorts before a chip call is spent; and,
+for comparison, the compaction the filter would run under any other consumer:
 
     python devtools/compile_q1_programs.py [rows, default 33554432] [general]
 
@@ -14,6 +15,13 @@ seconds, the count of `sort(`, `gather(`, `conditional(` and `while(` in the
 compiled text, and the compiler's memory figures.  The aggregate's and the
 sort's inputs are at the capacity the operator below them hands up.  A
 compile is not a chip run: no time here is a device time.
+
+Since PR 34 the plan pairs Q1's filter with its aggregate
+(`TpuHashAggregateExec.masked_source`): the filter's program is
+`FilterExec.mask` (`filters_masked` 1, no `sort(`, an output of one bool
+lane and a count) and the aggregate takes the scan's batch and the keep
+flags.  `FilterExec (compaction)` is what the same filter costs a consumer
+that reads rows by position (ten passes).
 
 The aggregate's `sort(` stays 45 with the dense arm in the program (PR 32):
 Q1's two `char(1)` keys send `_group_reduce` down the dense arm, which holds
@@ -141,13 +149,22 @@ def main() -> int:
     params = jax.tree_util.tree_map(
         lambda p: jax.ShapeDtypeStruct(np.shape(p), np.asarray(p).dtype,
                                        sharding=chip), flt._params)
+    keep = ()
+    if agg.masked_source() is flt:
+        print(json.dumps(compile_one(
+            "FilterExec.mask",
+            lambda b, ps: flt._compute_mask(jnp, b, params=ps),
+            batch_like(scan, rows), params)), flush=True)
+        keep = (jax.ShapeDtypeStruct((rows,), np.bool_, sharding=chip),)
     print(json.dumps(compile_one(
-        "FilterExec", lambda b, ps: flt._compute(jnp, b, params=ps),
+        "FilterExec (compaction)",
+        lambda b, ps: flt._compute(jnp, b, params=ps),
         batch_like(scan, rows), params)), flush=True)
     agg_line = compile_one(
         "TpuHashAggregateExec.complete",
-        lambda b: agg._evaluate_batch(jnp, agg._update_batch(jnp, b)),
-        batch_like(flt, rows))
+        lambda b, *keep: agg._evaluate_batch(
+            jnp, agg._update_batch(jnp, b, *keep)),
+        batch_like(flt, rows), *keep)
     print(json.dumps(agg_line), flush=True)
     print(json.dumps(compile_one(
         "SortExec", lambda b: srt._sort_batch(jnp, b),

@@ -1624,18 +1624,15 @@ def run_hbm_gate() -> int:
 
     # (3b) anti-vacuity: injected operator failure -> exactly one
     # well-formed post-mortem bundle
-    from spark_rapids_tpu.exec import basic as exec_basic
-    from spark_rapids_tpu.exec.base import _wrap_execute_partition
-    real_execute = exec_basic.FilterExec.execute_partition
+    from spark_rapids_tpu.testing.faults import arm_filter, disarm_filter
 
-    def boom(self, pid, ctx):
+    def boom(self, pid, ctx, *consumer):
         # generator, so the raise happens at first pull — inside the
         # operator span the flight recorder opens for FilterExec
         raise RuntimeError("hbm gate injected operator failure")
         yield
 
-    exec_basic.FilterExec.execute_partition = \
-        _wrap_execute_partition(boom)
+    armed = arm_filter(boom)
     raised = False
     try:
         with pool.session() as s:
@@ -1648,7 +1645,7 @@ def run_hbm_gate() -> int:
             except Exception:
                 raised = True
     finally:
-        exec_basic.FilterExec.execute_partition = real_execute
+        disarm_filter(armed)
     if not raised:
         failures += 1
         print("HBM: injected operator failure did not raise")
@@ -2465,10 +2462,9 @@ def run_faults_gate() -> int:
     from spark_rapids_tpu.api.pool import (PoolClosedError, PoolTimeout,
                                            SessionPool)
     from spark_rapids_tpu.api.session import TpuSession
-    from spark_rapids_tpu.exec import basic as exec_basic
-    from spark_rapids_tpu.exec.base import _wrap_execute_partition
     from spark_rapids_tpu.memory.admission import AdmissionController
     from spark_rapids_tpu.memory.spill import SpillCatalog
+    from spark_rapids_tpu.testing.faults import arm_filter, disarm_filter
     from spark_rapids_tpu.obs import bgerrors, health
     from spark_rapids_tpu.obs import metrics as m
     from spark_rapids_tpu.obs import postmortem as pm
@@ -2564,7 +2560,6 @@ def run_faults_gate() -> int:
     }
     sess = TpuSession(conf)
     pool = SessionPool(2, conf)
-    real_execute = exec_basic.FilterExec.execute_partition
 
     def inject_session(seam, name, runner, raise_obj=None,
                        expect_name=None):
@@ -2574,12 +2569,11 @@ def run_faults_gate() -> int:
             else raiseflow.construct_error(name)
         expect_name = expect_name or name
 
-        def boom(self, pid, ctx):
+        def boom(self, pid, ctx, *consumer):
             raise err
             yield  # generator: the raise happens inside the op span
 
-        exec_basic.FilterExec.execute_partition = \
-            _wrap_execute_partition(boom)
+        armed = arm_filter(boom)
         before = set(pm.list_bundles(pmdir))
         caught = None
         used_session = []
@@ -2589,7 +2583,7 @@ def run_faults_gate() -> int:
             except BaseException as ex:
                 caught = ex
         finally:
-            exec_basic.FilterExec.execute_partition = real_execute
+            disarm_filter(armed)
         probs = []
         if caught is None:
             probs.append("injected fault never surfaced")
@@ -3687,10 +3681,10 @@ def run_slo_gate() -> int:
     from spark_rapids_tpu.api.column import col
     from spark_rapids_tpu.api.pool import SessionPool
     from spark_rapids_tpu.api.session import TpuSession
-    from spark_rapids_tpu.exec.base import _wrap_execute_partition
-    from spark_rapids_tpu.exec.basic import FilterExec
     from spark_rapids_tpu.memory.admission import AdmissionController
     from spark_rapids_tpu.memory.spill import SpillCatalog
+    from spark_rapids_tpu.testing.faults import (arm_filter, disarm_filter,
+                                                 raw_filter_iterator)
     from spark_rapids_tpu.obs import metrics as m
     from spark_rapids_tpu.obs.critpath import SEGMENT_FAMILY
     from spark_rapids_tpu.obs.health import DEGRADED, OK, HealthMonitor
@@ -3843,15 +3837,13 @@ def run_slo_gate() -> int:
         ledger_path=os.path.join(hist_whale, "latency_ledger.jsonl"))
 
     whale_sleep, victim_sleep = 0.6, 0.05
-    raw_ep = FilterExec.execute_partition.__wrapped__
-    orig_ep = FilterExec.execute_partition
     orig_bound = TpuSession._static_peak_bound
 
-    def sleepy_ep(self, pid, ctx):
+    def sleepy_ep(self, pid, ctx, *consumer):
         s = TpuSession.active()
         tenant = getattr(s, "_tenant", "") if s is not None else ""
         slp = whale_sleep if tenant == "pool-0" else victim_sleep
-        for b in raw_ep(self, pid, ctx):
+        for b in raw_filter_iterator(self, pid, ctx, *consumer):
             if slp:
                 _time.sleep(slp)  # inside the operator span: compute
                 slp = 0.0
@@ -3863,7 +3855,7 @@ def run_slo_gate() -> int:
         return (200 << 20) if getattr(self, "_tenant", "") == "pool-0" \
             else (100 << 20)
 
-    FilterExec.execute_partition = _wrap_execute_partition(sleepy_ep)
+    armed = arm_filter(sleepy_ep)
     TpuSession._static_peak_bound = fixed_bound
     try:
         whale, victims = pool._sessions[0], pool._sessions[1:]
@@ -3890,7 +3882,7 @@ def run_slo_gate() -> int:
                 for f in futs:
                     f.result()
     finally:
-        FilterExec.execute_partition = orig_ep
+        disarm_filter(armed)
         TpuSession._static_peak_bound = orig_bound
 
     # -- whale-phase checks --------------------------------------------------
@@ -4015,10 +4007,10 @@ def run_progress_gate() -> int:
     from spark_rapids_tpu.api.column import col
     from spark_rapids_tpu.api.pool import SessionPool
     from spark_rapids_tpu.api.session import TpuSession
-    from spark_rapids_tpu.exec.base import _wrap_execute_partition
-    from spark_rapids_tpu.exec.basic import FilterExec
     from spark_rapids_tpu.memory.admission import AdmissionController
     from spark_rapids_tpu.memory.spill import SpillCatalog
+    from spark_rapids_tpu.testing.faults import (arm_filter, disarm_filter,
+                                                 raw_filter_iterator)
     from spark_rapids_tpu.obs import bgerrors
     from spark_rapids_tpu.obs import metrics as m
     from spark_rapids_tpu.obs import postmortem as pm
@@ -4245,23 +4237,21 @@ def run_progress_gate() -> int:
               "published")
 
     # -- probed monotone mid-flight ratios -----------------------------------
-    raw_ep = FilterExec.execute_partition.__wrapped__
-    orig_ep = FilterExec.execute_partition
     probe = []
 
-    def probing_ep(self, pid, ctx):
+    def probing_ep(self, pid, ctx, *consumer):
         h = prog.current_handle()
-        for b in raw_ep(self, pid, ctx):
+        for b in raw_filter_iterator(self, pid, ctx, *consumer):
             if h is not None:
                 probe.append(h.progress_ratio())
             yield b
 
-    FilterExec.execute_partition = _wrap_execute_partition(probing_ep)
+    armed = arm_filter(probing_ep)
     try:
         s0 = pool._sessions[0]
         run_as(s0, mixes[id(s0)]["filter4"])
     finally:
-        FilterExec.execute_partition = orig_ep
+        disarm_filter(armed)
     if len(probe) < 4:
         failures += 1
         print(f"PROGRESS: probe saw only {len(probe)} mid-flight "
@@ -4317,14 +4307,14 @@ def run_progress_gate() -> int:
                                     auto_cancel_seconds=0.9)
     started = threading.Event()
 
-    def stuck_ep(self, pid, ctx):
-        for b in raw_ep(self, pid, ctx):
+    def stuck_ep(self, pid, ctx, *consumer):
+        for b in raw_filter_iterator(self, pid, ctx, *consumer):
             if not started.is_set():
                 started.set()
                 _time.sleep(1.4)  # one dead-silent stall, no touch()
             yield b
 
-    FilterExec.execute_partition = _wrap_execute_partition(stuck_ep)
+    armed = arm_filter(stuck_ep)
     before = set(pm.list_bundles(pmdir))
     caught = {}
 
@@ -4357,7 +4347,7 @@ def run_progress_gate() -> int:
         snap = monitor.snapshot()
     finally:
         th.join(30)
-        FilterExec.execute_partition = orig_ep
+        disarm_filter(armed)
         ProgressTracker.get().configure(stall_seconds=30.0)
         ProgressTracker.get().auto_cancel_seconds = None
     op = (stall_rec or {}).get("deepest_open_operator")
@@ -4401,13 +4391,13 @@ def run_progress_gate() -> int:
     started2 = threading.Event()
     release2 = threading.Event()
 
-    def slow_ep(self, pid, ctx):
-        for b in raw_ep(self, pid, ctx):
+    def slow_ep(self, pid, ctx, *consumer):
+        for b in raw_filter_iterator(self, pid, ctx, *consumer):
             started2.set()
             release2.wait(10.0)  # held until the cancel has landed
             yield b
 
-    FilterExec.execute_partition = _wrap_execute_partition(slow_ep)
+    armed = arm_filter(slow_ep)
     before = set(pm.list_bundles(pmdir))
     s2 = pool._sessions[2]
 
@@ -4430,7 +4420,7 @@ def run_progress_gate() -> int:
         release2.set()
     finally:
         th.join(30)
-        FilterExec.execute_partition = orig_ep
+        disarm_filter(armed)
     err = caught.get("compute")
     if not isinstance(err, TpuQueryCancelled) or \
             getattr(err, "cause", None) != "client" or \
@@ -4458,16 +4448,16 @@ def run_progress_gate() -> int:
     h_started = threading.Event()
     hold = threading.Event()
 
-    def holding_ep(self, pid, ctx):
+    def holding_ep(self, pid, ctx, *consumer):
         s = TpuSession.active()
         if getattr(s, "_tenant", "") == "pool-0" and \
                 not h_started.is_set():
             h_started.set()
             hold.wait(20.0)  # holds 200M of admitted budget
-        for b in raw_ep(self, pid, ctx):
+        for b in raw_filter_iterator(self, pid, ctx, *consumer):
             yield b
 
-    FilterExec.execute_partition = _wrap_execute_partition(holding_ep)
+    armed = arm_filter(holding_ep)
     TpuSession._static_peak_bound = fixed_bound
     before = set(pm.list_bundles(pmdir))
     whale, victim = pool._sessions[0], pool._sessions[3]
@@ -4517,7 +4507,7 @@ def run_progress_gate() -> int:
         hold.set()
         th_v.join(30)
         th_w.join(30)
-        FilterExec.execute_partition = orig_ep
+        disarm_filter(armed)
         TpuSession._static_peak_bound = orig_bound
     err = caught.get("queue")
     if not isinstance(err, TpuQueryCancelled) or \

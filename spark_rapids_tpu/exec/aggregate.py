@@ -970,10 +970,38 @@ class TpuHashAggregateExec(Exec):
                 f"[{', '.join(self._group_names)}], fns="
                 f"[{', '.join(a.name for a in self.aggregates)}])")
 
+    def masked_source(self):
+        """The plan seam that pairs a filter with this aggregate: the
+        `FilterExec` whose keep flags the update side reduces under in
+        place of a compacted batch, or None.  Read from the plan's shape
+        alone, when the partition is pulled (after every rewrite and
+        pre-flight repair): this is the update side (`PARTIAL`,
+        `COMPLETE`), the child is the filter with nothing between, both
+        are on the TPU engine, and the filter's `rebucket_cap` is not
+        armed (the L018 repair shrinks a COMPACTED output).  A key or an
+        input that reads a row's position (`rand`,
+        `monotonically_increasing_id`) would see the rows where they lay
+        and not where compaction put them, so such an aggregate takes
+        the compacted batch as well."""
+        from .basic import FilterExec, _exprs_need_rowpos
+        child = self.children[0]
+        if self.mode in (PARTIAL, COMPLETE) and self.placement == TPU and \
+                isinstance(child, FilterExec) and child.placement == TPU \
+                and child.rebucket_cap is None and not _exprs_need_rowpos(
+                    self._bound_grouping + self._update_inputs):
+            return child
+        return None
+
     # --- device kernels -----------------------------------------------------
-    def _update_batch(self, xp, batch: Batch) -> Batch:
+    def _update_batch(self, xp, batch: Batch, keep=None) -> Batch:
+        """One input batch reduced to its groups.  `keep` is a paired
+        filter's flags (`masked_source`): the rows are those of `batch`
+        under it, wherever they lie; every arm of `_group_reduce` reads
+        rows through `live` alone."""
         ctx = EvalContext(xp, batch)
         live = ctx.row_mask()
+        if keep is not None:
+            live = live & keep
         key_cols = [g.eval(ctx).col for g in self._bound_grouping]
         val_cols = []
         for b, op in zip(self._update_inputs, self._update_ops):
@@ -1055,8 +1083,9 @@ class TpuHashAggregateExec(Exec):
 
     @property
     def _jit_update(self):
-        return process_jit(self._jit_key + ("update",),
-                           lambda: lambda b: self._update_batch(jnp, b))
+        return process_jit(
+            self._jit_key + ("update",),
+            lambda: lambda b, keep=None: self._update_batch(jnp, b, keep))
 
     @property
     def _jit_merge(self):
@@ -1082,8 +1111,8 @@ class TpuHashAggregateExec(Exec):
         unique keys, so the merge pass would be an expensive no-op."""
         return process_jit(
             self._jit_key + ("complete",),
-            lambda: lambda b: self._evaluate_batch(jnp,
-                                                   self._update_batch(jnp, b)))
+            lambda: lambda b, keep=None: self._evaluate_batch(
+                jnp, self._update_batch(jnp, b, keep)))
 
     @property
     def _jit_sortkeys(self):
@@ -1149,7 +1178,16 @@ class TpuHashAggregateExec(Exec):
         from ..memory.spill import SpillCatalog, SpillPriority
         spill = SpillCatalog.get()
         try:
-            it = iter(self.children[0].execute_partition(pid, ctx))
+            # the update side's arguments, batch by batch: (batch,), or
+            # (batch, keep flags) from the filter the plan paired with
+            # this aggregate, which then compacts nothing
+            source = self.masked_source()
+            if source is not None:
+                it = ((m.batch, m.keep)
+                      for m in source.execute_masked(pid, ctx, self))
+            else:
+                it = ((b,) for b in
+                      self.children[0].execute_partition(pid, ctx))
             first = next(it, None)
             second = next(it, None) if first is not None else None
             if first is not None and second is None and \
@@ -1160,31 +1198,31 @@ class TpuHashAggregateExec(Exec):
                 # update+evaluate into one compiled program.
                 with MetricTimer(self.metrics[OP_TIME]):
                     if not on_tpu:
-                        out = self._update_batch(np, first)
+                        out = self._update_batch(np, *first)
                         if self.mode == COMPLETE:
                             out = self._evaluate_batch(np, out)
                     elif self.mode == COMPLETE:
-                        out = self._jit_complete(first)
+                        out = self._jit_complete(*first)
                     else:
-                        out = self._jit_update(first)
+                        out = self._jit_update(*first)
                     maybe_sync(out)
-                self._note_rebucket(first.capacity, out)
+                self._note_rebucket(first[0].capacity, out)
                 self.metrics[NUM_OUTPUT_ROWS] += out.num_rows
                 self.metrics[NUM_OUTPUT_BATCHES] += 1
                 yield out
                 return
             import itertools
-            stream = (b for b in itertools.chain(
-                [x for x in (first, second) if x is not None], it))
-            for b in stream:
+            stream = itertools.chain(
+                [x for x in (first, second) if x is not None], it)
+            for args in stream:
                 with MetricTimer(self.metrics[OP_TIME]):
                     if self.mode in (PARTIAL, COMPLETE):
-                        out = self._jit_update(b) if on_tpu else \
-                            self._update_batch(np, b)
+                        out = self._jit_update(*args) if on_tpu else \
+                            self._update_batch(np, *args)
                     else:
-                        out = b  # FINAL: merge happens below
+                        out = args[0]  # FINAL: merge happens below
                     maybe_sync(out)
-                self._note_rebucket(b.capacity, out)
+                self._note_rebucket(args[0].capacity, out)
                 # accumulated partials are spillable (ref aggregate.scala's
                 # spillable batch accumulation before merge)
                 partials.append(spill.register(out, SpillPriority.INPUT))
@@ -1498,7 +1536,8 @@ class CpuHashAggregateExec(Exec):
                 col = pa.chunked_array([pa.array(
                     [[v for v in row if v is not None]
                      for row in chunk.to_pylist()],
-                    type=chunk.type) for chunk in col.chunks])
+                    type=chunk.type) for chunk in col.chunks],
+                    type=col.type)      # (no chunk at all: no group)
             col = col.cast(to_arrow_type(ae.data_type()))
             out_cols.append(col)
         out = pa.table(dict(zip(self.output_names, out_cols)))

@@ -467,8 +467,10 @@ OP_TIME = "opTime"
 
 
 def _wrap_execute_partition(fn):
-    """Route every operator's execute_partition through the tracer's
-    two sinks and the progress observatory: with a tracer installed the
+    """Route every operator's execute_partition (and `FilterExec`'s
+    second iterator, `execute_masked`, which also takes its consumer)
+    through the tracer's two sinks and the progress observatory: with a
+    tracer installed the
     produced iterator is wrapped in a per-(operator, partition) span
     recording batches/rows/bytes and the exception on failure; with
     trace annotations on, each pull of it is a profiler range
@@ -486,10 +488,10 @@ def _wrap_execute_partition(fn):
     import functools
 
     @functools.wraps(fn)
-    def wrapper(self, pid, ctx):
+    def wrapper(self, pid, ctx, *consumer):
         from ..obs import progress as prog
         tr = _obs.active_tracer()
-        inner = fn(self, pid, ctx)
+        inner = fn(self, pid, ctx, *consumer)
         handle = prog.current_handle()
         if handle is not None:
             inner = handle.observe_operator(self, pid, inner)

@@ -27,8 +27,9 @@ from ..expr.core import (EvalContext, Expression, bind_expression,
                          output_name)
 from ..ops.gather import gather_batch
 from .base import (CPU, NUM_OUTPUT_BATCHES, NUM_OUTPUT_ROWS, OP_TIME, TPU,
-                   Batch, Exec, ExecContext, MetricTimer, maybe_sync,
-                   process_jit, schema_sig, semantic_sig)
+                   Batch, Exec, ExecContext, MetricTimer,
+                   _wrap_execute_partition, maybe_sync, process_jit,
+                   schema_sig, semantic_sig)
 
 
 class LocalScanExec(Exec):
@@ -287,11 +288,21 @@ def _exprs_need_rowpos(bound_exprs) -> bool:
 
 
 class FilterExec(Exec):
-    """Columnar filter with device-side compaction
-    (ref GpuFilterExec, basicPhysicalOperators.scala:220).
+    """Columnar filter (ref GpuFilterExec, basicPhysicalOperators.scala:220).
 
-    Compaction keeps static shapes: a stable argsort on the keep flag moves
-    surviving rows to the front; num_rows shrinks to the survivor count."""
+    The plan says what becomes of the predicate's keep flags
+    (`exec/filter_common`):
+
+      - `execute_partition`, for every consumer but one: **compaction**.
+        Static shapes: a stable partition on the keep flag moves the
+        surviving rows to the front, every lane by a sort pass;
+        `num_rows` shrinks to the survivor count.
+      - `execute_masked`, for the update side of the TPU aggregate
+        directly above (`TpuHashAggregateExec.masked_source` pairs the
+        two, and nothing else pulls it): **the mask alone**.  The program
+        `jit_FilterExec.mask` evaluates the predicate and hands up the
+        keep flags and their count; the input batch goes up as it lay.
+        No sort pass, no prefix sum, no copy of a lane."""
 
     def __init__(self, condition: Expression, child: Exec):
         super().__init__([child])
@@ -317,57 +328,78 @@ class FilterExec(Exec):
     def describe(self):
         return f"Filter [{self.condition.sql()}]"
 
-    def _compute(self, xp, batch: Batch, row_base=0, params=None) -> Batch:
+    def _keep(self, xp, batch: Batch, row_base, params):
         ctx = EvalContext(xp, batch, row_base=row_base,
                           params=params if params is not None
                           else (self._params or None))
-        pred = self._bound.eval(ctx)
-        from .filter_common import apply_filter
-        return apply_filter(xp, batch, pred, self.output_names)
+        from .filter_common import keep_flags
+        return keep_flags(xp, batch, self._bound.eval(ctx))
+
+    def _compute(self, xp, batch: Batch, row_base=0, params=None) -> Batch:
+        from ..ops.carry import count_filter
+        from .filter_common import compact
+        count_filter(masked=False)
+        return compact(xp, batch, self._keep(xp, batch, row_base, params),
+                       self.output_names)
+
+    def _compute_mask(self, xp, batch: Batch, row_base=0, params=None):
+        """(keep flags, their count): all that `execute_masked` computes."""
+        from ..ops.carry import count_filter
+        from .filter_common import count_kept
+        count_filter(masked=True)
+        keep = self._keep(xp, batch, row_base, params)
+        return keep, count_kept(xp, keep)
 
     @functools.cached_property
     def _jit_key(self):
         return ("FilterExec", schema_sig(self.children[0]),
                 semantic_sig(self._bound))
 
-    @property
-    def _jitted(self):
-        if self._params:
-            fn = process_jit(
-                self._jit_key,
-                lambda: lambda b, ps: self._compute(jnp, b, params=ps))
-            return lambda b: fn(b, self._params)
-        return process_jit(self._jit_key,
-                           lambda: lambda b: self._compute(jnp, b))
-
-    @property
-    def _jitted_rowpos(self):
-        if self._params:
-            fn = process_jit(
-                self._jit_key + ("rowpos",),
-                lambda: lambda b, base, ps: self._compute(jnp, b, base,
-                                                          params=ps))
-            return lambda b, base: fn(b, base, self._params)
-        return process_jit(self._jit_key + ("rowpos",),
-                           lambda: lambda b, base: self._compute(jnp, b,
-                                                                 base))
+    def _program(self, compute, *role):
+        """`compute` (`_compute`, `_compute_mask`) as a process-wide
+        program under this filter's key and `role`; hoisted literals ride
+        as traced arguments, and so does the row base where the
+        predicate reads a row's position (`role` then holds "rowpos")."""
+        key = self._jit_key + role
+        rowpos = "rowpos" in role
+        if not self._params:
+            return process_jit(key, lambda: (
+                (lambda b, base: compute(jnp, b, base)) if rowpos
+                else (lambda b: compute(jnp, b))))
+        fn = process_jit(key, lambda: (
+            (lambda b, base, ps: compute(jnp, b, base, params=ps)) if rowpos
+            else (lambda b, ps: compute(jnp, b, params=ps))))
+        return lambda *args: fn(*args, self._params)
 
     @functools.cached_property
     def _needs_rowpos(self):
         return _exprs_need_rowpos([self._bound])
 
+    def _run(self, compute, role, b, pid, offset):
+        """`compute` over one input batch, on this filter's engine."""
+        base = ((pid << 33) + offset,) if self._needs_rowpos else ()
+        if self.placement != TPU:
+            return compute(np, b, *base)
+        if base:
+            role = ("rowpos",) + role
+        return self._program(compute, *role)(b, *map(jnp.int64, base))
+
+    def _note_output(self, path: str, rows) -> None:
+        self.metrics[NUM_OUTPUT_ROWS] += rows
+        self.metrics[NUM_OUTPUT_BATCHES] += 1
+        from ..obs import metrics as m
+        m.counter("tpu_filter_batches_total",
+                  "batches a FilterExec answered, by what became of the "
+                  "keep flags: compact (the kept rows moved to the "
+                  "front), mask (the flags handed up to the aggregate "
+                  "above, no lane moved)",
+                  ("path",)).labels(path=path).inc()
+
     def execute_partition(self, pid, ctx) -> Iterator[Batch]:
         offset = 0
         for b in self.children[0].execute_partition(pid, ctx):
             with MetricTimer(self.metrics[OP_TIME]):
-                if self._needs_rowpos:
-                    base = (pid << 33) + offset
-                    out = self._jitted_rowpos(b, jnp.int64(base)) \
-                        if self.placement == TPU \
-                        else self._compute(np, b, base)
-                else:
-                    out = self._jitted(b) if self.placement == TPU \
-                        else self._compute(np, b)
+                out = self._run(self._compute, (), b, pid, offset)
                 cap = self.rebucket_cap
                 if (cap is not None and self.placement == TPU and
                         ctx.speculation_enabled and cap < out.capacity):
@@ -382,9 +414,33 @@ class FilterExec(Exec):
                 maybe_sync(out)
             if self._needs_rowpos:
                 offset += int(b.num_rows)
-            self.metrics[NUM_OUTPUT_ROWS] += out.num_rows
-            self.metrics[NUM_OUTPUT_BATCHES] += 1
+            self._note_output("compact", out.num_rows)
             yield out
+
+    @_wrap_execute_partition
+    def execute_masked(self, pid, ctx, consumer):
+        """Each input batch as it lay, with its keep flags
+        (`filter_common.MaskedBatch`), for `consumer` alone: the
+        aggregate whose `masked_source` this filter is.  Anything else
+        is refused before a batch is made, so a masked batch reaches
+        nothing that does not read the mask."""
+        paired = getattr(consumer, "masked_source", None)
+        if paired is None or paired() is not self:
+            raise RuntimeError(
+                f"{type(consumer).__name__} is not the aggregate paired "
+                "with this filter: only TpuHashAggregateExec."
+                "masked_source() may pull execute_masked")
+        from .filter_common import MaskedBatch
+        offset = 0
+        for b in self.children[0].execute_partition(pid, ctx):
+            with MetricTimer(self.metrics[OP_TIME]):
+                keep, kept = self._run(self._compute_mask, ("mask",), b,
+                                       pid, offset)
+                maybe_sync(keep)
+            if self._needs_rowpos:
+                offset += int(b.num_rows)
+            self._note_output("mask", kept)
+            yield MaskedBatch(b, keep, kept)
 
 
 class RangeExec(Exec):

@@ -171,7 +171,7 @@ def _exec_kind(key: tuple) -> str:
 #: exec/basic.py, parallel/distributed.py)
 _ROLES = frozenset((
     "update", "merge", "merge_eval", "eval", "complete", "sortkeys",
-    "rowpos", "count", "expand", "unmatched"))
+    "rowpos", "count", "expand", "unmatched", "mask"))
 
 
 #: the SPMD steps are keyed by the stage class that traces them

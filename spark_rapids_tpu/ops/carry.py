@@ -62,6 +62,8 @@ class _MoveCounts(threading.local):
     strings_aligned = 0     # string columns moved as row-aligned lanes
     strings_gathered = 0    # string columns moved by offsets and gather
     join_gathered = 0       # columns a join gathered through its pair maps
+    filters_masked = 0      # filters that hand up their keep flags alone
+    filters_compacted = 0   # filters that move the kept rows to the front
 
 
 _COUNTS = _MoveCounts()
@@ -72,7 +74,8 @@ def lane_move_counts() -> dict:
     ungrouped aggregates that moved no row at all (or did), the grouped
     ones that hold the dense arm (or the sort arm alone), and the string
     columns a `sort_rows` moved as row-aligned lanes (fixed-width) or by
-    offsets and gather, traced on this thread so far, under the names a
+    offsets and gather, and the `FilterExec` programs that move no lane
+    (or compact), traced on this thread so far, under the names a
     program's build record gives them.  Tracing a program raises them, so
     the difference around a `lower()` is what that program does."""
     return {"lane_moves_sorted": _COUNTS.sorted,
@@ -84,7 +87,9 @@ def lane_move_counts() -> dict:
             "grouped_sorted": _COUNTS.grouped_sorted,
             "string_cols_row_aligned": _COUNTS.strings_aligned,
             "string_cols_gathered": _COUNTS.strings_gathered,
-            "join_cols_gathered": _COUNTS.join_gathered}
+            "join_cols_gathered": _COUNTS.join_gathered,
+            "filters_masked": _COUNTS.filters_masked,
+            "filters_compacted": _COUNTS.filters_compacted}
 
 
 def count_ungrouped(reduced: bool) -> None:
@@ -101,6 +106,16 @@ def count_join_gathers(columns: int) -> None:
     through its (probe, build) pair maps (`exec/join.HashJoinExec._expand`
     calls `ops/gather.gather_column`, which no other count here sees)."""
     _COUNTS.join_gathered += columns
+
+
+def count_filter(masked: bool) -> None:
+    """One `exec/basic.FilterExec` program, by what it does with the keep
+    flags: hands them up to the aggregate above it and moves no lane, or
+    compacts the batch (`exec/filter_common`)."""
+    if masked:
+        _COUNTS.filters_masked += 1
+    else:
+        _COUNTS.filters_compacted += 1
 
 
 def count_grouped(dense: bool) -> None:

@@ -170,6 +170,41 @@ def test_compact_at_the_largest_bucket(one_chip, record_property):
     assert seconds < 240, "a lane took a new sort signature"
 
 
+@pytest.mark.parametrize("grouping", [(), ("k",)],
+                         ids=["ungrouped", "sort_arm"])
+def test_mask_and_the_aggregate_under_it_at_the_largest_bucket(
+        one_chip, grouping):
+    """A filter directly under an aggregate: its mask program holds no
+    sort and no gather, and writes one bool lane and a count; the
+    aggregate takes the batch where it lay with the flags beside it."""
+    from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
+    from spark_rapids_tpu.exec.basic import FilterExec
+    from spark_rapids_tpu.expr.aggregates import (COMPLETE,
+                                                  AggregateExpression, Sum)
+    from spark_rapids_tpu.expr.core import AttributeReference as A, Literal
+    from spark_rapids_tpu.expr.predicates import GreaterThan
+    flt = FilterExec(GreaterThan(A("v"), Literal(100)), _source(FACT))
+    agg = TpuHashAggregateExec([A(g) for g in grouping], [
+        AggregateExpression(Sum(A("f")), "sf")], COMPLETE, flt)
+    flt.placement = "tpu"
+    assert agg.masked_source() is flt
+    batch = abstract_batch(FACT, M4, one_chip)
+    params = jax.tree_util.tree_map(
+        lambda p: jax.ShapeDtypeStruct(np.shape(p), np.asarray(p).dtype,
+                                       sharding=one_chip), flt._params)
+    mask = compile_for_chip(
+        lambda b, ps: flt._compute_mask(jnp, b, params=ps), batch, params)
+    text = mask.as_text()
+    assert " sort(" not in text and " gather(" not in text
+    out = mask.memory_analysis().output_size_in_bytes
+    assert out < 2 * M4, f"more than a bool lane and a count: {out} bytes"
+    keep = jax.ShapeDtypeStruct((M4,), np.bool_, sharding=one_chip)
+    reduced = compile_for_chip(
+        lambda b, k: agg._evaluate_batch(jnp, agg._update_batch(jnp, b, k)),
+        batch, keep)
+    assert (" sort(" in reduced.as_text()) == bool(grouping)
+
+
 def test_exchange_and_aggregate_over_four_chips(mesh4, record_property):
     """`DistributedAggregate`'s SPMD step (partial aggregate,
     `exchange_by_pid` all_to_all, final aggregate) on a 4-device mesh of

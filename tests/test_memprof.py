@@ -225,8 +225,7 @@ def test_postmortem_bundle_on_injected_failure(tmp_path, capsys):
     parses, names FilterExec as the culprit with the owning tenant and
     HBM occupancy, and renders through `tools postmortem`."""
     from spark_rapids_tpu.api.session import TpuSession
-    from spark_rapids_tpu.exec import basic as exec_basic
-    from spark_rapids_tpu.exec.base import _wrap_execute_partition
+    from spark_rapids_tpu.testing.faults import arm_filter, disarm_filter
     from spark_rapids_tpu.obs import postmortem as pm
     from spark_rapids_tpu.tools.__main__ import main as tools_main
 
@@ -243,15 +242,12 @@ def test_postmortem_bundle_on_injected_failure(tmp_path, capsys):
             "k": pa.array(np.arange(400, dtype=np.int64) % 7),
             "v": pa.array(np.arange(400, dtype=np.int64)),
         })
-        real = exec_basic.FilterExec.execute_partition
-
-        def boom(self, pid, ctx):
+        def boom(self, pid, ctx, *consumer):
             # generator: raises at first pull, inside FilterExec's span
             raise RuntimeError("injected failure for postmortem test")
             yield
 
-        exec_basic.FilterExec.execute_partition = \
-            _wrap_execute_partition(boom)
+        armed = arm_filter(boom)
         try:
             from spark_rapids_tpu.api import functions as F
             from spark_rapids_tpu.api.column import col
@@ -262,7 +258,7 @@ def test_postmortem_bundle_on_injected_failure(tmp_path, capsys):
                  .agg(F.sum(col("v")).alias("sv"))
                  .collect())
         finally:
-            exec_basic.FilterExec.execute_partition = real
+            disarm_filter(armed)
 
         bundles = pm.list_bundles(str(tmp_path))
         assert len(bundles) == 1

@@ -62,21 +62,30 @@ def test_q1_equals_the_reference_and_the_cpu_engine(lineitem, delta):
 
 def test_q1_moves_no_lane_by_gather_and_sorts_the_bounded_output(lineitem):
     _, table = lineitem
-    _, df = _frame(table, True)
+    session, df = _frame(table, True)
     q1.build(df, {"delta": 75}).collect()
-    programs = {p["exec"]: p for p in
-                CompileObservatory.get().snapshot()["programs"]
-                if p["exec"] in ("FilterExec", "TpuHashAggregateExec",
-                                 "SortExec")
+    built = CompileObservatory.get().snapshot()["programs"]
+    programs = {p["exec"]: p for p in built
+                if p["exec"] in ("TpuHashAggregateExec", "SortExec")
                 and p.get("string_cols_row_aligned") == 2}
-    assert set(programs) == {"FilterExec", "TpuHashAggregateExec",
-                             "SortExec"}
+    assert set(programs) == {"TpuHashAggregateExec", "SortExec"}
     for p in programs.values():
         assert p["lane_moves_gathered"] == 0
         assert p["string_cols_gathered"] == 0
-    # ten words: eight of four doubles, the date, and one word for the two
-    # key bytes with the seven validity flags
-    assert programs["FilterExec"]["sort_passes"] == 10
+    # the filter lies directly under the aggregate: it hands up its keep
+    # flags and moves none of the fourteen lanes (ten passes before)
+    nodes = []
+    session.last_plan.foreach(nodes.append)
+    aggregate = next(e for e in nodes
+                     if type(e).__name__ == "TpuHashAggregateExec")
+    assert type(aggregate.masked_source()).__name__ == "FilterExec"
+    masks = [p for p in built
+             if p["exec"] == "FilterExec" and p.get("filters_masked")]
+    assert masks
+    for p in masks:
+        assert p["filters_compacted"] == 0 and p["sort_passes"] == 0
+        assert p["lane_moves_sorted"] == 0 == p["lane_moves_gathered"]
+        assert p["string_cols_row_aligned"] == 0 == p["string_cols_gathered"]
 
 
 def test_the_tolerance_catches_float32_arithmetic_and_a_dropped_line(
