@@ -4387,6 +4387,13 @@ def run_progress_gate() -> int:
         print(f"PROGRESS: cancellations{{cause=watchdog}} = "
               f"{cancel_count('watchdog')}, expected 1")
 
+    def inflight_query(tenant):
+        """The id of the tenant's in-flight query, as the live view
+        (``GET /queries``) shows it: a session counts its queries."""
+        live = ProgressTracker.get().live_view(scan=False)["inflight"]
+        return next((q["query"] for q in live if q["tenant"] == tenant),
+                    "")
+
     # -- cancel mid-compute (session API) ------------------------------------
     started2 = threading.Event()
     release2 = threading.Event()
@@ -4414,7 +4421,7 @@ def run_progress_gate() -> int:
             failures += 1
             print("PROGRESS: compute-cancel query never reached the "
                   "armed operator")
-        if not s2.cancel("q0"):
+        if not s2.cancel(inflight_query("pool-2")):
             failures += 1
             print("PROGRESS: session.cancel found no in-flight query")
         release2.set()
@@ -4491,7 +4498,7 @@ def run_progress_gate() -> int:
         if ac.queue_depth < 1:
             failures += 1
             print("PROGRESS: victim never queued behind the whale")
-        if not pool.cancel("pool-3", "q0"):
+        if not pool.cancel("pool-3", inflight_query("pool-3")):
             failures += 1
             print("PROGRESS: pool.cancel found no in-flight query")
         # the cancelled ticket must leave the FIFO while the whale

@@ -53,6 +53,7 @@ class TpuSession:
         self.last_explain = ""
         # flight recorder (obs/): per-query trace + self-emitted event log
         self._last_trace = None
+        self._last_profile = None   # the host ledger's last record
         self._obs_plan = None
         self._obs_writer = None
         self._sql_counter = 0
@@ -386,68 +387,83 @@ class TpuSession:
             # whose owners were constructed since the last query
             from ..obs import lockwitness
             lockwitness.ensure_installed()
-        eventlog_dir = conf.get(cfg.EVENT_LOG_DIR)
-        tracing = conf.get(cfg.TRACE_ENABLED) or eventlog_dir is not None
-        # HBM observatory attribution scope: every spill/arena event on
-        # this thread books under (tenant, query) until the query ends
-        memprof.push_context(getattr(self, "_tenant", "") or "default",
-                             f"q{self._sql_counter}")
-        # progress observatory: register the live-view record + cancel
-        # token, bound thread-local so the cooperative checkpoints in
-        # exec/admission/shuffle find it without signature plumbing
-        if deadline_ms is None:
-            deadline_ms = conf.get(cfg.PROGRESS_DEADLINE_MS)
-        handle = prog.ProgressTracker.get().begin_query(
-            f"q{self._sql_counter}",
-            tenant=getattr(self, "_tenant", "") or "default",
-            deadline_ms=deadline_ms)
-        prog.bind_to_thread(handle)
+        # one id a query, nested ones too: the profiler's root range,
+        # the host ledger's record, the live view, memprof's context and
+        # the event log's sql_id all carry it
+        sql_id = self._sql_counter
+        self._sql_counter += 1
+        query_id = f"q{sql_id}"
         # the profiler's root range: every range of this query nests in
         # one that carries its id (the flight recorder has its own root)
-        root_range = obs.open_range(f"query:q{self._sql_counter}")
+        root_range = obs.open_range("query:" + query_id, query_id)
         try:
-            if not tracing:
+            eventlog_dir = conf.get(cfg.EVENT_LOG_DIR)
+            tracing = conf.get(cfg.TRACE_ENABLED) or \
+                eventlog_dir is not None
+            # HBM observatory attribution scope: every spill/arena event
+            # on this thread books under (tenant, query) until the query
+            # ends
+            memprof.push_context(
+                getattr(self, "_tenant", "") or "default", query_id)
+            # progress observatory: register the live-view record +
+            # cancel token, bound thread-local so the cooperative
+            # checkpoints in exec/admission/shuffle find it without
+            # signature plumbing
+            if deadline_ms is None:
+                deadline_ms = conf.get(cfg.PROGRESS_DEADLINE_MS)
+            handle = prog.ProgressTracker.get().begin_query(
+                query_id,
+                tenant=getattr(self, "_tenant", "") or "default",
+                deadline_ms=deadline_ms)
+            prog.bind_to_thread(handle)
+            try:
+                if not tracing:
+                    try:
+                        result = self._execute_query(lp, None, None)
+                        prog.ProgressTracker.get().end_query(handle)
+                        return result
+                    except BaseException as ex:
+                        prog.ProgressTracker.get().end_query(handle, ex)
+                        self._maybe_postmortem(ex, None)
+                        raise
+                # flight recorder: one QueryTrace per execute(); the
+                # installed tracer is what every instrumented layer
+                # (operator spans, spill/shuffle/ICI/bridge events)
+                # records
+                tracer = obs.QueryTrace(
+                    max_spans=conf.get(cfg.TRACE_MAX_SPANS))
+                tracer.sql_id = sql_id
+                if self._obs_isolation:
+                    obs.install_local(tracer)
+                else:
+                    obs.install(tracer)
+                self._last_trace = tracer
+                self._obs_plan = None
                 try:
-                    result = self._execute_query(lp, None, None)
+                    result = self._execute_query(lp, tracer, eventlog_dir)
                     prog.ProgressTracker.get().end_query(handle)
                     return result
                 except BaseException as ex:
+                    # failed queries flush too: spans close with the
+                    # exception recorded, the event log gets a JobFailed
+                    # group; the black box dumps AFTER the flush so the
+                    # bundle sees the sealed trace
                     prog.ProgressTracker.get().end_query(handle, ex)
-                    self._maybe_postmortem(ex, None)
+                    self._flush_query_obs(tracer, ex, eventlog_dir)
+                    self._maybe_postmortem(ex, tracer)
                     raise
-            # flight recorder: one QueryTrace per execute(); the
-            # installed tracer is what every instrumented layer
-            # (operator spans, spill/shuffle/ICI/bridge events) records
-            tracer = obs.QueryTrace(
-                max_spans=conf.get(cfg.TRACE_MAX_SPANS))
-            if self._obs_isolation:
-                obs.install_local(tracer)
-            else:
-                obs.install(tracer)
-            self._last_trace = tracer
-            self._obs_plan = None
-            try:
-                result = self._execute_query(lp, tracer, eventlog_dir)
-                prog.ProgressTracker.get().end_query(handle)
-                return result
-            except BaseException as ex:
-                # failed queries flush too: spans close with the
-                # exception recorded, the event log gets a JobFailed
-                # group; the black box dumps AFTER the flush so the
-                # bundle sees the sealed trace
-                prog.ProgressTracker.get().end_query(handle, ex)
-                self._flush_query_obs(tracer, ex, eventlog_dir)
-                self._maybe_postmortem(ex, tracer)
-                raise
+                finally:
+                    if self._obs_isolation:
+                        obs.uninstall_local()
+                    else:
+                        obs.uninstall()
             finally:
-                if self._obs_isolation:
-                    obs.uninstall_local()
-                else:
-                    obs.uninstall()
+                prog.bind_to_thread(None)
+                memprof.pop_context()
         finally:
-            prog.bind_to_thread(None)
-            memprof.pop_context()
-            obs.close_range(root_range)
+            profile = obs.close_range(root_range)
+            if profile is not None:
+                self._last_profile = profile
 
     def _execute_query(self, lp: L.LogicalPlan, tracer,
                        eventlog_dir) -> pa.Table:
@@ -467,7 +483,8 @@ class TpuSession:
         # held across the speculation retry (re-entrancy), released in
         # the finally (release-on-failure)
         try:
-            ticket, controller = self._admit_plan(final_plan)
+            with trace_span("phase:admit", kind="phase"):
+                ticket, controller = self._admit_plan(final_plan)
         except BaseException:
             # a cancel / deadline / AdmissionTimeout raised while
             # queued must not strand the shuffle blocks that exchange
@@ -484,41 +501,43 @@ class TpuSession:
     def _execute_admitted(self, lp: L.LogicalPlan, final_plan, tracer,
                           eventlog_dir, ticket) -> pa.Table:
         from ..obs.tracer import trace_span
-        self._obs_plan = final_plan
-        self._install_predictions(tracer, final_plan)
-        from ..plugin import ExecutionPlanCaptureCallback
-        ExecutionPlanCaptureCallback.on_plan(final_plan)
-        ctx = ExecContext(self.conf)
-        # exchange-boundary re-planner: armed for the whole execution
-        # (feedback.enabled gates inside); it needs the live ticket to
-        # re-price and the exec context to pin strategy switches on
-        from ..analysis import replan as replan_mod
-        from ..memory.admission import AdmissionController
-        rctx = replan_mod.ReplanContext(
-            plan_root=final_plan, conf=self.conf, ticket=ticket,
-            controller=AdmissionController.get()
-            if ticket is not None else None,
-            tracer=tracer, exec_ctx=ctx)
-        replan_mod.install(rctx)
-        # boundaries whose map stage ran during planning replay now —
-        # still before the first reduce partition launches
-        replan_mod.scan_materialized(rctx)
-        from ..memory.spill import SpillCatalog
-        debug = self.conf.get(cfg.MEMORY_DEBUG)
-        cat = SpillCatalog.get()
-        # tmsan runtime sanitizer: record + assert the buffer lifecycle
-        # state machine on every catalog/arena event while the query
-        # runs, then require a clean ledger (no leaks) afterwards.
-        # Pool sessions install thread-locally: a per-query clean check
-        # must not flag co-running queries' live buffers as leaks.
-        from ..memory import memsan
-        memsan_on = self.conf.get(cfg.MEMSAN_ENABLED)
-        if memsan_on:
-            ledger = memsan.install_local() if self._obs_isolation \
-                else memsan.install()
-        if debug:
-            cat.debug = True
-            before = {b_id for b_id, *_ in cat.leak_report()}
+        # what the session does between the plan and the first operator
+        with trace_span("phase:setup", kind="phase"):
+            self._obs_plan = final_plan
+            self._install_predictions(tracer, final_plan)
+            from ..plugin import ExecutionPlanCaptureCallback
+            ExecutionPlanCaptureCallback.on_plan(final_plan)
+            ctx = ExecContext(self.conf)
+            # exchange-boundary re-planner: armed for the whole execution
+            # (feedback.enabled gates inside); it needs the live ticket to
+            # re-price and the exec context to pin strategy switches on
+            from ..analysis import replan as replan_mod
+            from ..memory.admission import AdmissionController
+            rctx = replan_mod.ReplanContext(
+                plan_root=final_plan, conf=self.conf, ticket=ticket,
+                controller=AdmissionController.get()
+                if ticket is not None else None,
+                tracer=tracer, exec_ctx=ctx)
+            replan_mod.install(rctx)
+            # boundaries whose map stage ran during planning replay now —
+            # still before the first reduce partition launches
+            replan_mod.scan_materialized(rctx)
+            from ..memory.spill import SpillCatalog
+            debug = self.conf.get(cfg.MEMORY_DEBUG)
+            cat = SpillCatalog.get()
+            # tmsan runtime sanitizer: record + assert the buffer lifecycle
+            # state machine on every catalog/arena event while the query
+            # runs, then require a clean ledger (no leaks) afterwards.
+            # Pool sessions install thread-locally: a per-query clean check
+            # must not flag co-running queries' live buffers as leaks.
+            from ..memory import memsan
+            memsan_on = self.conf.get(cfg.MEMSAN_ENABLED)
+            if memsan_on:
+                ledger = memsan.install_local() if self._obs_isolation \
+                    else memsan.install()
+            if debug:
+                cat.debug = True
+                before = {b_id for b_id, *_ in cat.leak_report()}
         try:
             try:
                 with trace_span("phase:execute", kind="phase"):
@@ -581,37 +600,38 @@ class TpuSession:
             raise
         finally:
             replan_mod.uninstall()
-        self.release_plan_shuffles(final_plan)
-        if memsan_on:
-            try:
-                # everything the query registered must have reached
-                # CLOSED (pinned scan caches are sanctioned residents);
-                # leaks surface with owning-exec provenance
+        with trace_span("phase:release", kind="phase"):
+            self.release_plan_shuffles(final_plan)
+            if memsan_on:
                 try:
-                    ledger.assert_clean()
-                except BaseException:
-                    from ..obs import metrics as m
-                    m.counter("tpu_memsan_dirty_ledgers_total",
-                              "queries whose shadow ledger was dirty "
-                              "(leak or lifecycle violation)").inc()
-                    raise
-            finally:
-                self.last_peak_device_bytes = ledger.peak_device_bytes
-                if tracer is not None:
-                    tracer.measured_peak_device_bytes = \
-                        ledger.peak_device_bytes
-                self._memsan_uninstall(memsan)
-        if debug:
-            leaks = [l for l in cat.leak_report() if l[0] not in before]
-            cat.debug = False
-            if leaks:
-                detail = "\n---\n".join(
-                    f"{i} tier={t_} bytes={b}\n{st}"
-                    for i, t_, b, st in leaks)
-                from ..memory.memsan import LifecycleViolation
-                raise LifecycleViolation(
-                    f"query leaked {len(leaks)} spillable "
-                    f"buffer(s) (memory.tpu.debug):\n{detail}")
+                    # everything the query registered must have reached
+                    # CLOSED (pinned scan caches are sanctioned residents);
+                    # leaks surface with owning-exec provenance
+                    try:
+                        ledger.assert_clean()
+                    except BaseException:
+                        from ..obs import metrics as m
+                        m.counter("tpu_memsan_dirty_ledgers_total",
+                                  "queries whose shadow ledger was dirty "
+                                  "(leak or lifecycle violation)").inc()
+                        raise
+                finally:
+                    self.last_peak_device_bytes = ledger.peak_device_bytes
+                    if tracer is not None:
+                        tracer.measured_peak_device_bytes = \
+                            ledger.peak_device_bytes
+                    self._memsan_uninstall(memsan)
+            if debug:
+                leaks = [l for l in cat.leak_report() if l[0] not in before]
+                cat.debug = False
+                if leaks:
+                    detail = "\n---\n".join(
+                        f"{i} tier={t_} bytes={b}\n{st}"
+                        for i, t_, b, st in leaks)
+                    from ..memory.memsan import LifecycleViolation
+                    raise LifecycleViolation(
+                        f"query leaked {len(leaks)} spillable "
+                        f"buffer(s) (memory.tpu.debug):\n{detail}")
         if tracer is not None:
             self._flush_query_obs(tracer, None, eventlog_dir)
         return result
@@ -725,6 +745,14 @@ class TpuSession:
         spark.rapids.tpu.trace.enabled and eventLog.dir were unset)."""
         return self._last_trace
 
+    def last_query_profile(self) -> Optional[Dict]:
+        """The host ledger's record of this session's last top-level
+        query: ``{id, wall_ns, segments: {segment: self_ns}, spans:
+        {name: [count, inclusive_ns]}, off_thread_ns}``; the segments sum
+        to the wall.  None until a query has run with
+        spark.rapids.sql.profile.traceAnnotations on."""
+        return self._last_profile
+
     def _install_predictions(self, tracer, final_plan) -> None:
         """Attach the CBO/interp row+byte model and tmsan's static
         peak-HBM bound to the trace, keyed by plan node — actuals are
@@ -808,8 +836,7 @@ class TpuSession:
             pass  # attribution is advisory; never mask the query's outcome
         if eventlog_dir is None or final_plan is None:
             return
-        sql_id = self._sql_counter
-        self._sql_counter += 1
+        sql_id = tracer.sql_id
         try:
             writer = self._event_log_writer(eventlog_dir)
             writer.write_query(

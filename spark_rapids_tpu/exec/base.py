@@ -626,6 +626,7 @@ class Exec:
                                     TpuQueryDeadlineExceeded)
         sem = TpuSemaphore.get()
         out: List[pa.RecordBatch] = []
+        arrow_span = self.name + ".toArrow"
         for pid in range(self.num_partitions):
             # cooperative cancel checkpoint at the partition boundary:
             # nothing device-side is in flight here, so unwinding now
@@ -646,17 +647,21 @@ class Exec:
             sem.acquire_if_necessary(pid)
             try:
                 for b in self.execute_partition(pid, ctx):
-                    rb = to_host_batch(b, self.output_names)
+                    # the answer's batches turned into Arrow: the root
+                    # operator's own work, outside its iterator
+                    with _obs.trace_span(arrow_span):
+                        rb = to_host_batch(b, self.output_names)
                     if rb.num_rows:
                         out.append(rb)
             finally:
                 sem.release_if_necessary(pid)
         ctx.verify_spec_guards()
         from ..columnar.interop import to_arrow_schema
-        schema = to_arrow_schema(self.output_names, self.output_types)
-        if not out:
-            return schema.empty_table()
-        return pa.Table.from_batches([b.cast(schema) for b in out])
+        with _obs.trace_span(arrow_span):
+            schema = to_arrow_schema(self.output_names, self.output_types)
+            if not out:
+                return schema.empty_table()
+            return pa.Table.from_batches([b.cast(schema) for b in out])
 
     # -- display ------------------------------------------------------------
     @property

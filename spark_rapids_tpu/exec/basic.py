@@ -23,6 +23,7 @@ import pyarrow as pa
 from .. import types as t
 from ..columnar.device import (DEFAULT_ROW_BUCKETS, DeviceBatch, DeviceColumn,
                                batch_to_device, bucket_for)
+from ..columnar.fetch import fetch_array
 from ..expr.core import (EvalContext, Expression, bind_expression,
                          output_name)
 from ..ops.gather import gather_batch
@@ -549,7 +550,9 @@ class LocalLimitExec(Exec):
         remaining = self.limit
         xp = self.xp
         for b in self.children[0].execute_partition(pid, ctx):
-            n = int(b.num_rows)
+            # a device row count is a blocking read: through the
+            # sanctioned fetch, so it is a fetch.crossing span and counts
+            n = int(fetch_array(b.num_rows))
             take = min(n, remaining)
             if take < n:
                 mask = xp.arange(b.capacity) < take

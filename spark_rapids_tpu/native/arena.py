@@ -92,14 +92,11 @@ class HostArena:
                 self._arena_id,
                 size if self._closed else self.used + size, self._closed)
         mm = _metrics()
-        tenant, query = _tenant_ctx()
+        tenant, _ = _tenant_ctx()
         with self._lock:
             if self._arena is not None:
                 off = self._lib.tpu_arena_alloc(self._arena, size, align)
                 if off < 0:
-                    _trace_event("arena.exhausted", wanted=size,
-                                 capacity=self.capacity, tenant=tenant,
-                                 query=query)
                     mm[1].labels(tenant=tenant).inc()
                     return None
                 base = self._lib.tpu_arena_base(self._arena)
@@ -109,9 +106,6 @@ class HostArena:
             else:
                 off = (self._used + align - 1) & ~(align - 1)
                 if off + size > self.capacity:
-                    _trace_event("arena.exhausted", wanted=size,
-                                 capacity=self.capacity, tenant=tenant,
-                                 query=query)
                     mm[1].labels(tenant=tenant).inc()
                     return None
                 self._used = off + size

@@ -818,18 +818,28 @@ class _ProfiledJit:
                 for sk in list(self._compiled)]
 
     def __call__(self, *args):
-        sig = _dispatch_key(args)
+        # the one test a launch makes for the tracer's sinks; with both
+        # off (where the parent tested once, in _dispatch) no span opens
+        sinks_on = _tracer.ANNOTATIONS_ON or \
+            _tracer.active_tracer() is not None
+        if sinks_on:
+            # what finding the program costs the host: the signature of
+            # the arguments and the lookup, before any launch
+            with _tracer.trace_span("jit.key:" + self._exec):
+                sig = _dispatch_key(args)
+        else:
+            sig = _dispatch_key(args)
         if sig is None:
             # unsignable leaves (e.g. called under an enclosing trace):
             # plain jit dispatch, recorded under the same canonical key
             return self._traced_call(args)
         fn = self._compiled.get(sig)
         if fn is not None:
-            return self._dispatch(fn, args, sig)
+            return self._dispatch(fn, args, sig, sinks_on)
         if self._prewarmed and _on_default_device(sig):
             fn = self._prewarmed.get(_erase_sharding(sig))
             if fn is not None:
-                out = self._dispatch(fn, args)
+                out = self._dispatch(fn, args, None, sinks_on)
                 with self._lock:
                     self._compiled.setdefault(sig, fn)
                 self._obs.note_prewarm_hit(
@@ -837,17 +847,18 @@ class _ProfiledJit:
                     (self._key_hash,
                      _shape_record(sig, self._obs.buckets)[0]))
                 return out
-        return self._build_and_call(sig, args)
+        return self._build_and_call(sig, args, sinks_on)
 
-    def _dispatch(self, fn, args, sig=None):
+    def _dispatch(self, fn, args, sig, sinks_on):
         """The call into the compiled executable, as the span
         ``jit.dispatch:<exec kind>`` when either of the tracer's sinks
-        is on: the host's side of every program launch.  A program with
-        collectives adds its wire bytes (found when it was traced) to
-        ``tpu_ici_wire_bytes_total``; no device value is read."""
+        is on (`sinks_on`, as ``__call__`` found it): the host's side of
+        every program launch.  A program with collectives adds its wire
+        bytes (found when it was traced) to ``tpu_ici_wire_bytes_total``;
+        no device value is read."""
         if self._wire_bytes:
             _note_wire_bytes(self._wire_bytes.get(sig, 0))
-        if not _tracer.ANNOTATIONS_ON and _tracer.active_tracer() is None:
+        if not sinks_on:
             return fn(*args)
         with _tracer.trace_span("jit.dispatch:" + self._exec):
             return fn(*args)
@@ -875,7 +886,7 @@ class _ProfiledJit:
                                self._key_head)
         return out
 
-    def _build_and_call(self, sig, args):
+    def _build_and_call(self, sig, args, sinks_on):
         with self._lock:
             fn = self._compiled.get(sig)
             if fn is None:
@@ -885,7 +896,7 @@ class _ProfiledJit:
                 with _tracer.trace_span("jit.build:" + self._exec) as span:
                     fn = self._build(sig, args, span)
                 self._compiled[sig] = fn
-        return self._dispatch(fn, args, sig)
+        return self._dispatch(fn, args, sig, sinks_on)
 
     def _build(self, sig, args, span):
         t0 = time.perf_counter()

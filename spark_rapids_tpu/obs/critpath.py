@@ -21,8 +21,23 @@ segment             booked from
                     intervals reconstructed from enriched ``jit.build``
                     instant events (``total_s`` attr)
 ``prewarm``         same, when the build's ``cause`` is ``prewarm``
+``session``         ``phase:admit`` / ``phase:setup`` / ``phase:release``
+                    self-time: the session's own steps around the plan
+                    (admission's pricing, arming the re-planner and the
+                    sanitizers, releasing shuffle blocks)
 ``host_assist``     ``phase:host_assist`` self-time (fetch crossings)
-``compute:<Kind>``  operator-kind spans (``FilterExec`` etc.) self-time
+``compute:<Kind>``  operator-kind spans (``FilterExec`` etc.) self-time;
+                    the profiler sink's ``<Exec>.pull`` / ``<Exec>.
+                    <metric>`` ranges; and the spans an operator opens
+                    for its own work (``join.*``, ``ici.stage:<op>``,
+                    ``scan.upload``), which take the segment of the
+                    operator around them (``compute:join`` / ``ici`` /
+                    ``scan`` with none around them)
+``dispatch``        ``jit.key:<kind>`` / ``jit.dispatch:<kind>``: the
+                    host's side of a program launch (host ledger only:
+                    the sweep below folds them into their parent)
+``fetch_wait``      ``fetch.crossing``: the host blocked in a device-to-
+                    host transfer (host ledger only, as above)
 ``shuffle_write``   ``shuffle.map_write`` self-time
 ``fetch_wire``      ``shuffle.fetch`` self-time — time on the wire
                     after subtracting grafted producer-serve spans
@@ -32,6 +47,10 @@ segment             booked from
                     ``oc.merge_partials`` — out-of-core spill + merge
 ``other``           root / ``phase:execute`` / bridge self-time
 ==================  =====================================================
+
+:func:`segment_of` is the tree's one map from span names to segments:
+the host ledger (``obs/tracer.HostLedger``) books every range of the
+profiler sink through it too.
 
 **No double-booking.**  Concurrent children (per-partition execute
 spans, parallel shuffle fetches) overlap in wall time; summing their
@@ -64,10 +83,13 @@ SEG_PLANNING = "planning"
 SEG_COMPILE = "compile"
 SEG_PREWARM = "prewarm"
 SEG_HOST_ASSIST = "host_assist"
+SEG_SESSION = "session"
 SEG_SHUFFLE_WRITE = "shuffle_write"
 SEG_FETCH_WIRE = "fetch_wire"
 SEG_FETCH_SERVE = "fetch_serve"
 SEG_OC_SPILL = "oc_spill"
+SEG_DISPATCH = "dispatch"
+SEG_FETCH_WAIT = "fetch_wait"
 SEG_OTHER = "other"
 COMPUTE_PREFIX = "compute:"
 
@@ -81,22 +103,39 @@ _PLANNING_NAMES = frozenset((
     "phase:subqueries", "phase:plan-retry", "replan",
 ))
 
+_SESSION_NAMES = frozenset((
+    "phase:admit", "phase:setup", "phase:release",
+))
+
 _OC_PREFIX = "oc."
+
+#: the roots: what no span below them names is theirs, and is `other`
+_ROOT_NAMES = frozenset((
+    "query", "phase:execute", "phase:execute-retry",
+    "bridge.execute_stage",
+))
+
+#: spans an operator opens around its own work: their self time is the
+#: enclosing operator's, whichever it is; with no operator around them
+#: it goes under the name's own head, still a ``compute:`` segment
+_OPERATOR_WORK = (("join.", "join"), ("ici.stage:", "ici"),
+                  ("scan.upload", "scan"))
+_DISPATCH_PREFIXES = ("jit.dispatch:", "jit.key:")
 
 #: spans that detail their parent's own work (a program launch, a
 #: fetch's blocking transfer, an upload): they own no segment, their
 #: time stays the enclosing operator's or phase's self-time
 _DETAIL_NAMES = frozenset(("fetch.crossing", "scan.upload"))
-_DETAIL_PREFIX = "jit.dispatch:"
 _BUILD_PREFIX = "jit.build:"
 
 
 def _is_detail(name: str) -> bool:
-    return name in _DETAIL_NAMES or name.startswith(_DETAIL_PREFIX)
+    return name in _DETAIL_NAMES or name.startswith(_DISPATCH_PREFIXES)
 
 
-def segment_of(span: dict) -> str:
-    """Map one span dict to its latency segment.
+def segment_of(span: dict, enclosing: Optional[str] = None) -> str:
+    """Map one span dict to its latency segment; `enclosing` is the
+    segment of the span around it, where the caller knows it.
 
     Grafted remote spans carry ``proc`` (the producing process) and
     classify as producer-serve time regardless of name — a remote
@@ -111,6 +150,8 @@ def segment_of(span: dict) -> str:
         return SEG_PLANNING
     if name == "phase:host_assist":
         return SEG_HOST_ASSIST
+    if name in _SESSION_NAMES:
+        return SEG_SESSION
     if name == "jit.build":  # synthetic compile interval (see below)
         attrs = span.get("attrs") or {}
         return SEG_PREWARM if attrs.get("cause") == "prewarm" else SEG_COMPILE
@@ -126,6 +167,21 @@ def segment_of(span: dict) -> str:
         attrs = span.get("attrs") or {}
         op = attrs.get("op") or name.split(".", 1)[0]
         return COMPUTE_PREFIX + str(op)
+    if name in _ROOT_NAMES or name.startswith("query:"):
+        return SEG_OTHER
+    if name.startswith(_DISPATCH_PREFIXES):
+        return SEG_DISPATCH
+    if name == "fetch.crossing":
+        return SEG_FETCH_WAIT
+    for prefix, head in _OPERATOR_WORK:
+        if name.startswith(prefix):
+            if enclosing and enclosing.startswith(COMPUTE_PREFIX):
+                return enclosing
+            return COMPUTE_PREFIX + head
+    owner, dot, what = name.partition(".")
+    if dot and (owner.endswith("Exec") or what == "pull"):
+        # the profiler sink's <Exec>.pull and <Exec>.<metric> ranges
+        return COMPUTE_PREFIX + owner
     return SEG_OTHER
 
 
@@ -193,7 +249,9 @@ def extract_critical_path(spans: Sequence[dict],
     root = by_id[root["spanId"]]
     seg_ns: Dict[str, int] = {}
 
-    def attribute(span: dict, windows: List[List[int]]) -> None:
+    def attribute(span: dict, windows: List[List[int]],
+                  enclosing: Optional[str] = None) -> None:
+        own = segment_of(span, enclosing)
         kids = children.get(span["spanId"], ())
         kid_windows: Dict[object, List[List[int]]] = {}
         for lo, hi in windows:
@@ -203,8 +261,7 @@ def extract_critical_path(spans: Sequence[dict],
                 if k1 > k0:
                     entries.append((k0, k1, k))
             if not entries:
-                seg = segment_of(span)
-                seg_ns[seg] = seg_ns.get(seg, 0) + (hi - lo)
+                seg_ns[own] = seg_ns.get(own, 0) + (hi - lo)
                 continue
             bounds = {lo, hi}
             for k0, k1, _ in entries:
@@ -214,8 +271,7 @@ def extract_critical_path(spans: Sequence[dict],
             for a, b in zip(bounds, bounds[1:]):
                 covering = [e for e in entries if e[0] <= a and e[1] >= b]
                 if not covering:
-                    seg = segment_of(span)
-                    seg_ns[seg] = seg_ns.get(seg, 0) + (b - a)
+                    seg_ns[own] = seg_ns.get(own, 0) + (b - a)
                     continue
                 # ends-last = the longest dependency chain to completion
                 owner = max(covering, key=lambda e: (e[1], e[2]["spanId"]))
@@ -225,7 +281,7 @@ def extract_critical_path(spans: Sequence[dict],
                 else:
                     wins.append([a, b])
         for kid_id, wins in kid_windows.items():
-            attribute(by_id[kid_id], wins)
+            attribute(by_id[kid_id], wins, own)
 
     attribute(root, [[root["_t0"], root["_t1"]]])
 
@@ -243,7 +299,6 @@ def extract_critical_path(spans: Sequence[dict],
 # ---------------------------------------------------------------------------
 
 SEGMENT_FAMILY = "tpu_latency_segment_seconds_total"
-EXTRACT_FAMILY = "tpu_latency_extract_seconds_total"
 
 #: 4 pool tenants x ~40 segments (compute:<Kind> fan-out) exceeds the
 #: registry's 64-series default; a bigger explicit cap keeps every real
@@ -281,13 +336,9 @@ def record_query_latency(tracer, tenant: str, error: Optional[BaseException]
     fam = _segment_counter()
     for seg, sec in res["segments"].items():
         fam.labels(segment=seg, tenant=tenant).inc(sec)
+    # the observatory's own overhead rides the record (the --slo gate
+    # holds it under 5% of query wall)
     extract_s = time.perf_counter() - t_start
-    from .metrics import MetricsRegistry
-    MetricsRegistry.get().counter(
-        EXTRACT_FAMILY,
-        "Seconds spent extracting critical paths — the observatory's own "
-        "overhead, guarded < 5% of query wall by the --slo gate.").inc(
-            extract_s)
     # sink 3: the SLO observatory (burn window, tail reservoir, ledger).
     # Cancel/deadline accounting: a client cancel is excluded from the
     # burn window (the engine didn't miss), a blown deadline counts BAD

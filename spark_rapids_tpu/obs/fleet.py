@@ -394,9 +394,6 @@ class FleetAggregator:
             "per-peer rollup of allowlisted families scraped from "
             "each peer's /metrics", ("peer", "name"),
             max_series=self.max_peers * (len(ROLLUP_FAMILIES) + 1))
-        scrapes_c = m.counter("tpu_fleet_scrapes_total",
-                              "peer scrape attempts by outcome",
-                              ("status",))
         peers: Dict[str, Dict[str, Any]] = {}
         skipped = 0
         for i, p in enumerate(live):
@@ -422,10 +419,8 @@ class FleetAggregator:
                         p.host, obs_port, "/healthz", self.timeout_s))
                     entry["health"] = health.get("status")
                     entry["scraped"] = True
-                    scrapes_c.labels(status="ok").inc()
                 except Exception as ex:
                     entry["error"] = repr(ex)
-                    scrapes_c.labels(status="error").inc()
             up_g.labels(peer=p.executor_id).set(
                 1 if entry["scraped"] else 0)
             peers[p.executor_id] = entry

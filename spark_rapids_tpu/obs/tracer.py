@@ -28,12 +28,19 @@ profiler's own clock (``spark.rapids.sql.profile.traceAnnotations``:
 one ``jax.profiler.TraceAnnotation`` per span, so a device trace shows
 engine phases, operators, dispatch and fetch beside the device's
 operations).  ``open_range`` is the engine's only ``TraceAnnotation``.
+
+While the profiler sink is on, every range is also a frame of the
+``HostLedger``: each top-level query's wall time summed by segment
+(``critpath.segment_of``) as its ranges close, the last queries' records
+kept in memory (``host_ledger().records()``).
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import itertools
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -148,6 +155,8 @@ class QueryTrace:
         # grafted under this trace's fetch spans
         from .fleet import new_trace_id
         self.trace_id = new_trace_id()
+        # the id the session gave the query (the event log's sql_id)
+        self.sql_id: Optional[int] = None
         self.remote_spans_merged = 0
         self.remote_spans_lost = 0
         self.root_id = self.start("query", QUERY)
@@ -589,21 +598,193 @@ def set_trace_annotations(enabled: bool) -> None:
     ANNOTATIONS_ON = bool(enabled)
 
 
-def open_range(name: str):
+def open_range(name: str, query_id: Optional[str] = None):
     """An entered range of `name` on the profiler's clock, or None with
     the switch off.  A range is thread-scoped: close it (close_range)
-    on the opening thread and never across a generator's ``yield``."""
+    on the opening thread and never across a generator's ``yield``.
+    Every range is also a frame of the host ledger (below), under the
+    same name; `query_id` marks a query's root range."""
     if not ANNOTATIONS_ON:
         return None
     from jax.profiler import TraceAnnotation
     ann = TraceAnnotation(name)
     ann.__enter__()
-    return ann
+    return _LEDGER.enter(name, ann, query_id, time.perf_counter_ns())
 
 
-def close_range(ann) -> None:
-    if ann is not None:
-        ann.__exit__(None, None, None)
+def close_range(frame) -> Optional[Dict[str, Any]]:
+    """Close a range ``open_range`` returned.  The record of the query
+    when the range was a top-level query's root, else None."""
+    if frame is None:
+        return None
+    return _LEDGER.leave(frame, time.perf_counter_ns())
+
+
+# ---------------------------------------------------------------------------
+# the host ledger: each query's wall time by segment, summed as its
+# ranges close
+# ---------------------------------------------------------------------------
+
+#: closed queries kept in memory; the oldest fall off
+LEDGER_RECORDS = 8192
+
+#: the bucket of ranges closed on a thread that holds no query root
+OFF_THREAD = "off_thread"
+
+
+class _Frame:
+    """One open range: its ledger name, its open on the host clock, the
+    nanoseconds its closed children covered, and the query record that
+    was open on its thread with the segment its self time goes under
+    (both None off a query's thread)."""
+
+    __slots__ = ("name", "ann", "t0_ns", "child_ns", "record", "seg")
+
+    def __init__(self, name, ann, t0_ns, record, seg):
+        self.name = name
+        self.ann = ann      # None once closed
+        self.t0_ns = t0_ns
+        self.child_ns = 0
+        self.record = record
+        self.seg = seg
+
+
+class _LedgerThread(threading.local):
+    stack: Optional[List[_Frame]] = None
+    root: Optional[_Frame] = None   # the open top-level query's frame
+
+
+class HostLedger:
+    """Self time by segment of every top-level query, from the ranges of
+    the profiler sink (``open_range`` / ``close_range`` feed it and read
+    its clock; nothing else does).  A frame's self time is its duration
+    less its children's (they nest on one thread, so no second is booked
+    twice), booked under ``critpath.segment_of`` of its name into the
+    record of the query open on its thread; the segments of a record sum
+    to its wall exactly.  A nested query (a scalar subquery's execute)
+    books into the outer record.  Ranges on a thread without a root are
+    summed under ``off_thread``, outside every record's partition, and
+    handed to the next record that closes as ``off_thread_ns``."""
+
+    def __init__(self, max_records: int = LEDGER_RECORDS):
+        self._ring = collections.deque(maxlen=max_records)
+        self._tls = _LedgerThread()
+        self._segments: Dict[str, str] = {}    # span name -> segment
+        # guards the ring, the name cache and _off_thread_ns: taken when
+        # a root closes, on a new name and off a query's thread, never
+        # for a frame of a query's own
+        self._lock = threading.Lock()
+        self._off_thread_ns = 0
+
+    def records(self) -> List[Dict[str, Any]]:
+        """The closed queries' records, oldest first: ``{id, wall_ns,
+        segments: {segment: self_ns}, spans: {name: [count,
+        inclusive_ns]}, off_thread_ns}``."""
+        return list(self._ring)
+
+    def enter(self, name, ann, query_id, now_ns) -> _Frame:
+        if query_id is not None:
+            name = "query"      # a root's key; the id is the record's
+        tls = self._tls
+        stack = tls.stack
+        if stack is None:
+            stack = tls.stack = []
+        while stack and stack[-1].ann is None:
+            stack.pop()     # closed from another thread
+        root = tls.root
+        record = None if root is None else root.record
+        seg = None
+        if record is not None or query_id is not None:
+            seg = self._segments.get(name)
+            if seg is None:
+                seg = self._place(name, stack)
+        frame = _Frame(name, ann, now_ns, record, seg)
+        if query_id is not None and root is None:
+            frame.record = {"id": query_id, "wall_ns": None,
+                            "segments": {}, "spans": {},
+                            "off_thread_ns": 0}
+            tls.root = frame
+        stack.append(frame)
+        return frame
+
+    def _place(self, name, stack) -> str:
+        """The segment of a name seen for the first time.  It is cached
+        where the name alone decides it; a span an operator opens for
+        its own work (``join.build``, ``scan.upload``) goes under the
+        operator's segment, whichever is around it this time."""
+        from .critpath import COMPUTE_PREFIX, segment_of
+        span = {"name": name}
+        seg = segment_of(span)
+        if segment_of(span, COMPUTE_PREFIX) != seg:
+            return segment_of(span, stack[-1].seg if stack else None)
+        with self._lock:
+            self._segments[name] = seg
+        return seg
+
+    def leave(self, frame: _Frame, now_ns: int):
+        if frame.ann is None:
+            return None     # an unwind closed it already
+        stack = self._tls.stack
+        if stack and stack[-1] is frame:
+            return self._close_top(stack, now_ns)
+        if not stack or frame not in stack:
+            # closed on another thread than it opened on: no duration
+            # this thread's stack can place.  The frame stays on its own
+            # thread's stack, closed; _close_top and enter drop it there
+            frame.ann.__exit__(None, None, None)
+            frame.ann = None
+            return None
+        # a range left open below this one (an exception, a suspended
+        # generator) ends here with it
+        while stack[-1] is not frame:
+            self._close_top(stack, now_ns)
+        return self._close_top(stack, now_ns)
+
+    def _close_top(self, stack, now_ns):
+        frame = stack.pop()
+        if frame.ann is None:
+            # another thread closed it: nothing to book
+            if frame is self._tls.root:
+                self._tls.root = None
+            return None
+        frame.ann.__exit__(None, None, None)
+        frame.ann = None
+        dur = now_ns - frame.t0_ns
+        if stack:
+            stack[-1].child_ns += dur
+        self_ns = dur - frame.child_ns
+        record = frame.record
+        if record is None:
+            with self._lock:
+                self._off_thread_ns += self_ns
+            return None
+        name = frame.name
+        seg = frame.seg
+        segments = record["segments"]
+        segments[seg] = segments.get(seg, 0) + self_ns
+        span = record["spans"].get(name)
+        if span is None:
+            record["spans"][name] = [1, dur]
+        else:
+            span[0] += 1
+            span[1] += dur
+        tls = self._tls
+        if frame is not tls.root:
+            return None
+        tls.root = None
+        record["wall_ns"] = dur
+        with self._lock:
+            record["off_thread_ns"] = self._off_thread_ns
+            self._off_thread_ns = 0
+            self._ring.append(record)
+        return record
+
+
+_LEDGER = HostLedger()
+
+
+def host_ledger() -> HostLedger:
+    return _LEDGER
 
 
 def annotate_pulls(name: str, inner):
@@ -617,13 +798,13 @@ def annotate_pulls(name: str, inner):
     def gen():
         try:
             while True:
-                ann = open_range(name)
+                frame = open_range(name)
                 try:
                     b = next(it)
                 except StopIteration:
                     return
                 finally:
-                    close_range(ann)
+                    close_range(frame)
                 yield b
         finally:
             # an abandoned pull (early-exit limits) closes the operator's
@@ -635,26 +816,50 @@ def annotate_pulls(name: str, inner):
     return gen()
 
 
-@contextlib.contextmanager
-def trace_span(name: str, kind: str = SPAN, **attrs):
+class trace_span:
     """Span context manager: a profiler range when annotations are on,
     a recorded span when a trace is active, and the live view's phase
     feed for phase spans — with all of them off, an inert handle.
-    Yields a handle with ``.set(**attrs)``."""
-    ann = open_range(name)
-    try:
-        tr = active_tracer()
-        with (_NULL_SPAN if tr is None
-              else tr.span(name, kind=kind, **attrs)) as h:
+    Enters to a handle with ``.set(**attrs)``.  (A class, not a
+    generator: a span site with both sinks off costs half as much.)"""
+
+    __slots__ = ("name", "kind", "attrs", "_frame", "_recorded")
+
+    def __init__(self, name: str, kind: str = SPAN, **attrs):
+        self.name = name
+        self.kind = kind
+        self.attrs = attrs
+
+    def __enter__(self):
+        name = self.name
+        self._frame = open_range(name)
+        self._recorded = None
+        try:
+            tr = active_tracer()
+            if tr is None:
+                handle = _SpanHandle_NULL
+            else:
+                recorded = tr.span(name, kind=self.kind, **self.attrs)
+                handle = recorded.__enter__()
+                self._recorded = recorded
             # phase spans and the admission wait are the only names
             # that move a query's live-view phase; called outside the
             # span lock (the hook takes the tracker's own lock)
-            if kind == PHASE or name == "admission.wait":
+            if self.kind == PHASE or name == "admission.wait":
                 from . import progress as _progress
-                _progress.note_span_open(name, kind)
-            yield h
-    finally:
-        close_range(ann)
+                _progress.note_span_open(name, self.kind)
+        except BaseException:
+            self.__exit__(*sys.exc_info())
+            raise
+        return handle
+
+    def __exit__(self, exc_type, exc, tb):
+        try:
+            if self._recorded is not None:
+                self._recorded.__exit__(exc_type, exc, tb)
+        finally:
+            close_range(self._frame)
+        return False
 
 
 class _NullHandle:
@@ -668,4 +873,3 @@ class _NullHandle:
 
 
 _SpanHandle_NULL = _NullHandle()
-_NULL_SPAN = contextlib.nullcontext(_SpanHandle_NULL)

@@ -48,10 +48,9 @@ def discover_devices(timeout_s: Optional[float] = None) -> List:
 
     On timeout the daemon probe thread is left behind (there is no safe
     way to interrupt a hung PJRT client), ``tpu_device_probe_failures_
-    total`` increments, a tracer event is emitted, and
-    ``DeviceDiscoveryTimeout`` raises instead of hanging the process."""
+    total`` increments and ``DeviceDiscoveryTimeout`` raises instead of
+    hanging the process."""
     from ..obs import metrics as m
-    from ..obs.tracer import trace_event
     timeout_s = _probe_timeout_s() if timeout_s is None else timeout_s
     result: List = []
     error: List[BaseException] = []
@@ -73,7 +72,6 @@ def discover_devices(timeout_s: Optional[float] = None) -> List:
     if t.is_alive():
         fail.inc()
         ok.set(0)
-        trace_event("mesh.probe_timeout", timeout_s=timeout_s)
         raise DeviceDiscoveryTimeout(
             f"device discovery exceeded {timeout_s:g}s (unreachable "
             f"chip); set "
@@ -81,7 +79,6 @@ def discover_devices(timeout_s: Optional[float] = None) -> List:
     if error:
         fail.inc()
         ok.set(0)
-        trace_event("mesh.probe_error", error=repr(error[0]))
         raise error[0]
     ok.set(1)
     return result

@@ -166,10 +166,6 @@ class AQEShuffleReadExec(Exec):
             # under this exchange undershot and the catalog now holds
             # TRUNCATED blocks.  Heal locally — drop
             # the bad shuffle and rewrite it exactly, no speculation.
-            from ..obs import metrics as m
-            m.counter("tpu_shuffle_map_rewrites_total",
-                      "map stages rewritten after a speculation guard "
-                      "failed under the exchange's private context").inc()
             with self.exchange._write_lock:
                 sid = self.exchange._shuffle_id
                 self.exchange._shuffle_id = None
