@@ -14,8 +14,9 @@ output at the buckets `tpch_q3_1chip`'s joins settle in: 1,048,576 /
 1,048,576 for the first, 1,048,576 / 262,144 for the second; the same
 shares of another lineitem capacity where one is asked for).
 Prints one JSON object a program: the build counters
-(`ops/carry.lane_move_counts`, `join_cols_gathered` among them), the
-compile seconds, the count of `sort(`, `gather(`, `scatter(` and `while(`
+(`ops/carry.lane_move_counts`, `join_cols_gathered` among them), which sides come up masked (PR 36:
+`HashJoinExec.masked_sources`; the keep flags are then one more bool lane
+of the program, at the side's capacity), the compile seconds, the count of `sort(`, `gather(`, `scatter(` and `while(`
 in the compiled text, and the compiler's memory figures (which are no guide
 to the allocator's peak: PERF.md, PR 31).  A compile is not a chip run: no
 time here is a device time.
@@ -81,23 +82,29 @@ def main() -> int:
                 node.output_names, node.output_types, cap, chip, False)
         probe, build = batch(probe_node, probe_cap), batch(build_node,
                                                            build_cap)
-        count = q1_tool.compile_one(
-            f"HashJoinExec.count ({name})",
-            lambda b, p: join._count(jnp, b, p, need_matched=False),
-            build, probe)
-        print(json.dumps({**count, "probe_capacity": probe_cap,
-                          "build_capacity": build_cap}), flush=True)
 
         def lane(dtype, n):
             return jax.ShapeDtypeStruct((n,), dtype, sharding=chip)
+        # the sides the plan pairs with a filter bring their keep flags
+        masked = [s is not None for s in join.masked_sources()]
+        pkeep = lane(np.bool_, probe_cap) if masked[0] else None
+        bkeep = lane(np.bool_, build_cap) if masked[1] else None
+        count = q1_tool.compile_one(
+            f"HashJoinExec.count ({name})",
+            lambda b, p, pk, bk: join._count(jnp, b, p, False, pk, bk),
+            build, probe, pkeep, bkeep)
+        print(json.dumps({**count, "probe_capacity": probe_cap,
+                          "build_capacity": build_cap,
+                          "probe_masked": masked[0],
+                          "build_masked": masked[1]}), flush=True)
         caps = (out_cap, (0,) * len(probe.columns),
                 (0,) * len(build.columns))
         expand = q1_tool.compile_one(
             f"HashJoinExec.expand ({name})",
-            lambda b, p, o, l, c: join._expand_sized(
-                jnp, b, p, o, l, c, caps),
+            lambda b, p, o, l, c, pk: join._expand_sized(
+                jnp, b, p, o, l, c, caps, pk),
             build, probe, lane(np.int32, build_cap),
-            lane(np.int32, probe_cap), lane(np.int64, probe_cap))
+            lane(np.int32, probe_cap), lane(np.int64, probe_cap), pkeep)
         print(json.dumps({**expand, "out_capacity": out_cap}), flush=True)
     return 0
 

@@ -983,14 +983,16 @@ class TpuHashAggregateExec(Exec):
         `monotonically_increasing_id`) would see the rows where they lay
         and not where compaction put them, so such an aggregate takes
         the compacted batch as well."""
-        from .basic import FilterExec, _exprs_need_rowpos
-        child = self.children[0]
-        if self.mode in (PARTIAL, COMPLETE) and self.placement == TPU and \
-                isinstance(child, FilterExec) and child.placement == TPU \
-                and child.rebucket_cap is None and not _exprs_need_rowpos(
-                    self._bound_grouping + self._update_inputs):
-            return child
+        from .filter_common import masked_child
+        if self.mode in (PARTIAL, COMPLETE):
+            return masked_child(self, self.children[0],
+                                self._bound_grouping + self._update_inputs)
         return None
+
+    def masked_sources(self) -> tuple:
+        """`masked_source()` as the seam every masked consumer declares
+        (`filter_common.check_paired`)."""
+        return (self.masked_source(),)
 
     # --- device kernels -----------------------------------------------------
     def _update_batch(self, xp, batch: Batch, keep=None) -> Batch:

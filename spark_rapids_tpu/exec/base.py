@@ -467,11 +467,10 @@ OP_TIME = "opTime"
 
 
 def _wrap_execute_partition(fn):
-    """Route every operator's execute_partition (and `FilterExec`'s
-    second iterator, `execute_masked`, which also takes its consumer)
-    through the tracer's two sinks and the progress observatory: with a
-    tracer installed the
-    produced iterator is wrapped in a per-(operator, partition) span
+    """Route every operator's execute_partition (and the second
+    iterator of `FilterExec` and `ProjectExec`, `execute_masked`, which
+    also takes its consumer) through the tracer's two sinks and the
+    progress observatory: with a tracer installed the produced iterator is wrapped in a per-(operator, partition) span
     recording batches/rows/bytes and the exception on failure; with
     trace annotations on, each pull of it is a profiler range
     ``<ExecClass>.pull``; with a progress handle bound to the thread

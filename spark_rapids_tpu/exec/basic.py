@@ -180,7 +180,15 @@ class LocalScanExec(Exec):
 
 
 class ProjectExec(Exec):
-    """Columnar projection (ref GpuProjectExec, basicPhysicalOperators.scala:140)."""
+    """Columnar projection (ref GpuProjectExec, basicPhysicalOperators.scala:140).
+
+    A projection whose every output is a column reference, aliased or
+    not (`_selection`: what a planner's column pruning leaves between a
+    filter and a join), forwards the filter's masked batches to the join
+    (`execute_masked`): the selected columns as they lay, the keep flags
+    and their count, and no program at all.  A projection that computes
+    anything is NOT forwarded through: its expressions would run over
+    rows the filter dropped, so it takes the compacted batch, as ever."""
 
     def __init__(self, exprs: Sequence[Expression], child: Exec):
         super().__init__([child])
@@ -255,6 +263,44 @@ class ProjectExec(Exec):
     def _needs_rowpos(self):
         return _exprs_need_rowpos(self._bound)
 
+    @functools.cached_property
+    def _selection(self) -> Optional[tuple]:
+        """The child's ordinal behind every output where each is a bare
+        column reference (an alias only renames), else None."""
+        from ..expr.core import Alias, BoundReference
+        refs = [b.child if isinstance(b, Alias) else b for b in self._bound]
+        if all(isinstance(r, BoundReference) for r in refs):
+            return tuple(r.ordinal for r in refs)
+        return None
+
+    def can_mask(self) -> bool:
+        """True where this projection hands up its child's mask to the
+        consumer above (`filter_common.masked_child`)."""
+        return self.masked_sources()[0] is not None
+
+    def masked_sources(self) -> tuple:
+        """(the child this projection reads masked, or None): a bare
+        selection forwards what a masking child hands up."""
+        if self._selection is None:
+            return (None,)
+        from .filter_common import masked_child
+        return (masked_child(self, self.children[0]),)
+
+    @_wrap_execute_partition
+    def execute_masked(self, pid, ctx, consumer):
+        """The child's masked batches with this selection's columns, for
+        the `consumer` the plan paired with it (`filter_common`); nothing
+        is evaluated, on a dropped row or any other."""
+        from .filter_common import MaskedBatch, check_paired
+        check_paired(self, consumer)
+        names = self.output_names
+        for m in self.children[0].execute_masked(pid, ctx, self):
+            self.metrics[NUM_OUTPUT_ROWS] += m.num_rows
+            self.metrics[NUM_OUTPUT_BATCHES] += 1
+            yield MaskedBatch(
+                DeviceBatch([m.batch.columns[i] for i in self._selection],
+                            m.batch.num_rows, names), m.keep, m.num_rows)
+
     def execute_partition(self, pid, ctx) -> Iterator[Batch]:
         xp = self.xp
         offset = 0
@@ -294,13 +340,15 @@ class FilterExec(Exec):
     The plan says what becomes of the predicate's keep flags
     (`exec/filter_common`):
 
-      - `execute_partition`, for every consumer but one: **compaction**.
-        Static shapes: a stable partition on the keep flag moves the
-        surviving rows to the front, every lane by a sort pass;
-        `num_rows` shrinks to the survivor count.
-      - `execute_masked`, for the update side of the TPU aggregate
-        directly above (`TpuHashAggregateExec.masked_source` pairs the
-        two, and nothing else pulls it): **the mask alone**.  The program
+      - `execute_partition`, for every consumer that reads rows by
+        position: **compaction**.  Static shapes: a stable partition on
+        the keep flag moves the surviving rows to the front, every lane
+        by a sort pass; `num_rows` shrinks to the survivor count.
+      - `execute_masked`, for a consumer that keeps dead rows apart by
+        flags: the update side of the TPU aggregate directly above, or
+        either side of a TPU `HashJoinExec` directly above or above a
+        bare selection (the consumer's `masked_sources()` pairs them,
+        and nothing else pulls it): **the mask alone**.  The program
         `jit_FilterExec.mask` evaluates the predicate and hands up the
         keep flags and their count; the input batch goes up as it lay.
         No sort pass, no prefix sum, no copy of a lane."""
@@ -392,8 +440,8 @@ class FilterExec(Exec):
         m.counter("tpu_filter_batches_total",
                   "batches a FilterExec answered, by what became of the "
                   "keep flags: compact (the kept rows moved to the "
-                  "front), mask (the flags handed up to the aggregate "
-                  "above, no lane moved)",
+                  "front), mask (the flags handed up to the aggregate or "
+                  "the join above, no lane moved)",
                   ("path",)).labels(path=path).inc()
 
     def execute_partition(self, pid, ctx) -> Iterator[Batch]:
@@ -418,20 +466,21 @@ class FilterExec(Exec):
             self._note_output("compact", out.num_rows)
             yield out
 
+    def can_mask(self) -> bool:
+        """True where this filter hands up its mask to a consumer that
+        reads one (`filter_common.masked_child`): on the TPU engine and
+        `rebucket_cap` not armed."""
+        return self.placement == TPU and self.rebucket_cap is None
+
     @_wrap_execute_partition
     def execute_masked(self, pid, ctx, consumer):
         """Each input batch as it lay, with its keep flags
         (`filter_common.MaskedBatch`), for `consumer` alone: the
-        aggregate whose `masked_source` this filter is.  Anything else
-        is refused before a batch is made, so a masked batch reaches
-        nothing that does not read the mask."""
-        paired = getattr(consumer, "masked_source", None)
-        if paired is None or paired() is not self:
-            raise RuntimeError(
-                f"{type(consumer).__name__} is not the aggregate paired "
-                "with this filter: only TpuHashAggregateExec."
-                "masked_source() may pull execute_masked")
-        from .filter_common import MaskedBatch
+        aggregate, join or bare selection whose `masked_sources()` names
+        this filter.  Anything else is refused before a batch is made,
+        so a masked batch reaches nothing that does not read the mask."""
+        from .filter_common import MaskedBatch, check_paired
+        check_paired(self, consumer)
         offset = 0
         for b in self.children[0].execute_partition(pid, ctx):
             with MetricTimer(self.metrics[OP_TIME]):

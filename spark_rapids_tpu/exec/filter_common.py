@@ -12,15 +12,23 @@ by an order, no dynamic shapes, no host sync.  A pass is 79 ms a 32-bit
 word at 33,554,432 slots on the v5e, so this is what a consumer pays for
 that reads rows by position.
 
-**The mask alone** (`MaskedBatch`): a consumer that reduces under a mask
-wherever the rows lie needs none of that.  The update side of a
-`TpuHashAggregateExec` directly above a `FilterExec` is the one such
-consumer (`exec/aggregate.TpuHashAggregateExec.masked_source` is the plan
-seam that pairs them): it pulls `FilterExec.execute_masked`, which hands
-up the input batch as it lay, untouched, with the keep flags and their
-count beside it.  A `MaskedBatch` is no `DeviceBatch` and no pytree: it
-has no columns to read, so an operator that does not know the mask cannot
-take one for a batch.
+**The mask alone** (`MaskedBatch`): a consumer that keeps dead rows apart
+by flags, wherever the rows lie, needs none of that.  Two operators do,
+each directly above a `FilterExec`, both on the TPU engine: the update
+side of a `TpuHashAggregateExec` (it reduces under the mask) and either
+side of a `HashJoinExec` (liveness rides its sorts as a flag).  Such a
+consumer pulls `FilterExec.execute_masked`, which hands up the input batch
+as it lay, untouched, with the keep flags and their count beside it.  A
+`ProjectExec` that only selects columns forwards the masked batch from the
+filter below it to the join above it.  A `MaskedBatch` is no `DeviceBatch`
+and no pytree: it has no columns to read, so an operator that does not
+know the mask cannot take one for a batch.
+
+**The seam** is one: a consumer's `masked_sources()` names, child by
+child, the operator it reads through `execute_masked` (or None: it pulls
+`execute_partition`), from the plan's shape when the partition is pulled.
+`masked_child` is the condition every consumer shares; `check_paired` is
+the refusal every source makes before it hands anything up.
 """
 
 from __future__ import annotations
@@ -64,6 +72,36 @@ class MaskedBatch:
     @property
     def capacity(self) -> int:
         return self.batch.capacity
+
+
+def masked_child(consumer, child, exprs=()):
+    """`child` where `consumer` may read it through `execute_masked`, else
+    None: both are on the TPU engine, `child` hands up a mask as the plan
+    stands (`can_mask`: a filter whose `rebucket_cap` is not armed, for
+    the L018 repair shrinks a COMPACTED output; a bare selection over
+    one), and none of `exprs`, what the consumer evaluates over the
+    child's rows, reads a row's position (`rand`,
+    `monotonically_increasing_id`: it would see the rows where they lay
+    and not where compaction put them)."""
+    from .base import TPU
+    from .basic import _exprs_need_rowpos
+    can_mask = getattr(child, "can_mask", None)
+    if consumer.placement == TPU and can_mask is not None and can_mask() \
+            and not _exprs_need_rowpos(exprs):
+        return child
+    return None
+
+
+def check_paired(source, consumer) -> None:
+    """Refuse, before a batch is made, any `consumer` that pulls
+    `source.execute_masked` and is not paired with it by the plan: a
+    masked batch reaches nothing that does not read the mask."""
+    sources = getattr(consumer, "masked_sources", None)
+    if sources is None or not any(s is source for s in sources()):
+        raise RuntimeError(
+            f"{type(consumer).__name__} is not paired with this "
+            f"{type(source).__name__}: only the consumer whose "
+            "masked_sources() names it may pull execute_masked")
 
 
 def count_kept(xp, keep):

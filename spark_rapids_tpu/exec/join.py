@@ -19,6 +19,12 @@ picks the output's buckets, and dispatches the expand program keyed by
 them.  Parameter sets that leave a join's output in the same buckets run
 the same two programs, so a warm join builds nothing.
 
+Dead rows are kept apart by flags, never by position (`join_kernels`), so
+a `FilterExec` directly under either side, or under a bare selection
+there, compacts nothing: the join pulls its `execute_masked`
+(`HashJoinExec.masked_sources` is the plan seam, `exec/filter_common`)
+and `&`s the keep flags into the liveness lanes it builds anyway.
+
 CpuJoinExec is an independent pyarrow Table.join implementation (CPU
 fallback engine + differential oracle).
 """
@@ -147,6 +153,13 @@ def _gather_pairs(xp, probe: Batch, pidx, pvalid, pchar_caps,
              for c, cc in zip(build.columns, bchar_caps)])
 
 
+def _live(xp, batch: Batch, keep=None):
+    """bool[capacity]: the batch's rows, under a filter's `keep` flags
+    where the batch came up masked."""
+    live = xp.arange(batch.capacity, dtype=np.int32) < batch.num_rows
+    return live if keep is None else live & keep
+
+
 class HashJoinExec(Exec):
     """TPU equi-join; build side is always the right child
     (right joins are planned flipped, like the reference's build-side
@@ -231,26 +244,43 @@ class HashJoinExec(Exec):
                        for a, b in zip(self.left_keys, self.right_keys))
         return f"HashJoin {self.how} on [{ks}]"
 
+    def masked_sources(self) -> tuple:
+        """The plan seam that pairs filters with this join: (probe side,
+        build side), each the child this join reads through
+        `execute_masked` or None.  Read from the plan's shape alone, when
+        the partition is pulled (`filter_common.masked_child`: the child
+        is a `FilterExec`, or a bare selection over one, both on the TPU
+        engine, `rebucket_cap` not armed); a key or a residual condition
+        that reads a row's position takes the compacted batches."""
+        from .filter_common import masked_child
+        cond = () if self._bound_condition is None \
+            else (self._bound_condition,)
+        probe, build = self.children
+        return (masked_child(self, probe, tuple(self.left_keys) + cond),
+                masked_child(self, build, tuple(self.right_keys) + cond))
+
     # --- phase 1: count + sizing -------------------------------------------
     def _count(self, xp, build: Batch, probe: Batch,
-               need_matched: bool = True):
+               need_matched: bool = True, pkeep=None, bkeep=None):
         """(order, lo, counts, sizes, matched): each probe row's run of
         the hash-sorted build order, the output's sizes as ONE int64
         vector (rows, then the span bytes or child rows of every probe and
         build column), and, where a right or full join will emit the
         unmatched build rows (`need_matched`), the build rows some probe
-        row matched, else None."""
+        row matched, else None.  `pkeep` / `bkeep` are a paired filter's
+        flags over the probe / the build (`masked_sources`): a row they
+        drop is no row of its side, wherever it lies."""
         bctx = EvalContext(xp, build)
         pctx = EvalContext(xp, probe)
         bkeys = [k.eval(bctx).col for k in self.right_keys]
         pkeys = [k.eval(pctx).col for k in self.left_keys]
-        plive = pctx.row_mask()
+        plive = _live(xp, probe, pkeep)
         bh, bnull = jk.combined_key_hash(xp, bkeys, build.capacity)
         ph, pnull = jk.combined_key_hash(xp, pkeys, probe.capacity)
         # a null key matches nothing; a probe row that has one is still a
         # row of a left join's output
         order, lo, counts = jk.count_matches(
-            xp, bh, bctx.row_mask() & ~bnull, ph, plive & ~pnull)
+            xp, bh, _live(xp, build, bkeep) & ~bnull, ph, plive & ~pnull)
         outer = self.how in ("left", "full")
         eff = xp.maximum(counts, 1) if outer else counts
         eff = xp.where(plive, eff, 0)
@@ -300,17 +330,23 @@ class HashJoinExec(Exec):
     def _emits_unmatched_build(self) -> bool:
         return self.how in ("right", "full")
 
-    @property
-    def _jit_count(self):
+    def _count_call(self, xp, build, probe, pkeep, bkeep):
         need = self._emits_unmatched_build
-        return process_jit(
-            self._jit_key + ("count",),
-            lambda: lambda b, p: self._count(jnp, b, p, need_matched=need))
+        if xp is np:
+            return self._count(np, build, probe, need, pkeep, bkeep)
+        # the flags are arguments like the batches: the key says which
+        # of them this program takes
+        fn = process_jit(
+            self._jit_key + ("probe_masked",) * (pkeep is not None)
+            + ("build_masked",) * (bkeep is not None) + ("count",),
+            lambda: lambda b, p, pk, bk: self._count(
+                jnp, b, p, need_matched=need, pkeep=pk, bkeep=bk))
+        return fn(build, probe, pkeep, bkeep)
 
     # --- phase 2: expansion -------------------------------------------------
     def _expand(self, xp, build: Batch, probe: Batch, order, lo, counts,
-                out_cap: int, pchar_caps, bchar_caps) -> Batch:
-        plive = xp.arange(probe.capacity, dtype=np.int32) < probe.num_rows
+                out_cap: int, pchar_caps, bchar_caps, pkeep=None) -> Batch:
+        plive = _live(xp, probe, pkeep)
         (pidx, bidx, pair_valid, pvalid, bvalid, total) = jk.expand_pairs(
             xp, order, lo, counts, plive, out_cap, self.how)
         lcols, rcols = _gather_pairs(xp, probe, pidx, pvalid, pchar_caps,
@@ -318,10 +354,13 @@ class HashJoinExec(Exec):
         return DeviceBatch(lcols + rcols, total, self.output_names)
 
     def _expand_sized(self, xp, build: Batch, probe: Batch, order, lo,
-                      counts, caps: tuple) -> Batch:
+                      counts, caps: tuple, pkeep=None) -> Batch:
         """Phase 2 at the buckets `caps` = (out_cap, probe span caps,
         build span caps) that `_sizing_fetch` picked: the pairs, the
-        columns gathered through them, the residual condition."""
+        columns gathered through them, the residual condition.  `pkeep`:
+        a dropped probe row emits nothing, no null-extended row either
+        (the build's flags did their work in the count: a dropped build
+        row is in no probe row's run)."""
         out_cap, pchar_caps, bchar_caps = caps
         if self._bound_condition is not None and self.how == "left":
             # its output never exceeds the sizing bound (eff counts
@@ -329,9 +368,9 @@ class HashJoinExec(Exec):
             # only shrinks)
             return self._expand_left_cond(xp, build, probe, order, lo,
                                           counts, out_cap, pchar_caps,
-                                          bchar_caps)
+                                          bchar_caps, pkeep)
         out = self._expand(xp, build, probe, order, lo, counts,
-                           out_cap, pchar_caps, bchar_caps)
+                           out_cap, pchar_caps, bchar_caps, pkeep)
         if self._bound_condition is not None and self.how == "inner":
             pctx = EvalContext(xp, out)
             out = apply_filter(xp, out, self._bound_condition.eval(pctx),
@@ -339,20 +378,21 @@ class HashJoinExec(Exec):
         return out
 
     def _expand_call(self, xp, build, probe, order, lo, counts,
-                     caps: tuple) -> Batch:
+                     caps: tuple, pkeep=None) -> Batch:
         if xp is np:
             return self._expand_sized(np, build, probe, order, lo, counts,
-                                      caps)
+                                      caps, pkeep)
         fn = process_jit(
-            self._jit_key + ("expand",) + caps,
-            lambda: lambda b, p, o, l, c: self._expand_sized(
-                jnp, b, p, o, l, c, caps))
-        return fn(build, probe, order, lo, counts)
+            self._jit_key + ("probe_masked",) * (pkeep is not None)
+            + ("expand",) + caps,
+            lambda: lambda b, p, o, l, c, pk: self._expand_sized(
+                jnp, b, p, o, l, c, caps, pk))
+        return fn(build, probe, order, lo, counts, pkeep)
 
     # --- conditional left join ---------------------------------------------
     def _expand_left_cond(self, xp, build: Batch, probe: Batch, order, lo,
-                          counts, out_cap: int, pchar_caps, bchar_caps
-                          ) -> Batch:
+                          counts, out_cap: int, pchar_caps, bchar_caps,
+                          pkeep=None) -> Batch:
         """LEFT join with a residual condition, one traced function:
         expand all candidate pairs, evaluate the condition, keep passing
         pairs, and REPAIR probe rows whose candidates all failed — their
@@ -360,7 +400,7 @@ class HashJoinExec(Exec):
         conditional-join semantics; ref GpuHashJoin's post-filter with
         unmatched-row emission, GpuOverrides.scala:3352-3355)."""
         from ..ops.carry import mask_validity
-        plive = xp.arange(probe.capacity, dtype=np.int32) < probe.num_rows
+        plive = _live(xp, probe, pkeep)
         (pidx, bidx, pair_valid, pvalid, bvalid, total) = jk.expand_pairs(
             xp, order, lo, counts, plive, out_cap, "left")
         lcols, rcols = _gather_pairs(xp, probe, pidx, pvalid, pchar_caps,
@@ -399,9 +439,9 @@ class HashJoinExec(Exec):
         return compact(xp, out, keep, self.output_names)
 
     # --- unmatched build rows for right/full --------------------------------
-    def _unmatched_build(self, xp, build: Batch, matched_any) -> Batch:
-        keep = (xp.arange(build.capacity, dtype=np.int32) < build.num_rows) \
-            & ~matched_any
+    def _unmatched_build(self, xp, build: Batch, matched_any,
+                         bkeep=None) -> Batch:
+        keep = _live(xp, build, bkeep) & ~matched_any
         compacted = compact(xp, build, keep, self.children[1].output_names)
         n = compacted.num_rows
         from ..expr.core import EvalContext as EC, all_null_column
@@ -411,19 +451,32 @@ class HashJoinExec(Exec):
         return DeviceBatch(lcols + list(compacted.columns), n,
                            self.output_names)
 
-    def _collect_build(self, pid, ctx) -> Batch:
+    def _collect_build(self, pid, ctx) -> tuple:
         """Materialize the build side as ONE device batch: this
         partition's co-clustered shard when colocated, the whole right
-        side otherwise."""
+        side otherwise.  Returns (the batch, a paired filter's keep flags
+        over it or None).  Where the build comes up masked
+        (`masked_sources`) in ONE batch, as every resident table does, it
+        stays where it lay and the flags go with it; several masked
+        batches are compacted each under its flags and concatenated like
+        any others (`concat_batches` packs live prefixes)."""
         xp = self.xp
         right = self.children[1]
+        source = self.masked_sources()[1]
         build_batches = []
         if self.colocated:
             build_pids = [pid]
         else:
             build_pids = list(range(right.num_partitions))
         for bpid in build_pids:
-            build_batches += list(right.execute_partition(bpid, ctx))
+            build_batches += list(
+                right.execute_partition(bpid, ctx) if source is None
+                else source.execute_masked(bpid, ctx, self))
+        if source is not None:
+            if len(build_batches) == 1:
+                return build_batches[0].batch, build_batches[0].keep
+            build_batches = [self._compact_build(m.batch, m.keep)
+                             for m in build_batches]
         if not build_batches:
             from ..columnar.interop import to_arrow_schema
             schema = to_arrow_schema(right.output_names, right.output_types)
@@ -431,38 +484,47 @@ class HashJoinExec(Exec):
                 {n: pa.array([], type=f.type)
                  for n, f in zip(schema.names, schema)})
             build_batches = [batch_to_device(rb, xp=xp)]
-        return concat_batches(xp, build_batches, right.output_names,
-                              right.output_types) \
-            if len(build_batches) > 1 else build_batches[0]
+        return (concat_batches(xp, build_batches, right.output_names,
+                               right.output_types)
+                if len(build_batches) > 1 else build_batches[0]), None
 
-    def _probe_batch(self, build: Batch, probe: Batch):
+    def _compact_build(self, batch: Batch, keep) -> Batch:
+        """One of several masked build batches, its kept rows moved to
+        the front for the concatenation."""
+        names = self.children[1].output_names
+        if self.xp is np:
+            return compact(np, batch, keep, names)
+        return process_jit(
+            ("HashJoinExec", schema_sig(self.children[1]), "compact_build"),
+            lambda: lambda b, k: compact(jnp, b, k, names))(batch, keep)
+
+    def _probe_batch(self, build: Batch, probe: Batch, pkeep=None,
+                     bkeep=None):
         """One probe batch against the build: (the joined batch, the build
-        rows it matched or None).  The span `join.probe` covers the
-        dispatch of its programs and, inside it, `join.size` the wait for
-        the sizes."""
+        rows it matched or None).  `pkeep` / `bkeep`: the keep flags of
+        the filters the plan paired with either side.  The span
+        `join.probe` covers the dispatch of its programs and, inside it,
+        `join.size` the wait for the sizes."""
         from ..obs import metrics as m
         from ..obs.tracer import trace_span
         xp = self.xp
         with trace_span("join.probe", how=self.how,
                         probe_capacity=int(probe.capacity),
-                        build_capacity=int(build.capacity)) as sp:
-            if xp is np:
-                order, lo, counts, sizes, matched = self._count(
-                    np, build, probe, self._emits_unmatched_build)
-            else:
-                (order, lo, counts, sizes,
-                 matched) = self._jit_count(build, probe)
+                        build_capacity=int(build.capacity),
+                        probe_masked=pkeep is not None) as sp:
+            order, lo, counts, sizes, matched = self._count_call(
+                xp, build, probe, pkeep, bkeep)
             if self.how in ("left_semi", "left_anti"):
-                live = xp.arange(probe.capacity, dtype=np.int32) < \
-                    probe.num_rows
                 hit = counts > 0
-                keep = (hit if self.how == "left_semi" else ~hit) & live
+                keep = (hit if self.how == "left_semi" else ~hit) \
+                    & _live(xp, probe, pkeep)
                 out, path = compact(xp, probe, keep, self.output_names), \
                     "count"
             else:
                 caps = _sizing_fetch(sizes, probe, build)
                 out, path = self._expand_call(xp, build, probe, order, lo,
-                                              counts, caps), "two_phase"
+                                              counts, caps, pkeep), \
+                    "two_phase"
                 sp.set(out_capacity=caps[0])
             sp.set(path=path)
         m.counter("tpu_join_probe_batches_total",
@@ -477,16 +539,28 @@ class HashJoinExec(Exec):
         from ..obs.tracer import trace_span
         xp = self.xp
         with trace_span("join.build") as sp:
-            build = self._collect_build(pid, ctx)
+            build, bkeep = self._collect_build(pid, ctx)
             # (a build that a filter or a join made keeps its row count
-            # on the device: the span does not wait for it)
-            if isinstance(build.num_rows, (int, np.integer)):
+            # on the device: the span does not wait for it; under a mask
+            # the batch's own count is the unfiltered one, and left out)
+            if bkeep is None and isinstance(build.num_rows,
+                                            (int, np.integer)):
                 sp.set(rows=int(build.num_rows))
-            sp.set(capacity=int(build.capacity))
+            sp.set(capacity=int(build.capacity), masked=bkeep is not None)
         matched_acc = None
-        for probe in self.children[0].execute_partition(pid, ctx):
+        # the probe's batches: (batch,), or (batch, keep flags) from the
+        # filter the plan paired with this side, which then compacts
+        # nothing
+        source = self.masked_sources()[0]
+        if source is not None:
+            probes = ((m.batch, m.keep)
+                      for m in source.execute_masked(pid, ctx, self))
+        else:
+            probes = ((b,) for b in
+                      self.children[0].execute_partition(pid, ctx))
+        for probe in probes:
             with MetricTimer(self.metrics[OP_TIME]):
-                out, matched = self._probe_batch(build, probe)
+                out, matched = self._probe_batch(build, *probe, bkeep=bkeep)
                 if matched is not None:
                     matched_acc = matched if matched_acc is None else \
                         (matched_acc | matched)
@@ -495,7 +569,7 @@ class HashJoinExec(Exec):
             self.metrics[NUM_OUTPUT_BATCHES] += 1
             yield out
         if matched_acc is not None:
-            out = self._unmatched_build(xp, build, matched_acc)
+            out = self._unmatched_build(xp, build, matched_acc, bkeep)
             if int(out.num_rows):
                 yield out
 

@@ -3,10 +3,11 @@
 fault, a stall or a probe.
 
 A filter is pulled through one of two iterators (`exec/basic.py`):
-`execute_partition` by every consumer but one, `execute_masked` by the
-update side of the TPU aggregate directly above it, which passes itself.
-Arming one of them alone misses the queries that take the other, so both
-are replaced together."""
+`execute_partition` by every consumer that reads rows by position,
+`execute_masked` by the consumer the plan paired with it (the update side
+of a TPU aggregate, a TPU hash join, or the bare selection under one),
+which passes itself.  Arming one of them alone misses the queries that
+take the other, so both are replaced together."""
 
 from ..exec.base import _wrap_execute_partition
 from ..exec.basic import FilterExec
@@ -18,8 +19,8 @@ _RAW_MASKED = FilterExec.execute_masked.__wrapped__
 def raw_filter_iterator(self, pid, ctx, *consumer):
     """The filter's own iterator, for a stand-in that does its deed and
     then lets the batches through: the masked one where the paired
-    aggregate pulls (`consumer` is that aggregate), else the compacting
-    one."""
+    consumer pulls (`consumer` is that aggregate, join or selection),
+    else the compacting one."""
     if consumer:
         return _RAW_MASKED(self, pid, ctx, *consumer)
     return _RAW_COMPACTED(self, pid, ctx)

@@ -1,6 +1,8 @@
 """A `FilterExec` directly under the update side of a TPU aggregate hands
 up its keep flags and moves no lane (`FilterExec.execute_masked`,
-`TpuHashAggregateExec.masked_source`); under anything else it compacts.
+`TpuHashAggregateExec.masked_source`); under a consumer that reads rows by
+position it compacts.  (The other consumer that reads a mask, either side
+of a `HashJoinExec`, is `tests/test_masked_join.py`'s.)
 
 The referees: the same query with the pairing switched off (the filter
 compacts, as it did before), and the NumPy engine
@@ -246,19 +248,11 @@ def _small(session, partitions=1):
         num_partitions=partitions)
 
 
-def _dim(session):
-    return session.create_dataframe(pa.table({
-        "k2": pa.array(np.arange(9, dtype=np.int64)),
-        "w": pa.array(np.arange(9, dtype=np.int64) * 3)}))
-
-
 CONSUMERS = {
     "project": lambda s: _small(s).filter(col("x") > lit(100))
     .select((col("x") + lit(1)).alias("y")),
     "sort": lambda s: _small(s).filter(col("x") > lit(100))
     .order_by(col("x")),
-    "join": lambda s: _small(s).filter(col("x") > lit(100))
-    .join(_dim(s), col("k") == col("k2")),
     "limit": lambda s: _small(s).filter(col("x") > lit(100)).limit(5),
     "fetch": lambda s: _small(s).filter(col("x") > lit(100)),
     "project_under_aggregate": lambda s: _small(s)
@@ -317,6 +311,27 @@ def _filter_then_sum(session):
             .agg(F.sum(col("x")).alias("s")))
 
 
+@pytest.mark.parametrize("selection", [
+    lambda df: df.select(col("x"), col("k")),
+    lambda df: df.select(col("k").alias("key"), col("x").alias("x"))
+    .select(col("key").alias("k"), col("x"))])
+def test_a_bare_selection_between_forwards_the_flags(selection):
+    """What a planner's column pruning leaves between the two: the
+    selection evaluates nothing, so it hands the filter's flags on (a
+    project that computes does not: `CONSUMERS`)."""
+    query = lambda s: (selection(  # noqa: E731
+        _small(s).filter(col("x") > lit(100)))
+        .group_by(col("k")).agg(F.sum(col("x")).alias("s")))
+    session = _session()
+    masked0, compact0 = _counter("mask"), _counter("compact")
+    got = query(session).collect()
+    aggregate, project = _paired(session)
+    assert isinstance(project, ProjectExec)
+    assert aggregate.masked_source() is project
+    assert (_counter("mask"), _counter("compact")) == (masked0 + 1, compact0)
+    assert_tables_equal(query(_session(False)).collect(), got)
+
+
 def test_an_armed_rebucket_cap_compacts():
     """The L018 repair shrinks a COMPACTED output: with it armed the
     filter is no masked source, whatever lies above."""
@@ -367,11 +382,11 @@ def test_only_the_paired_aggregate_may_pull_execute_masked():
     other = TpuHashAggregateExec(aggregate.grouping, [], COMPLETE,
                                  _nodes(session)[-1])
     for consumer in (project, other, None):
-        with pytest.raises(RuntimeError, match="not the aggregate paired"):
+        with pytest.raises(RuntimeError, match="is not paired with"):
             next(iter(filt.execute_masked(0, ctx, consumer)))
     # armed after the pairing: refused too, and execute_partition compacts
     filt.rebucket_cap = 1024
-    with pytest.raises(RuntimeError, match="not the aggregate paired"):
+    with pytest.raises(RuntimeError, match="is not paired with"):
         next(iter(filt.execute_masked(0, ctx, aggregate)))
     filt.rebucket_cap = None
     (m,) = list(filt.execute_masked(0, ctx, aggregate))

@@ -39,7 +39,15 @@ def _frame(columns):
 
 
 @pytest.fixture(scope="module")
-def generated():
+def programs_before():
+    """How many programs the process had built before this module's
+    first query (no other module runs Q3)."""
+    from spark_rapids_tpu.obs.compileprof import CompileObservatory
+    return len(CompileObservatory.get().snapshot()["programs"])
+
+
+@pytest.fixture(scope="module")
+def generated(programs_before):
     columns = gen.generate({"scale_factor": 0.01}, SEED)
     return columns, _frame(columns)
 
@@ -76,6 +84,47 @@ def test_every_operator_but_the_fetch_is_on_the_tpu_engine(generated):
     names = {k for k, _, _ in kinds}
     assert {"FilterExec", "TpuHashAggregateExec", "SortExec",
             "GlobalLimitExec", "LocalScanExec"} <= names
+
+
+def _filter_batches(path):
+    from spark_rapids_tpu.obs import metrics
+    for family in metrics.registry().families():
+        if family.name == "tpu_filter_batches_total":
+            return family.value(path=path)
+    return 0
+
+
+def test_no_filter_moves_a_lane_in_front_of_a_join(generated,
+                                                   programs_before):
+    """All three filters lie under a join (CUSTOMER's under the bare
+    selection of `c_custkey`): each hands up its keep flags, the joins
+    read them, and no filter program holds a sort pass."""
+    from spark_rapids_tpu.obs.compileprof import CompileObservatory
+    obs = CompileObservatory.get()
+    masked0, compact0 = _filter_batches("mask"), _filter_batches("compact")
+    got, want = _ask(generated, {"segment": "BUILDING", "day": 15})
+    assert q3.mismatch(got, want) is None
+    assert _filter_batches("mask") == masked0 + 3
+    assert _filter_batches("compact") == compact0
+    _, df = generated
+    joins = []
+    df.session.last_plan.foreach(
+        lambda e: joins.append(e)
+        if type(e).__name__ == "HashJoinExec" else None)
+    assert [[type(s).__name__ for s in j.masked_sources()]
+            for j in joins] == [["FilterExec", "NoneType"],
+                                ["FilterExec", "ProjectExec"]]
+    filters = [p for p in obs.snapshot()["programs"][programs_before:]
+               if p["exec"] == "FilterExec"]
+    assert len(filters) == 3
+    for p in filters:
+        assert p["filters_masked"] == 1 and p["filters_compacted"] == 0
+        assert p["sort_passes"] == 0 and p["lane_moves_sorted"] == 0
+    built = obs.snapshot()["builds"]
+    got, want = _ask(generated, {"segment": "MACHINERY", "day": 2})
+    assert q3.mismatch(got, want) is None
+    assert obs.snapshot()["builds"] == built
+    assert _filter_batches("mask") == masked0 + 6
 
 
 def test_the_reference_in_float32_is_not_correct(generated):
