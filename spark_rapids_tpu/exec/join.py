@@ -144,9 +144,11 @@ def split_equi_condition(cond: Optional[Expression], left_names, right_names
 def _gather_pairs(xp, probe: Batch, pidx, pvalid, pchar_caps,
                   build: Batch, bidx, bvalid, bchar_caps):
     """Both sides' columns through the (probe, build) pair maps, row by
-    row; a traced program counts them (`join_cols_gathered`)."""
+    row; a traced program counts them (`join_cols_gathered`) and, of
+    them, the strings that go through the span repack
+    (`join_string_cols_gathered`)."""
     if xp is not np:
-        count_join_gathers(len(probe.columns) + len(build.columns))
+        count_join_gathers(list(probe.columns) + list(build.columns))
     return ([gather_column(xp, c, pidx, pvalid, cc)
              for c, cc in zip(probe.columns, pchar_caps)],
             [gather_column(xp, c, bidx, bvalid, cc)
@@ -330,18 +332,47 @@ class HashJoinExec(Exec):
     def _emits_unmatched_build(self) -> bool:
         return self.how in ("right", "full")
 
+    @property
+    def _selects(self) -> bool:
+        """A semi or an anti join: the count decides which probe rows
+        stay, and nothing expands."""
+        return self.how in ("left_semi", "left_anti")
+
     def _count_call(self, xp, build, probe, pkeep, bkeep):
         need = self._emits_unmatched_build
         if xp is np:
             return self._count(np, build, probe, need, pkeep, bkeep)
         # the flags are arguments like the batches: the key says which
-        # of them this program takes
+        # of them this program takes.  A selecting join's count has a
+        # role of its own, so that a trace tells its device time from
+        # the expanding joins' (the program is the same)
         fn = process_jit(
             self._jit_key + ("probe_masked",) * (pkeep is not None)
-            + ("build_masked",) * (bkeep is not None) + ("count",),
+            + ("build_masked",) * (bkeep is not None)
+            + ("semi_count" if self._selects else "count",),
             lambda: lambda b, p, pk, bk: self._count(
                 jnp, b, p, need_matched=need, pkeep=pk, bkeep=bk))
         return fn(build, probe, pkeep, bkeep)
+
+    # --- phase 2 of a semi or an anti join: selection -----------------------
+    def _select(self, xp, probe: Batch, counts, pkeep=None) -> Batch:
+        """The probe's live rows that found a match (`left_semi`) or none
+        (`left_anti`), moved to the front in their order, at the probe's
+        capacity: a probe row is emitted once however many build rows
+        hold its key, and a null key matched nothing."""
+        hit = counts > 0
+        keep = (hit if self.how == "left_semi" else ~hit) \
+            & _live(xp, probe, pkeep)
+        return compact(xp, probe, keep, self.output_names)
+
+    def _select_call(self, xp, probe, counts, pkeep=None) -> Batch:
+        if xp is np:
+            return self._select(np, probe, counts, pkeep)
+        fn = process_jit(
+            self._jit_key + ("probe_masked",) * (pkeep is not None)
+            + ("semi",),
+            lambda: lambda p, c, pk: self._select(jnp, p, c, pk))
+        return fn(probe, counts, pkeep)
 
     # --- phase 2: expansion -------------------------------------------------
     def _expand(self, xp, build: Batch, probe: Batch, order, lo, counts,
@@ -504,22 +535,28 @@ class HashJoinExec(Exec):
         rows it matched or None).  `pkeep` / `bkeep`: the keep flags of
         the filters the plan paired with either side.  The span
         `join.probe` covers the dispatch of its programs and, inside it,
-        `join.size` the wait for the sizes."""
+        `join.size` the wait for the sizes.  A semi or an anti join
+        sizes nothing: its second program (`_select`, role `semi`)
+        compacts the probe's kept rows at the probe's own capacity, and
+        how many it kept stays on the device (`out_capacity` is the
+        probe's; the span waits for no count).  `sorted_slots` is what
+        the count's one sort covers: both sides' capacities, whatever is
+        live in them."""
         from ..obs import metrics as m
         from ..obs.tracer import trace_span
         xp = self.xp
+        sorted_slots = int(probe.capacity) + int(build.capacity)
         with trace_span("join.probe", how=self.how,
                         probe_capacity=int(probe.capacity),
                         build_capacity=int(build.capacity),
+                        sorted_slots=sorted_slots,
                         probe_masked=pkeep is not None) as sp:
             order, lo, counts, sizes, matched = self._count_call(
                 xp, build, probe, pkeep, bkeep)
-            if self.how in ("left_semi", "left_anti"):
-                hit = counts > 0
-                keep = (hit if self.how == "left_semi" else ~hit) \
-                    & _live(xp, probe, pkeep)
-                out, path = compact(xp, probe, keep, self.output_names), \
+            if self._selects:
+                out, path = self._select_call(xp, probe, counts, pkeep), \
                     "count"
+                sp.set(out_capacity=int(probe.capacity))
             else:
                 caps = _sizing_fetch(sizes, probe, build)
                 out, path = self._expand_call(xp, build, probe, order, lo,
@@ -533,6 +570,10 @@ class HashJoinExec(Exec):
                   "the expansion at their buckets), count (semi and anti "
                   "joins size nothing)",
                   ("path",)).labels(path=path).inc()
+        m.counter("tpu_join_sorted_slots_total",
+                  "slots the joins' count programs sorted: at each probe "
+                  "batch the probe's capacity and the build's, live or "
+                  "not").inc(sorted_slots)
         return out, matched
 
     def execute_partition(self, pid, ctx) -> Iterator[Batch]:

@@ -171,7 +171,8 @@ def _exec_kind(key: tuple) -> str:
 #: exec/basic.py, parallel/distributed.py)
 _ROLES = frozenset((
     "update", "merge", "merge_eval", "eval", "complete", "sortkeys",
-    "rowpos", "count", "expand", "unmatched", "mask"))
+    "rowpos", "count", "expand", "unmatched", "mask", "semi",
+    "semi_count"))
 
 
 #: the SPMD steps are keyed by the stage class that traces them

@@ -62,6 +62,7 @@ class _MoveCounts(threading.local):
     strings_aligned = 0     # string columns moved as row-aligned lanes
     strings_gathered = 0    # string columns moved by offsets and gather
     join_gathered = 0       # columns a join gathered through its pair maps
+    join_strings_gathered = 0   # of them, strings (the span repack)
     filters_masked = 0      # filters that hand up their keep flags alone
     filters_compacted = 0   # filters that move the kept rows to the front
 
@@ -88,6 +89,7 @@ def lane_move_counts() -> dict:
             "string_cols_row_aligned": _COUNTS.strings_aligned,
             "string_cols_gathered": _COUNTS.strings_gathered,
             "join_cols_gathered": _COUNTS.join_gathered,
+            "join_string_cols_gathered": _COUNTS.join_strings_gathered,
             "filters_masked": _COUNTS.filters_masked,
             "filters_compacted": _COUNTS.filters_compacted}
 
@@ -101,11 +103,15 @@ def count_ungrouped(reduced: bool) -> None:
         _COUNTS.ungrouped_sorted += 1
 
 
-def count_join_gathers(columns: int) -> None:
+def count_join_gathers(columns: Sequence[DeviceColumn]) -> None:
     """Columns of both sides that a join's expansion gathers row by row
     through its (probe, build) pair maps (`exec/join.HashJoinExec._expand`
-    calls `ops/gather.gather_column`, which no other count here sees)."""
-    _COUNTS.join_gathered += columns
+    calls `ops/gather.gather_column`, which no other count here sees),
+    and how many of them are strings with offsets, whose bytes the gather
+    repacks span by span."""
+    _COUNTS.join_gathered += len(columns)
+    _COUNTS.join_strings_gathered += sum(
+        _is_string(c) and c.offsets is not None for c in columns)
 
 
 def count_filter(masked: bool) -> None:

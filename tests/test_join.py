@@ -475,7 +475,10 @@ def test_join_spans_say_how_the_output_was_sized():
                            how="left_semi"))
     assert not semi["join.size"]
     assert semi["join.probe"][0].attrs["path"] == "count"
-    assert "out_capacity" not in semi["join.probe"][0].attrs
+    # a semi join sizes nothing: its output lies at the probe's capacity
+    # (PR 37 says so; the kept rows' count stays on the device)
+    assert semi["join.probe"][0].attrs["out_capacity"] == 8192
+    assert semi["join.probe"][0].attrs["sorted_slots"] == 8192 + 1024
 
 
 def test_join_programs_count_the_columns_they_gather():
