@@ -11,10 +11,11 @@ The operators are the ones the planner makes for the query over a tiny
 table; each program is lowered for batches of shapes at the capacities
 `tpch_q18_1chip`'s joins run at: the semi join probes with ORDERS
 (capacity / 4) against the subquery's HAVING output at LINEITEM's
-capacity; the join with CUSTOMER probes with the semi join's output
-(capacity / 4) against capacity / 32 slots; the last join probes with a
-1,024-row batch against LINEITEM.  The two expansions are at the 1,024-row
-bucket.  With `lower` nothing is compiled: the build counters alone
+capacity, whose 23-58 live rows its count gathers into the 1,024-row
+bucket (PR 38), and its selection gathers the kept orders into that
+bucket; the join with CUSTOMER probes with that 1,024-row output against
+capacity / 32 slots; the last join probes with a 1,024-row batch against
+LINEITEM.  The two expansions are at the 1,024-row bucket.  With `lower` nothing is compiled: the build counters alone
 (seconds, not minutes).  Prints one JSON object a program, as
 `devtools/compile_q3_programs.py` does.  A compile is not a chip run: no
 time here is a device time.
@@ -85,7 +86,7 @@ def main() -> int:
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
     shapes = (("semi", "orders x HAVING output", rows // 4, rows),
-              ("customer", "semi output x customer", rows // 4, rows // 32),
+              ("customer", "semi output x customer", OUT_BUCKET, rows // 32),
               ("lineitem", "chain x lineitem", OUT_BUCKET, rows))
 
     def lane(dtype, n):
@@ -104,20 +105,25 @@ def main() -> int:
         pkeep = lane(np.bool_, probe_cap) if masked[0] else None
         bkeep = lane(np.bool_, build_cap) if masked[1] else None
         role = "semi_count" if join._selects else "count"
+        narrow = OUT_BUCKET if join._selects else 0
         count = one(
             f"HashJoinExec.{role} ({name})",
-            lambda b, p, pk, bk: join._count(jnp, b, p, False, pk, bk),
+            lambda b, p, pk, bk: join._count_narrowed(jnp, b, p, pk, bk,
+                                                      narrow),
             build, probe, pkeep, bkeep)
         print(json.dumps({**count, "probe_capacity": probe_cap,
                           "build_capacity": build_cap,
+                          "narrowed_capacity": narrow or build_cap,
                           "probe_masked": masked[0],
                           "build_masked": masked[1]}), flush=True)
         if join._selects:
+            caps = (OUT_BUCKET, tuple(0 for _ in probe.columns))
             select = one(
                 f"HashJoinExec.semi ({name})",
-                lambda p, c, pk: join._select(jnp, p, c, pk),
+                lambda p, c, pk: join._select(jnp, p, c, caps, pk),
                 probe, lane(np.int64, probe_cap), pkeep)
-            print(json.dumps(select), flush=True)
+            print(json.dumps({**select, "out_capacity": OUT_BUCKET}),
+                  flush=True)
             continue
         caps = (OUT_BUCKET,
                 tuple(16384 if c.offsets is not None else 0

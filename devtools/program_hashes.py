@@ -13,7 +13,8 @@ another list of programs than the parent does; the other cells' differences
 are printed and expected (`tpch_q3_1chip.q3` in PR 36: its three filters
 hand up a mask to its joins, whose programs take the flags;
 `tpch_q18_1chip.q18` in PR 37, where the parent is given this checkout's
-benchmark files: its semi join's selection became a program).  A cell the
+benchmark files: its semi join's selection became a program, and in PR
+38: its count and its selection work at their live rows' buckets).  A cell the
 parent's `BENCHMARK.json` does not have is run here alone.
 
 The second form prints one cell's list as JSON lines: the operator kind,

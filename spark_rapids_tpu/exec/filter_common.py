@@ -10,7 +10,10 @@ their count.  Static shapes: each row's place is a prefix sum of the
 flags, and every lane goes there by a sort pass (ops/carry.py): no gather
 by an order, no dynamic shapes, no host sync.  A pass is 79 ms a 32-bit
 word at 33,554,432 slots on the v5e, so this is what a consumer pays for
-that reads rows by position.
+that reads rows by position.  **Gathering** (`gather_kept`: a semi or an
+anti join's sides, few kept rows in a large capacity) puts the same rows
+in a smaller bucket by looking each up, a binary search over the flags'
+prefix sum, and moves no lane at the capacity.
 
 **The mask alone** (`MaskedBatch`): a consumer that keeps dead rows apart
 by flags, wherever the rows lie, needs none of that.  Two operators do,
@@ -119,6 +122,19 @@ def compact(xp, batch: DeviceBatch, keep, names):
     _, cols, _ = compact_rows(xp, keep, batch.columns, cap)
     cols = [mask_validity(xp, c, valid_slot) for c in cols]
     return DeviceBatch(cols, new_n, names)
+
+
+def gather_kept(xp, batch: DeviceBatch, keep, out_cap: int, names,
+                char_caps=None) -> DeviceBatch:
+    """`compact`'s rows in `out_cap` slots, for kept rows few against the
+    capacity: each column gathered through `carry.kept_slots` (a
+    prefix sum and a search, no pass at the capacity); no flags ride
+    along and `num_rows` is the kept count.  `char_caps`: a span
+    column's child capacity, 0 for the source's (always enough)."""
+    from ..ops.carry import kept_slots
+    slot, valid, kept = kept_slots(xp, keep, out_cap)
+    return DeviceBatch(gather_batch(xp, batch, slot, valid, kept,
+                                    char_caps).columns, kept, names)
 
 
 def apply_filter(xp, batch: DeviceBatch, pred_value, names):

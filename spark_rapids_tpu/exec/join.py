@@ -19,6 +19,15 @@ picks the output's buckets, and dispatches the expand program keyed by
 them.  Parameter sets that leave a join's output in the same buckets run
 the same two programs, so a warm join builds nothing.
 
+A semi or an anti join (the selecting arm) sizes both its sides by what is
+live in them: it learns its build's live rows (from the host, or one
+counted fetch) and, where their bucket is at most 1/`NARROW_SHARE` of the
+build's capacity, its count gathers them into that bucket before it sorts;
+its selection waits for the kept count like the expansion waits for its
+sizes and hands up a batch in the kept rows' bucket, gathered there under
+the same rule or compacted and sliced above it.  An IN subquery's output
+is a sparse build, and a semi join's output a subset of its probe.
+
 Dead rows are kept apart by flags, never by position (`join_kernels`), so
 a `FilterExec` directly under either side, or under a bare selection
 there, compacts nothing: the join pulls its `execute_masked`
@@ -42,7 +51,7 @@ import pyarrow as pa
 from .. import types as t
 from ..columnar.device import (DEFAULT_CHAR_BUCKETS, DEFAULT_ROW_BUCKETS,
                                DeviceBatch, DeviceColumn, batch_to_arrow,
-                               batch_to_device, bucket_for)
+                               batch_to_device, bucket_for, shrink_batch)
 from ..expr.core import (BoundReference, EvalContext, Expression,
                          bind_expression)
 from ..expr.predicates import And, EqualTo
@@ -53,11 +62,61 @@ from .base import (maybe_sync,  # noqa: F401
                    NUM_OUTPUT_BATCHES, NUM_OUTPUT_ROWS, OP_TIME, Batch,
                    Exec, MetricTimer, process_jit, schema_sig, semantic_sig)
 from .concat import concat_batches
-from .filter_common import apply_filter, compact
+from .filter_common import apply_filter, compact, gather_kept
 from ..ops.scan import cumsum_fast
 
 JOIN_TYPES = ("inner", "left", "right", "full", "left_semi", "left_anti",
               "cross")
+
+#: a selecting join gathers a side into the bucket of its live rows where
+#: that bucket is at most 1/NARROW_SHARE of the side's capacity; above it
+#: the side keeps its capacity.  The search-gather costs about
+#: log2(capacity) gathers an output slot: on the v5e, four of Q18's ORDERS
+#: columns at 8,388,608 slots gather into 262,144 in 62 ms and into
+#: 1,048,576 in 241, against 106 for the compaction at the capacity
+#: (PERF.md §5, `devtools/chip_semi_narrow.py`).  A rule on what the code
+#: observes, not a knob
+NARROW_SHARE = 32
+
+
+def _narrow_bucket(live: int, capacity: int) -> int:
+    """The row bucket a selecting join gathers a side of `live` rows into,
+    or 0 where that bucket is over 1/`NARROW_SHARE` of `capacity`."""
+    bucket = bucket_for(live, DEFAULT_ROW_BUCKETS)
+    return bucket if bucket * NARROW_SHARE <= capacity else 0
+
+
+def _span_cap(x, c) -> int:
+    """Output child capacity for a span column of `x` bytes or child
+    rows: char bucket for strings, row bucket for array/map child rows;
+    0 = not a span column."""
+    if isinstance(c.dtype, (t.StringType, t.BinaryType)):
+        return bucket_for(max(int(x), 1), DEFAULT_CHAR_BUCKETS)
+    if isinstance(c.dtype, (t.ArrayType, t.MapType)):
+        return bucket_for(max(int(x), 1), DEFAULT_ROW_BUCKETS)
+    return 0
+
+
+def _span_lens(xp, c):
+    return (c.offsets[1:] - c.offsets[:-1]).astype(xp.int64)
+
+
+def _kept_fetch(sizes, probe: Batch) -> tuple:
+    """A selecting join's host sync: wait for its count's kept rows and
+    pick the selection's buckets, (out_cap, probe span caps).  The span
+    caps are the kept bytes' buckets where the rows are gathered
+    (`_narrow_bucket`), else 0: a compaction keeps the probe's."""
+    from ..columnar.fetch import fetch_array
+    from ..obs.tracer import trace_span
+    with trace_span("join.size") as sp:
+        sizes = fetch_array(sizes)         # one round trip
+        kept = int(sizes[0])
+        narrow = _narrow_bucket(kept, probe.capacity)
+        out_cap = narrow or min(bucket_for(kept, DEFAULT_ROW_BUCKETS),
+                                probe.capacity)
+        sp.set(total=kept, out_capacity=out_cap)
+    return out_cap, tuple(_span_cap(x, c) if narrow else 0
+                          for x, c in zip(sizes[1:], probe.columns))
 
 
 def _sizing_fetch(sizes, probe: Batch, build: Batch) -> tuple:
@@ -80,22 +139,11 @@ def _sizing_fetch(sizes, probe: Batch, build: Batch) -> tuple:
     m.counter("tpu_join_sizing_fetches_total",
               "blocking fetches of a join's output sizes, one a probe "
               "batch").inc()
-
-    def span_cap(x, c):
-        """Output child capacity for a span column: char bucket for
-        strings, row bucket for array/map child rows; 0 = not a span
-        column."""
-        if isinstance(c.dtype, (t.StringType, t.BinaryType)):
-            return bucket_for(max(int(x), 1), DEFAULT_CHAR_BUCKETS)
-        if isinstance(c.dtype, (t.ArrayType, t.MapType)):
-            return bucket_for(max(int(x), 1), DEFAULT_ROW_BUCKETS)
-        return 0
-
     n_p = len(probe.columns)
     return (out_cap,
-            tuple(span_cap(x, c)
+            tuple(_span_cap(x, c)
                   for x, c in zip(sizes[1:1 + n_p], probe.columns)),
-            tuple(span_cap(x, c)
+            tuple(_span_cap(x, c)
                   for x, c in zip(sizes[1 + n_p:], build.columns)))
 
 
@@ -271,7 +319,8 @@ class HashJoinExec(Exec):
         unmatched build rows (`need_matched`), the build rows some probe
         row matched, else None.  `pkeep` / `bkeep` are a paired filter's
         flags over the probe / the build (`masked_sources`): a row they
-        drop is no row of its side, wherever it lies."""
+        drop is no row of its side, wherever it lies.  A selecting join's
+        sizes are its kept rows and their span bytes (`_kept_sizes`)."""
         bctx = EvalContext(xp, build)
         pctx = EvalContext(xp, probe)
         bkeys = [k.eval(bctx).col for k in self.right_keys]
@@ -283,6 +332,9 @@ class HashJoinExec(Exec):
         # row of a left join's output
         order, lo, counts = jk.count_matches(
             xp, bh, _live(xp, build, bkeep) & ~bnull, ph, plive & ~pnull)
+        if self._selects:
+            return (order, lo, counts,
+                    self._kept_sizes(xp, probe, counts, pkeep), None)
         outer = self.how in ("left", "full")
         eff = xp.maximum(counts, 1) if outer else counts
         eff = xp.where(plive, eff, 0)
@@ -291,19 +343,16 @@ class HashJoinExec(Exec):
         # output CHILD ROWS — a row-duplicating gather must size the
         # child buffer to the duplicated total, not the source capacity
         # (a source-cap default silently truncates join expansions)
-        def span_lens(c):
-            return (c.offsets[1:] - c.offsets[:-1]).astype(xp.int64)
-
         pbytes = []
         for c in probe.columns:
             if c.offsets is not None:
-                pbytes.append(xp.sum(eff * span_lens(c)))
+                pbytes.append(xp.sum(eff * _span_lens(xp, c)))
             else:
                 pbytes.append(xp.int64(0) if xp is not np else np.int64(0))
         bbytes = []
         for c in build.columns:
             if c.offsets is not None:
-                sl = span_lens(c)[order]
+                sl = _span_lens(xp, c)[order]
                 pre = xp.concatenate([xp.zeros((1,), xp.int64),
                                       cumsum_fast(xp, sl)])
                 per = pre[lo + counts.astype(xp.int32)] - pre[lo]
@@ -338,40 +387,78 @@ class HashJoinExec(Exec):
         stay, and nothing expands."""
         return self.how in ("left_semi", "left_anti")
 
-    def _count_call(self, xp, build, probe, pkeep, bkeep):
-        need = self._emits_unmatched_build
+    def _count_narrowed(self, xp, build: Batch, probe: Batch, pkeep,
+                        bkeep, narrow: int):
+        """`_count`; where `narrow` (a selecting join's), over the build's
+        live rows gathered into that bucket first, so that nothing sorts
+        the build's dead slots: the count then sees a build with no
+        flags."""
+        if narrow:
+            build = gather_kept(xp, build, _live(xp, build, bkeep), narrow,
+                                build.names)
+            bkeep = None
+        return self._count(xp, build, probe, self._emits_unmatched_build,
+                           pkeep, bkeep)
+
+    def _count_call(self, xp, build, probe, pkeep, bkeep, narrow=0):
         if xp is np:
-            return self._count(np, build, probe, need, pkeep, bkeep)
+            return self._count_narrowed(np, build, probe, pkeep, bkeep,
+                                        narrow)
         # the flags are arguments like the batches: the key says which
         # of them this program takes.  A selecting join's count has a
         # role of its own, so that a trace tells its device time from
-        # the expanding joins' (the program is the same)
+        # the expanding joins' (the program is the same), and a narrowed
+        # one the bucket it gathers into
         fn = process_jit(
             self._jit_key + ("probe_masked",) * (pkeep is not None)
             + ("build_masked",) * (bkeep is not None)
-            + ("semi_count" if self._selects else "count",),
-            lambda: lambda b, p, pk, bk: self._count(
-                jnp, b, p, need_matched=need, pkeep=pk, bkeep=bk))
+            + ("semi_count" if self._selects else "count",)
+            + ((narrow,) if narrow else ()),
+            lambda: lambda b, p, pk, bk: self._count_narrowed(
+                jnp, b, p, pk, bk, narrow))
         return fn(build, probe, pkeep, bkeep)
 
     # --- phase 2 of a semi or an anti join: selection -----------------------
-    def _select(self, xp, probe: Batch, counts, pkeep=None) -> Batch:
+    def _kept(self, xp, probe: Batch, counts, pkeep=None):
         """The probe's live rows that found a match (`left_semi`) or none
-        (`left_anti`), moved to the front in their order, at the probe's
-        capacity: a probe row is emitted once however many build rows
-        hold its key, and a null key matched nothing."""
+        (`left_anti`): a null key matched nothing."""
         hit = counts > 0
-        keep = (hit if self.how == "left_semi" else ~hit) \
+        return (hit if self.how == "left_semi" else ~hit) \
             & _live(xp, probe, pkeep)
-        return compact(xp, probe, keep, self.output_names)
 
-    def _select_call(self, xp, probe, counts, pkeep=None) -> Batch:
+    def _kept_sizes(self, xp, probe: Batch, counts, pkeep=None):
+        """ONE int64 vector: the kept rows, then each probe column's
+        kept span bytes or child rows (0 for a column with none)."""
+        keep = self._kept(xp, probe, counts, pkeep)
+        return xp.stack(
+            [xp.sum(keep.astype(xp.int64))]
+            + [xp.sum(xp.where(keep, _span_lens(xp, c), 0))
+               if c.offsets is not None else xp.int64(0)
+               for c in probe.columns])
+
+    def _select(self, xp, probe: Batch, counts, caps: tuple,
+                pkeep=None) -> Batch:
+        """The kept rows (`_kept`) in their order, each once however many
+        build rows hold its key, at the buckets `caps` = (out_cap, probe
+        span caps) that `_kept_fetch` picked: gathered there where
+        `out_cap` is a narrowed bucket, else compacted at the probe's
+        capacity and sliced to `out_cap` (the rows are at the front)."""
+        out_cap, char_caps = caps
+        keep = self._kept(xp, probe, counts, pkeep)
+        if _narrow_bucket(out_cap, probe.capacity):
+            return gather_kept(xp, probe, keep, out_cap, self.output_names,
+                               char_caps)
+        return shrink_batch(compact(xp, probe, keep, self.output_names),
+                            out_cap)
+
+    def _select_call(self, xp, probe, counts, caps: tuple,
+                     pkeep=None) -> Batch:
         if xp is np:
-            return self._select(np, probe, counts, pkeep)
+            return self._select(np, probe, counts, caps, pkeep)
         fn = process_jit(
             self._jit_key + ("probe_masked",) * (pkeep is not None)
-            + ("semi",),
-            lambda: lambda p, c, pk: self._select(jnp, p, c, pk))
+            + ("semi",) + caps,
+            lambda: lambda p, c, pk: self._select(jnp, p, c, caps, pk))
         return fn(probe, counts, pkeep)
 
     # --- phase 2: expansion -------------------------------------------------
@@ -486,11 +573,13 @@ class HashJoinExec(Exec):
         """Materialize the build side as ONE device batch: this
         partition's co-clustered shard when colocated, the whole right
         side otherwise.  Returns (the batch, a paired filter's keep flags
-        over it or None).  Where the build comes up masked
-        (`masked_sources`) in ONE batch, as every resident table does, it
-        stays where it lay and the flags go with it; several masked
-        batches are compacted each under its flags and concatenated like
-        any others (`concat_batches` packs live prefixes)."""
+        over it or None, its live rows: a host int or a device scalar).
+        Where the build comes up masked (`masked_sources`) in ONE batch,
+        as every resident table does, it stays where it lay and the flags
+        go with it, their count (the mask program's) beside them; several
+        masked batches are compacted each under its flags and
+        concatenated like any others (`concat_batches` packs live
+        prefixes)."""
         xp = self.xp
         right = self.children[1]
         source = self.masked_sources()[1]
@@ -505,7 +594,8 @@ class HashJoinExec(Exec):
                 else source.execute_masked(bpid, ctx, self))
         if source is not None:
             if len(build_batches) == 1:
-                return build_batches[0].batch, build_batches[0].keep
+                m, = build_batches
+                return m.batch, m.keep, m.num_rows
             build_batches = [self._compact_build(m.batch, m.keep)
                              for m in build_batches]
         if not build_batches:
@@ -515,9 +605,10 @@ class HashJoinExec(Exec):
                 {n: pa.array([], type=f.type)
                  for n, f in zip(schema.names, schema)})
             build_batches = [batch_to_device(rb, xp=xp)]
-        return (concat_batches(xp, build_batches, right.output_names,
-                               right.output_types)
-                if len(build_batches) > 1 else build_batches[0]), None
+        build = (concat_batches(xp, build_batches, right.output_names,
+                                right.output_types)
+                 if len(build_batches) > 1 else build_batches[0])
+        return build, None, build.num_rows
 
     def _compact_build(self, batch: Batch, keep) -> Batch:
         """One of several masked build batches, its kept rows moved to
@@ -530,57 +621,70 @@ class HashJoinExec(Exec):
             lambda: lambda b, k: compact(jnp, b, k, names))(batch, keep)
 
     def _probe_batch(self, build: Batch, probe: Batch, pkeep=None,
-                     bkeep=None):
+                     bkeep=None, narrow=0):
         """One probe batch against the build: (the joined batch, the build
         rows it matched or None).  `pkeep` / `bkeep`: the keep flags of
-        the filters the plan paired with either side.  The span
-        `join.probe` covers the dispatch of its programs and, inside it,
-        `join.size` the wait for the sizes.  A semi or an anti join
-        sizes nothing: its second program (`_select`, role `semi`)
-        compacts the probe's kept rows at the probe's own capacity, and
-        how many it kept stays on the device (`out_capacity` is the
-        probe's; the span waits for no count).  `sorted_slots` is what
-        the count's one sort covers: both sides' capacities, whatever is
-        live in them."""
+        the filters the plan paired with either side; `narrow`: the
+        bucket a selecting join's count gathers the build's live rows
+        into, or 0.  The span `join.probe` covers the dispatch of its
+        programs and, inside it, `join.size` the wait for the sizes: an
+        expanding join's output sizes (`_sizing_fetch`), a selecting
+        join's kept rows (`_kept_fetch`, path `select`: its second
+        program, role `semi`, hands up the kept rows in their bucket,
+        `out_capacity`).  `sorted_slots` is what the count's one sort
+        covers: the probe's capacity and the build's, narrowed where it
+        was, whatever is live in them."""
         from ..obs import metrics as m
         from ..obs.tracer import trace_span
         xp = self.xp
-        sorted_slots = int(probe.capacity) + int(build.capacity)
+        sorted_slots = int(probe.capacity) + int(narrow or build.capacity)
         with trace_span("join.probe", how=self.how,
                         probe_capacity=int(probe.capacity),
                         build_capacity=int(build.capacity),
                         sorted_slots=sorted_slots,
                         probe_masked=pkeep is not None) as sp:
             order, lo, counts, sizes, matched = self._count_call(
-                xp, build, probe, pkeep, bkeep)
+                xp, build, probe, pkeep, bkeep, narrow)
             if self._selects:
-                out, path = self._select_call(xp, probe, counts, pkeep), \
-                    "count"
-                sp.set(out_capacity=int(probe.capacity))
+                caps = _kept_fetch(sizes, probe)
+                out, path = self._select_call(xp, probe, counts, caps,
+                                              pkeep), "select"
+                narrowed = ("build",) * bool(narrow) + ("output",) * bool(
+                    _narrow_bucket(caps[0], probe.capacity))
             else:
                 caps = _sizing_fetch(sizes, probe, build)
                 out, path = self._expand_call(xp, build, probe, order, lo,
                                               counts, caps, pkeep), \
                     "two_phase"
-                sp.set(out_capacity=caps[0])
-            sp.set(path=path)
+                narrowed = ()
+            sp.set(out_capacity=caps[0], path=path)
         m.counter("tpu_join_probe_batches_total",
                   "probe batches joined, by how the output was sized: "
                   "two_phase (a blocking fetch of the count's sizes, then "
-                  "the expansion at their buckets), count (semi and anti "
-                  "joins size nothing)",
+                  "the expansion at their buckets), select (semi and anti "
+                  "joins: a blocking fetch of the kept rows, then the "
+                  "selection at their bucket)",
                   ("path",)).labels(path=path).inc()
         m.counter("tpu_join_sorted_slots_total",
                   "slots the joins' count programs sorted: at each probe "
-                  "batch the probe's capacity and the build's, live or "
-                  "not").inc(sorted_slots)
+                  "batch the probe's capacity and the build's, the "
+                  "narrowed one where a semi or an anti join gathered "
+                  "its live rows").inc(sorted_slots)
+        for side in narrowed:
+            m.counter("tpu_join_narrowed_total",
+                      "sides a semi or an anti join gathered into the "
+                      "smaller bucket of their live rows: the build "
+                      "before its count, the output after it",
+                      ("side",)).labels(side=side).inc()
         return out, matched
 
     def execute_partition(self, pid, ctx) -> Iterator[Batch]:
+        from ..columnar.fetch import fetch_array
         from ..obs.tracer import trace_span
         xp = self.xp
+        narrow = 0
         with trace_span("join.build") as sp:
-            build, bkeep = self._collect_build(pid, ctx)
+            build, bkeep, live = self._collect_build(pid, ctx)
             # (a build that a filter or a join made keeps its row count
             # on the device: the span does not wait for it; under a mask
             # the batch's own count is the unfiltered one, and left out)
@@ -588,6 +692,13 @@ class HashJoinExec(Exec):
                                             (int, np.integer)):
                 sp.set(rows=int(build.num_rows))
             sp.set(capacity=int(build.capacity), masked=bkeep is not None)
+            if self._selects:
+                # a selecting join sizes its build by what is live in it:
+                # the host's count, or one counted fetch of the device's
+                live = int(fetch_array(live))
+                narrow = _narrow_bucket(live, build.capacity)
+                sp.set(live_rows=live,
+                       narrowed_capacity=narrow or int(build.capacity))
         matched_acc = None
         # the probe's batches: (batch,), or (batch, keep flags) from the
         # filter the plan paired with this side, which then compacts
@@ -601,7 +712,8 @@ class HashJoinExec(Exec):
                       self.children[0].execute_partition(pid, ctx))
         for probe in probes:
             with MetricTimer(self.metrics[OP_TIME]):
-                out, matched = self._probe_batch(build, *probe, bkeep=bkeep)
+                out, matched = self._probe_batch(build, *probe, bkeep=bkeep,
+                                                 narrow=narrow)
                 if matched is not None:
                     matched_acc = matched if matched_acc is None else \
                         (matched_acc | matched)
@@ -659,7 +771,7 @@ class ShuffledHashJoinExec(HashJoinExec):
             hold=2.0 * build + 2.0 * probe + build,
             note="co-partitioned spill-backed build shard")
 
-    def _collect_build(self, pid, ctx) -> Batch:
+    def _collect_build(self, pid, ctx) -> tuple:
         """Materialize this partition's build shard under OOM-retry:
         running out of device memory synchronously spills lower-priority
         registrations (shuffle blocks first) and tries again, instead of

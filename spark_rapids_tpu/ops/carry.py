@@ -319,6 +319,22 @@ def compaction_rank(xp, keep, cap: int):
     return xp.where(keep, seen - 1, seen[-1] + pos - seen)
 
 
+def kept_slots(xp, keep, out_cap: int):
+    """(slot, valid, kept): the slots of the first `out_cap` kept rows in
+    their order (int32[out_cap]; 0 where `valid` is false) and how many
+    rows `keep` holds.  `compaction_rank`'s prefix sum, then a binary
+    search of each output slot's ordinal in it: log2(cap) rounds of
+    `out_cap`-sized gathers, no sort, no scatter.  For a side whose kept
+    rows are few against its capacity: moving every lane by a pass at
+    the capacity costs far more than looking the few up."""
+    from .scan import cumsum_fast
+    seen = cumsum_fast(xp, keep.astype(xp.int32))
+    want = xp.arange(1, out_cap + 1, dtype=xp.int32)
+    slot = xp.searchsorted(seen, want, side="left").astype(xp.int32)
+    valid = want <= seen[-1]
+    return xp.where(valid, slot, 0), valid, seen[-1]
+
+
 def _u32_pieces(xp, w) -> list:
     """(uint32 lane, bits) pieces of one integer key word, least
     significant first, which order as the word does: one for words of up

@@ -473,10 +473,13 @@ def test_join_spans_say_how_the_output_was_sized():
         assert size.parent_id == probe.span_id
     semi = spans(fact.join(dim, on=(col("k") == col("k2")),
                            how="left_semi"))
-    assert not semi["join.size"]
-    assert semi["join.probe"][0].attrs["path"] == "count"
-    # a semi join sizes nothing: its output lies at the probe's capacity
-    # (PR 37 says so; the kept rows' count stays on the device)
+    # a semi join waits for its kept rows (PR 38): 2,748 of 3,000 keep
+    # the 8,192 bucket, the probe's own, so the rows are compacted there;
+    # the build's 64 rows fill their 1,024 slots
+    size, = semi["join.size"]
+    assert size.attrs == {**size.attrs, "total": 2748,
+                          "out_capacity": 8192}
+    assert semi["join.probe"][0].attrs["path"] == "select"
     assert semi["join.probe"][0].attrs["out_capacity"] == 8192
     assert semi["join.probe"][0].attrs["sorted_slots"] == 8192 + 1024
 

@@ -125,12 +125,17 @@ def test_the_arm_keeps_what_isin_keeps_once_and_in_order(how, mask):
 @pytest.mark.parametrize("mask", ["none", "both"])
 @pytest.mark.parametrize("how", HOWS)
 def test_the_jitted_selection_is_the_eager_one_to_the_bit(how, mask):
-    """What `_probe_batch` did before PR 37, the compaction as a string
+    """What `_probe_batch` did before PR 37, the selection as a string
     of eager operations, against the program `jit_HashJoinExec.semi`:
-    every lane, flag and count of the output batch, padding included."""
+    every lane, flag and count of the output batch, padding included.
+    Since PR 38 both hand up the kept rows' bucket, and where it is over
+    1/`NARROW_SHARE` of the probe's capacity (here: 1,024 of 1,024) they
+    are compacted at the probe's capacity and sliced to it."""
+    from spark_rapids_tpu.columnar.device import bucket_for
+    from spark_rapids_tpu.exec.join import _kept_fetch, _narrow_bucket
     plan = _plan(_tables(seed=38), how, mask)
     ctx = ExecContext()
-    build, bkeep = plan._collect_build(0, ctx)
+    build, bkeep, _ = plan._collect_build(0, ctx)
     source = plan.masked_sources()[0]
     if source is not None:
         (m,) = list(source.execute_masked(0, ctx, plan))
@@ -138,10 +143,16 @@ def test_the_jitted_selection_is_the_eager_one_to_the_bit(how, mask):
     else:
         (probe,) = list(plan.children[0].execute_partition(0, ctx))
         pkeep = None
-    _, _, counts, _, _ = plan._count_call(jnp, build, probe, pkeep, bkeep)
-    eager = plan._select(jnp, probe, counts, pkeep)
-    jitted = plan._select_call(jnp, probe, counts, pkeep)
-    assert jitted.capacity == eager.capacity == probe.capacity
+    _, _, counts, sizes, _ = plan._count_call(jnp, build, probe, pkeep,
+                                              bkeep)
+    caps = _kept_fetch(sizes, probe)
+    kept = int(np.asarray(sizes)[0])
+    assert caps[0] == min(bucket_for(kept, (1024,)), probe.capacity)
+    assert not _narrow_bucket(kept, probe.capacity)
+    eager = plan._select(jnp, probe, counts, caps, pkeep)
+    jitted = plan._select_call(jnp, probe, counts, caps, pkeep)
+    assert jitted.capacity == eager.capacity == caps[0]
+    assert int(jitted.num_rows) == kept
     assert jitted.names == eager.names
     a, b = jax.tree_util.tree_leaves(eager), jax.tree_util.tree_leaves(
         jitted)
@@ -203,13 +214,22 @@ def test_the_span_and_the_counter_say_what_the_count_sorted():
                    if f.name == "tpu_join_sorted_slots_total")
     before = sorted_slots()
     fact.join(dim, col("k") == col("k2"), "left_semi").collect()
-    attrs = next(sp for sp in session.last_query_trace().spans
-                 if sp.name == "join.probe").attrs
-    assert attrs["how"] == "left_semi" and attrs["path"] == "count"
+    spans = session.last_query_trace().spans
+    attrs = next(sp for sp in spans if sp.name == "join.probe").attrs
+    assert attrs["how"] == "left_semi" and attrs["path"] == "select"
+    # 90 build rows in 1,024 slots and 400 probe rows in 1,024: no side is
+    # sparse enough to narrow (PR 38), so the count sorts both capacities
+    build = next(sp for sp in spans if sp.name == "join.build").attrs
+    assert build["narrowed_capacity"] == build["capacity"] == \
+        attrs["build_capacity"]
     assert attrs["sorted_slots"] == \
         attrs["probe_capacity"] + attrs["build_capacity"]
-    # a semi join sizes nothing: its output lies at the probe's capacity
-    assert attrs["out_capacity"] == attrs["probe_capacity"]
+    # the semi join waits for its kept rows and hands up their bucket,
+    # here the probe's capacity
+    size = next(sp for sp in spans if sp.name == "join.size").attrs
+    assert attrs["out_capacity"] == size["out_capacity"] == \
+        attrs["probe_capacity"]
+    assert 0 < size["total"] < probe.num_rows
     assert sorted_slots() - before == attrs["sorted_slots"]
     fact.join(dim, col("k") == col("k2"), "inner").collect()
     attrs = next(sp for sp in session.last_query_trace().spans
