@@ -12,10 +12,14 @@ table; each program is lowered for batches of shapes at the capacities
 `tpch_q18_1chip`'s joins run at: the semi join probes with ORDERS
 (capacity / 4) against the subquery's HAVING output at LINEITEM's
 capacity, whose 23-58 live rows its count gathers into the 1,024-row
-bucket (PR 38), and its selection gathers the kept orders into that
-bucket; the join with CUSTOMER probes with that 1,024-row output against
-capacity / 32 slots; the last join probes with a 1,024-row batch against
-LINEITEM.  The two expansions are at the 1,024-row bucket.  With `lower` nothing is compiled: the build counters alone
+bucket and holds ORDERS' hashes against (the small-side count:
+`join_kernels.match_flags`), and its selection gathers the kept orders
+into that bucket; the join with CUSTOMER probes with that 1,024-row
+output against capacity / 32 slots; the last join probes with a
+1,024-row batch against LINEITEM.  Each inner join's first count flags
+the build rows its small probe matches, its second gathers them into the
+1,024-row bucket and sorts them with the probe, and its expansion is at
+the 1,024-row bucket.  With `lower` nothing is compiled: the build counters alone
 (seconds, not minutes).  Prints one JSON object a program, as
 `devtools/compile_q3_programs.py` does.  A compile is not a chip run: no
 time here is a device time.
@@ -104,19 +108,16 @@ def main() -> int:
         masked = [s is not None for s in join.masked_sources()]
         pkeep = lane(np.bool_, probe_cap) if masked[0] else None
         bkeep = lane(np.bool_, build_cap) if masked[1] else None
-        role = "semi_count" if join._selects else "count"
-        narrow = OUT_BUCKET if join._selects else 0
-        count = one(
-            f"HashJoinExec.{role} ({name})",
-            lambda b, p, pk, bk: join._count_narrowed(jnp, b, p, pk, bk,
-                                                      narrow),
-            build, probe, pkeep, bkeep)
-        print(json.dumps({**count, "probe_capacity": probe_cap,
-                          "build_capacity": build_cap,
-                          "narrowed_capacity": narrow or build_cap,
-                          "probe_masked": masked[0],
-                          "build_masked": masked[1]}), flush=True)
+        facts = {"probe_capacity": probe_cap, "build_capacity": build_cap,
+                 "probe_masked": masked[0], "build_masked": masked[1]}
         if join._selects:
+            count = one(
+                f"HashJoinExec.semi_count ({name})",
+                lambda b, p, pk, bk: join._count_narrowed(
+                    jnp, b, p, pk, bk, OUT_BUCKET, True),
+                build, probe, pkeep, bkeep)
+            print(json.dumps({**count, **facts,
+                              "narrowed_capacity": OUT_BUCKET}), flush=True)
             caps = (OUT_BUCKET, tuple(0 for _ in probe.columns))
             select = one(
                 f"HashJoinExec.semi ({name})",
@@ -125,16 +126,30 @@ def main() -> int:
             print(json.dumps({**select, "out_capacity": OUT_BUCKET}),
                   flush=True)
             continue
-        caps = (OUT_BUCKET,
-                tuple(16384 if c.offsets is not None else 0
-                      for c in probe.columns),
-                tuple(16384 if c.offsets is not None else 0
-                      for c in build.columns))
+        flag = one(
+            f"HashJoinExec.count, flags ({name})",
+            lambda b, p, pk, bk: join._flag_build(jnp, b, p, pk, bk),
+            build, probe, pkeep, bkeep)
+        print(json.dumps({**flag, **facts}), flush=True)
+
+        def spans(b):
+            return tuple(16384 if c.offsets is not None else 0
+                         for c in b.columns)
+        gathered_caps = (OUT_BUCKET, spans(build))
+        gathered = one(
+            f"HashJoinExec.count, gathered ({name})",
+            lambda b, p, f, pk: join._count_gathered(
+                jnp, b, p, f, gathered_caps, pk),
+            build, probe, lane(np.bool_, build_cap), pkeep)
+        print(json.dumps({**gathered, **facts,
+                          "gathered_capacity": OUT_BUCKET}), flush=True)
+        small = batch(build_node, OUT_BUCKET)
+        caps = (OUT_BUCKET, spans(probe), spans(small))
         expand = one(
             f"HashJoinExec.expand ({name})",
             lambda b, p, o, l, c, pk: join._expand_sized(
                 jnp, b, p, o, l, c, caps, pk),
-            build, probe, lane(np.int32, build_cap),
+            small, probe, lane(np.int32, OUT_BUCKET),
             lane(np.int32, probe_cap), lane(np.int64, probe_cap), pkeep)
         print(json.dumps({**expand, "out_capacity": OUT_BUCKET}),
               flush=True)

@@ -28,6 +28,19 @@ sizes and hands up a batch in the kept rows' bucket, gathered there under
 the same rule or compacted and sliced above it.  An IN subquery's output
 is a sparse build, and a semi join's output a subset of its probe.
 
+A join whose one side has at most `SMALL_SIDE_SLOTS` slots and whose other
+side at least `NARROW_SHARE` times as many does not sort the two together
+(`_compared_side`): its count holds the large side's key hashes against
+the small side's live ones (`join_kernels.match_flags`).  A semi or an
+anti join's small build, narrowed as above: the flags over the probe are
+its count, and nothing is sorted.  An inner or a left join's small probe:
+the flags pick the build rows it matches, the host waits for their count,
+and they are gathered into their bucket and sorted with the probe, so the
+output is the one the whole build gives, row for row; where that bucket
+is over 1/`NARROW_SHARE` of the build's capacity the whole build is
+sorted after all.  A small build under an expanding join, a small probe
+under a selecting one, and right and full joins keep the sort.
+
 Dead rows are kept apart by flags, never by position (`join_kernels`), so
 a `FilterExec` directly under either side, or under a bare selection
 there, compacts nothing: the join pulls its `execute_masked`
@@ -78,12 +91,43 @@ JOIN_TYPES = ("inner", "left", "right", "full", "left_semi", "left_anti",
 #: observes, not a knob
 NARROW_SHARE = 32
 
+#: a join whose one side has at most SMALL_SIDE_SLOTS slots and whose other
+#: side at least NARROW_SHARE times as many compares the large side's key
+#: hashes against the small side's live ones (`join_kernels.match_flags`)
+#: instead of sorting the two together (`_compared_side`).  On the v5e
+#: (`devtools/chip_small_side.py`, every small slot live) the compare costs
+#: 9.5 / 54.9 / 212.1 / 418.6 ms at 33,554,432 large slots against 128 /
+#: 1,024 / 4,096 / 8,192 small ones, where the sort costs 2,126-2,285;
+#: 3.4 / 13.5 / 51.9 / 102.7 against 335-367 at 8,388,608; 1.0 / 2.9 /
+#: 9.7 / 18.5 against 30.3-34.1 at 1,048,576.  The compare wins at every
+#: width measured; at the next row bucket, 65,536, its steps (6.5 ms each
+#: at 33,554,432) would add up to 3.3 s, over the sort's 2.1
+SMALL_SIDE_SLOTS = 8192
+
 
 def _narrow_bucket(live: int, capacity: int) -> int:
     """The row bucket a selecting join gathers a side of `live` rows into,
     or 0 where that bucket is over 1/`NARROW_SHARE` of `capacity`."""
     bucket = bucket_for(live, DEFAULT_ROW_BUCKETS)
     return bucket if bucket * NARROW_SHARE <= capacity else 0
+
+
+def _compared_side(how: str, probe_cap: int, build_cap: int) -> str:
+    """The side whose live hashes the count compares the other side's
+    against, or "" where it sorts both: a semi or an anti join's build
+    (`build_cap` narrowed where it was), an inner or a left join's
+    probe, where that side has at most `SMALL_SIDE_SLOTS` slots and the
+    other at least `NARROW_SHARE` times as many.  A small build under an
+    expanding join, a small probe under a selecting one, and right and
+    full joins, which emit the unmatched build rows, sort."""
+    if how in ("left_semi", "left_anti"):
+        side, small, large = "build", build_cap, probe_cap
+    elif how in ("inner", "left"):
+        side, small, large = "probe", probe_cap, build_cap
+    else:
+        return ""
+    small_enough = 0 < small <= SMALL_SIDE_SLOTS
+    return side if small_enough and large >= NARROW_SHARE * small else ""
 
 
 def _span_cap(x, c) -> int:
@@ -101,22 +145,34 @@ def _span_lens(xp, c):
     return (c.offsets[1:] - c.offsets[:-1]).astype(xp.int64)
 
 
-def _kept_fetch(sizes, probe: Batch) -> tuple:
-    """A selecting join's host sync: wait for its count's kept rows and
-    pick the selection's buckets, (out_cap, probe span caps).  The span
-    caps are the kept bytes' buckets where the rows are gathered
-    (`_narrow_bucket`), else 0: a compaction keeps the probe's."""
+def _kept_fetch(sizes, batch: Batch) -> tuple:
+    """Wait for the rows a count kept of `batch` and pick the buckets
+    they go to, (out_cap, span caps): a selecting join's kept probe rows
+    (`_kept_sizes`), or the build rows a small probe flagged
+    (`_flag_build`).  The span caps are the kept bytes' buckets where the
+    rows are gathered (`_narrow_bucket`), else 0: a compaction keeps the
+    batch's."""
     from ..columnar.fetch import fetch_array
     from ..obs.tracer import trace_span
     with trace_span("join.size") as sp:
         sizes = fetch_array(sizes)         # one round trip
         kept = int(sizes[0])
-        narrow = _narrow_bucket(kept, probe.capacity)
+        narrow = _narrow_bucket(kept, batch.capacity)
         out_cap = narrow or min(bucket_for(kept, DEFAULT_ROW_BUCKETS),
-                                probe.capacity)
+                                batch.capacity)
         sp.set(total=kept, out_capacity=out_cap)
     return out_cap, tuple(_span_cap(x, c) if narrow else 0
-                          for x, c in zip(sizes[1:], probe.columns))
+                          for x, c in zip(sizes[1:], batch.columns))
+
+
+def _kept_sizes(xp, batch: Batch, keep):
+    """ONE int64 vector: the rows `keep` holds, then each column's span
+    bytes or child rows of them (0 for a column with none)."""
+    return xp.stack(
+        [xp.sum(keep.astype(xp.int64))]
+        + [xp.sum(xp.where(keep, _span_lens(xp, c), 0))
+           if c.offsets is not None else xp.int64(0)
+           for c in batch.columns])
 
 
 def _sizing_fetch(sizes, probe: Batch, build: Batch) -> tuple:
@@ -310,6 +366,15 @@ class HashJoinExec(Exec):
                 masked_child(self, build, tuple(self.right_keys) + cond))
 
     # --- phase 1: count + sizing -------------------------------------------
+    def _key_hashes(self, xp, build: Batch, probe: Batch):
+        """(build hash, build null keys, probe hash, probe null keys):
+        each side's combined key hash and its rows with a null in a key
+        (`join_kernels.combined_key_hash`)."""
+        bkeys = [k.eval(EvalContext(xp, build)).col for k in self.right_keys]
+        pkeys = [k.eval(EvalContext(xp, probe)).col for k in self.left_keys]
+        return (jk.combined_key_hash(xp, bkeys, build.capacity)
+                + jk.combined_key_hash(xp, pkeys, probe.capacity))
+
     def _count(self, xp, build: Batch, probe: Batch,
                need_matched: bool = True, pkeep=None, bkeep=None):
         """(order, lo, counts, sizes, matched): each probe row's run of
@@ -321,20 +386,15 @@ class HashJoinExec(Exec):
         flags over the probe / the build (`masked_sources`): a row they
         drop is no row of its side, wherever it lies.  A selecting join's
         sizes are its kept rows and their span bytes (`_kept_sizes`)."""
-        bctx = EvalContext(xp, build)
-        pctx = EvalContext(xp, probe)
-        bkeys = [k.eval(bctx).col for k in self.right_keys]
-        pkeys = [k.eval(pctx).col for k in self.left_keys]
         plive = _live(xp, probe, pkeep)
-        bh, bnull = jk.combined_key_hash(xp, bkeys, build.capacity)
-        ph, pnull = jk.combined_key_hash(xp, pkeys, probe.capacity)
+        bh, bnull, ph, pnull = self._key_hashes(xp, build, probe)
         # a null key matches nothing; a probe row that has one is still a
         # row of a left join's output
         order, lo, counts = jk.count_matches(
             xp, bh, _live(xp, build, bkeep) & ~bnull, ph, plive & ~pnull)
         if self._selects:
-            return (order, lo, counts,
-                    self._kept_sizes(xp, probe, counts, pkeep), None)
+            return (order, lo, counts, _kept_sizes(
+                xp, probe, self._kept(xp, probe, counts, pkeep)), None)
         outer = self.how in ("left", "full")
         eff = xp.maximum(counts, 1) if outer else counts
         eff = xp.where(plive, eff, 0)
@@ -388,22 +448,39 @@ class HashJoinExec(Exec):
         return self.how in ("left_semi", "left_anti")
 
     def _count_narrowed(self, xp, build: Batch, probe: Batch, pkeep,
-                        bkeep, narrow: int):
-        """`_count`; where `narrow` (a selecting join's), over the build's
-        live rows gathered into that bucket first, so that nothing sorts
-        the build's dead slots: the count then sees a build with no
-        flags."""
+                        bkeep, narrow: int, compare: bool = False):
+        """`_count`, or `_select_compared` where `compare`; where `narrow`
+        (a selecting join's), over the build's live rows gathered into
+        that bucket first, so that nothing sorts or compares the build's
+        dead slots: the count then sees a build with no flags."""
         if narrow:
             build = gather_kept(xp, build, _live(xp, build, bkeep), narrow,
                                 build.names)
             bkeep = None
+        if compare:
+            return self._select_compared(xp, build, probe, pkeep, bkeep)
         return self._count(xp, build, probe, self._emits_unmatched_build,
                            pkeep, bkeep)
 
-    def _count_call(self, xp, build, probe, pkeep, bkeep, narrow=0):
+    def _select_compared(self, xp, build: Batch, probe: Batch, pkeep,
+                         bkeep):
+        """A selecting join's count where its build is the small side
+        (`_compared_side`): no sort, each probe row's hash held against
+        the build's live ones (`join_kernels.match_flags`).  `_count`'s
+        five results; `counts` is 1 where a probe row's key is in the
+        build and not null, else 0 (`_kept` reads whether, not how
+        often), and nothing needs the order."""
+        bh, bnull, ph, pnull = self._key_hashes(xp, build, probe)
+        hit = jk.match_flags(xp, ph, bh, _live(xp, build, bkeep) & ~bnull)
+        counts = (hit & ~pnull).astype(xp.int64)
+        return (None, None, counts, _kept_sizes(
+            xp, probe, self._kept(xp, probe, counts, pkeep)), None)
+
+    def _count_call(self, xp, build, probe, pkeep, bkeep, narrow=0,
+                    compare=False):
         if xp is np:
             return self._count_narrowed(np, build, probe, pkeep, bkeep,
-                                        narrow)
+                                        narrow, compare)
         # the flags are arguments like the batches: the key says which
         # of them this program takes.  A selecting join's count has a
         # role of its own, so that a trace tells its device time from
@@ -412,11 +489,56 @@ class HashJoinExec(Exec):
         fn = process_jit(
             self._jit_key + ("probe_masked",) * (pkeep is not None)
             + ("build_masked",) * (bkeep is not None)
+            + ("compare",) * compare
             + ("semi_count" if self._selects else "count",)
             + ((narrow,) if narrow else ()),
             lambda: lambda b, p, pk, bk: self._count_narrowed(
-                jnp, b, p, pk, bk, narrow))
+                jnp, b, p, pk, bk, narrow, compare))
         return fn(build, probe, pkeep, bkeep)
+
+    # --- an expanding join's count under a small probe ----------------------
+    def _flag_build(self, xp, build: Batch, probe: Batch, pkeep=None,
+                    bkeep=None):
+        """The first of the two counts of an inner or a left join whose
+        probe is the small side (`_compared_side`): (flags, sizes), the
+        build rows that some live probe row's hash equals
+        (`join_kernels.match_flags`), in the batch and under `bkeep`, and
+        ONE int64 vector of their count and span bytes (`_kept_sizes`),
+        which `_kept_fetch` waits for.  Every build row a probe row
+        matches is among them."""
+        bh, bnull, ph, pnull = self._key_hashes(xp, build, probe)
+        flags = jk.match_flags(xp, bh, ph, _live(xp, probe, pkeep) & ~pnull) \
+            & _live(xp, build, bkeep) & ~bnull
+        return flags, _kept_sizes(xp, build, flags)
+
+    def _flag_call(self, build, probe, pkeep, bkeep):
+        fn = process_jit(
+            self._jit_key + ("probe_masked",) * (pkeep is not None)
+            + ("build_masked",) * (bkeep is not None) + ("compare", "count"),
+            lambda: lambda b, p, pk, bk: self._flag_build(jnp, b, p, pk, bk))
+        return fn(build, probe, pkeep, bkeep)
+
+    def _count_gathered(self, xp, build: Batch, probe: Batch, flags,
+                        caps: tuple, pkeep=None):
+        """The second count: the flagged build rows gathered, in their
+        order, into the buckets `caps` = (rows, span caps) that
+        `_kept_fetch` picked, then `_count` over the two small sides:
+        (the gathered build, order, lo, counts, sizes).  The stable sort
+        keeps the gathered rows' order, so the expansion is the one the
+        whole build gives, row for row."""
+        out_cap, char_caps = caps
+        small = gather_kept(xp, build, flags, out_cap, build.names,
+                            char_caps)
+        return (small,) + self._count(xp, small, probe, False, pkeep)[:4]
+
+    def _count_gathered_call(self, build, probe, flags, caps: tuple,
+                             pkeep=None):
+        fn = process_jit(
+            self._jit_key + ("probe_masked",) * (pkeep is not None)
+            + ("gathered",) + caps + ("count",),
+            lambda: lambda b, p, f, pk: self._count_gathered(
+                jnp, b, p, f, caps, pk))
+        return fn(build, probe, flags, pkeep)
 
     # --- phase 2 of a semi or an anti join: selection -----------------------
     def _kept(self, xp, probe: Batch, counts, pkeep=None):
@@ -425,16 +547,6 @@ class HashJoinExec(Exec):
         hit = counts > 0
         return (hit if self.how == "left_semi" else ~hit) \
             & _live(xp, probe, pkeep)
-
-    def _kept_sizes(self, xp, probe: Batch, counts, pkeep=None):
-        """ONE int64 vector: the kept rows, then each probe column's
-        kept span bytes or child rows (0 for a column with none)."""
-        keep = self._kept(xp, probe, counts, pkeep)
-        return xp.stack(
-            [xp.sum(keep.astype(xp.int64))]
-            + [xp.sum(xp.where(keep, _span_lens(xp, c), 0))
-               if c.offsets is not None else xp.int64(0)
-               for c in probe.columns])
 
     def _select(self, xp, probe: Batch, counts, caps: tuple,
                 pkeep=None) -> Batch:
@@ -621,61 +733,106 @@ class HashJoinExec(Exec):
             lambda: lambda b, k: compact(jnp, b, k, names))(batch, keep)
 
     def _probe_batch(self, build: Batch, probe: Batch, pkeep=None,
-                     bkeep=None, narrow=0):
+                     bkeep=None, narrow=0, live=None):
         """One probe batch against the build: (the joined batch, the build
         rows it matched or None).  `pkeep` / `bkeep`: the keep flags of
         the filters the plan paired with either side; `narrow`: the
         bucket a selecting join's count gathers the build's live rows
-        into, or 0.  The span `join.probe` covers the dispatch of its
-        programs and, inside it, `join.size` the wait for the sizes: an
-        expanding join's output sizes (`_sizing_fetch`), a selecting
-        join's kept rows (`_kept_fetch`, path `select`: its second
-        program, role `semi`, hands up the kept rows in their bucket,
-        `out_capacity`).  `sorted_slots` is what the count's one sort
-        covers: the probe's capacity and the build's, narrowed where it
-        was, whatever is live in them."""
+        into, or 0; `live`: a selecting join's live build rows.  The span
+        `join.probe` covers the dispatch of its programs and, inside it,
+        `join.size` each wait for sizes: an expanding join's output sizes
+        (`_sizing_fetch`), a selecting join's kept rows (`_kept_fetch`,
+        path `select`: its second program, role `semi`, hands up the kept
+        rows in their bucket, `out_capacity`).  Path `compare`: one side
+        is small (`_compared_side`) and the count holds the other side's
+        hashes against its live ones instead of sorting the two: a
+        selecting join's count then sorts nothing, and an expanding
+        join's first count flags the build rows the probe matches, waits
+        for their count (a `join.size` of its own) and sorts them with
+        the probe, unless their bucket is over 1/`NARROW_SHARE` of the
+        build's capacity, where it sorts the whole build as before
+        (`two_phase`).  `sorted_slots` is what the counts' sorts covered,
+        `compared_slots` the large side's capacity times the comparands'
+        slots: the live build rows' in steps of `COMPARE_CHUNK` where the
+        host knows them, else the small side's capacity."""
         from ..obs import metrics as m
         from ..obs.tracer import trace_span
         xp = self.xp
-        sorted_slots = int(probe.capacity) + int(narrow or build.capacity)
+        small_build = int(narrow or build.capacity)
+        side = "" if xp is np else _compared_side(
+            self.how, int(probe.capacity), small_build)
+        compared_slots, matched = 0, None
+        if side == "build":
+            step = jk.COMPARE_CHUNK
+            width = small_build if live is None else \
+                min(-(-max(live, 1) // step) * step, small_build)
+            compared_slots = int(probe.capacity) * width
+        elif side == "probe":
+            compared_slots = int(build.capacity) * int(probe.capacity)
         with trace_span("join.probe", how=self.how,
                         probe_capacity=int(probe.capacity),
                         build_capacity=int(build.capacity),
-                        sorted_slots=sorted_slots,
+                        compared_slots=compared_slots,
                         probe_masked=pkeep is not None) as sp:
-            order, lo, counts, sizes, matched = self._count_call(
-                xp, build, probe, pkeep, bkeep, narrow)
             if self._selects:
+                _, _, counts, sizes, _ = self._count_call(
+                    xp, build, probe, pkeep, bkeep, narrow, bool(side))
                 caps = _kept_fetch(sizes, probe)
-                out, path = self._select_call(xp, probe, counts, caps,
-                                              pkeep), "select"
+                out = self._select_call(xp, probe, counts, caps, pkeep)
+                path = "compare" if side else "select"
+                sorted_slots = 0 if side else \
+                    int(probe.capacity) + small_build
                 narrowed = ("build",) * bool(narrow) + ("output",) * bool(
                     _narrow_bucket(caps[0], probe.capacity))
             else:
+                if side:
+                    flags, sizes = self._flag_call(build, probe, pkeep,
+                                                   bkeep)
+                    caps = _kept_fetch(sizes, build)
+                    if not _narrow_bucket(caps[0], build.capacity):
+                        side = ""          # the probe's keys fan out
+                if side:
+                    build, order, lo, counts, sizes = \
+                        self._count_gathered_call(build, probe, flags, caps,
+                                                  pkeep)
+                else:
+                    order, lo, counts, sizes, matched = self._count_call(
+                        xp, build, probe, pkeep, bkeep)
                 caps = _sizing_fetch(sizes, probe, build)
-                out, path = self._expand_call(xp, build, probe, order, lo,
-                                              counts, caps, pkeep), \
-                    "two_phase"
+                out = self._expand_call(xp, build, probe, order, lo,
+                                        counts, caps, pkeep)
+                path = "compare" if side else "two_phase"
+                sorted_slots = int(probe.capacity) + int(build.capacity)
                 narrowed = ()
-            sp.set(out_capacity=caps[0], path=path)
+            sp.set(out_capacity=caps[0], path=path,
+                   sorted_slots=sorted_slots)
         m.counter("tpu_join_probe_batches_total",
                   "probe batches joined, by how the output was sized: "
                   "two_phase (a blocking fetch of the count's sizes, then "
                   "the expansion at their buckets), select (semi and anti "
                   "joins: a blocking fetch of the kept rows, then the "
-                  "selection at their bucket)",
+                  "selection at their bucket), compare (either, after a "
+                  "count that compared a large side with a small one)",
                   ("path",)).labels(path=path).inc()
         m.counter("tpu_join_sorted_slots_total",
                   "slots the joins' count programs sorted: at each probe "
                   "batch the probe's capacity and the build's, the "
                   "narrowed one where a semi or an anti join gathered "
-                  "its live rows").inc(sorted_slots)
-        for side in narrowed:
+                  "its live rows or a small probe's flagged build rows "
+                  "were gathered; 0 for a count that compared").inc(
+                      sorted_slots)
+        if path == "compare":
+            m.counter("tpu_join_compared_total",
+                      "probe batches whose count compared the large "
+                      "side's key hashes with the small side's live ones "
+                      "instead of sorting both, by the small side",
+                      ("side",)).labels(side=side).inc()
+        for s in narrowed:
             m.counter("tpu_join_narrowed_total",
                       "sides a semi or an anti join gathered into the "
                       "smaller bucket of their live rows: the build "
                       "before its count, the output after it",
-                      ("side",)).labels(side=side).inc()
+                      ("side",)).labels(side=s).inc()
         return out, matched
 
     def execute_partition(self, pid, ctx) -> Iterator[Batch]:
@@ -713,7 +870,7 @@ class HashJoinExec(Exec):
         for probe in probes:
             with MetricTimer(self.metrics[OP_TIME]):
                 out, matched = self._probe_batch(build, *probe, bkeep=bkeep,
-                                                 narrow=narrow)
+                                                 narrow=narrow, live=live)
                 if matched is not None:
                     matched_acc = matched if matched_acc is None else \
                         (matched_acc | matched)

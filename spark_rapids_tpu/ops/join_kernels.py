@@ -13,6 +13,15 @@ NumPy engine keeps it; it is the reference).  Pair expansion fills each
 probe row's span of the output from its start by a running maximum
 (`scan.fill_rows_from_starts`) — all static shapes.
 
+Where one side has at most `SMALL_SIDE_SLOTS` slots and the other at
+least `NARROW_SHARE` times as many (`exec/join._compared_side`, a rule on
+their capacities), the large side is not sorted at all: `match_flags`
+holds each of its hashes against the small side's live hashes,
+`COMPARE_CHUNK` of them a step, and flags the rows that equal one.  A
+selecting join's count is those flags over its probe; an expanding
+join's first count flags its build's rows, which it gathers before it
+sorts the two small sides as above.
+
 Two-phase protocol (one host sync, like cuDF sizing its gather maps):
   phase 1 (jitted `count_matches`): per-probe match ranges + totals;
   host picks a bucketed output capacity;
@@ -153,6 +162,44 @@ def count_matches(xp, build_hash, build_live, probe_hash, probe_live):
         cnt_row, mode="drop", unique_indices=True)
     counts = xp.where(probe_live, cnt, 0).astype(xp.int64)
     return order, lo, counts
+
+
+#: the comparands one step of `match_flags` holds against the large side:
+#: one row of the chip's 128 lanes
+COMPARE_CHUNK = 128
+
+
+def match_flags(xp, large_hash, small_hash, small_live):
+    """bool[large]: the large side's rows whose hash equals the hash of a
+    live row of the small side (`small_live`: in the batch, no null key).
+
+    The small side's live hashes are gathered to its front
+    (`carry.kept_slots`) and the large side's are held against them
+    `COMPARE_CHUNK` at a time, as two uint32 halves, in a loop of as many
+    steps as the live rows fill: the time follows the live rows, the
+    program only the capacities.  A step reduces its [COMPARE_CHUNK,
+    large] comparison as it makes it, so no array of that shape is
+    written.  Slots past the live rows repeat the first live hash, which
+    matches nothing the first does not: no hash value stands for "no
+    row"."""
+    from jax import lax
+    from .carry import kept_slots
+    steps = -(-small_hash.shape[0] // COMPARE_CHUNK)
+    slot, valid, live = kept_slots(xp, small_live, steps * COMPARE_CHUNK)
+    comp = small_hash[slot]
+    comp = xp.where(valid, comp, comp[0])
+    c_lo = comp.astype(xp.uint32).reshape(steps, COMPARE_CHUNK)
+    c_hi = (comp >> np.uint64(32)).astype(xp.uint32).reshape(
+        steps, COMPARE_CHUNK)
+    lo = large_hash.astype(xp.uint32)
+    hi = (large_hash >> np.uint64(32)).astype(xp.uint32)
+
+    def step(i, hit):
+        eq = (lo[None, :] == c_lo[i][:, None]) & \
+            (hi[None, :] == c_hi[i][:, None])
+        return hit | xp.any(eq, axis=0)
+    return lax.fori_loop(0, (live + COMPARE_CHUNK - 1) // COMPARE_CHUNK,
+                         step, xp.zeros(large_hash.shape, bool))
 
 
 def expand_pairs(xp, order, lo, counts, probe_live, out_cap: int,

@@ -135,9 +135,11 @@ def _checked(tables, how, live, **shape):
 @pytest.mark.parametrize("how", HOWS)
 def test_a_few_live_build_rows_are_gathered_into_their_bucket(how,
                                                              build_kind):
-    """40 live build rows in 65,536 slots: the count sorts 1,024 of them,
-    whether the flags came up with the batch or a compaction left the
-    count on the device (one counted fetch)."""
+    """40 live build rows in 65,536 slots: the count gathers them into
+    1,024, whether the flags came up with the batch or a compaction left
+    the count on the device (one counted fetch), and holds the probe's
+    65,536 hashes against them, a step of 128, sorting nothing (the
+    small-side rule: 65,536 >= 32 x 1,024)."""
     before = _narrowed()
     crossings = _counter("tpu_fetch_crossings_total")
     got, spans = _checked(_tables(40), how, 40, build_kind=build_kind)
@@ -146,8 +148,8 @@ def test_a_few_live_build_rows_are_gathered_into_their_bucket(how,
     assert build["narrowed_capacity"] == 1024
     assert build["masked"] is (build_kind == "masked")
     probe, = spans["join.probe"]
-    assert probe["sorted_slots"] == CAP + 1024
-    assert probe["build_capacity"] == CAP and probe["path"] == "select"
+    assert probe["sorted_slots"] == 0 and probe["compared_slots"] == CAP * 128
+    assert probe["build_capacity"] == CAP and probe["path"] == "compare"
     assert _narrowed()["build"] == before["build"] + 1
     # the build's live count and the kept count: two waits, and the
     # answer's own fetch
@@ -221,7 +223,8 @@ def test_no_kept_probe_row(how):
 def test_the_share_rule_picks_the_gather_or_the_capacity(how, live):
     """1,024 live build rows in 65,536 slots narrow (1,024 x 32 <= 65,536),
     1,025 do not (their bucket is 8,192): the count then sorts the
-    build's capacity under its flags, as before PR 38.  The kept rows
+    build's capacity under its flags, and the narrowed 1,024 slots are
+    compared with the probe's 65,536, not sorted.  The kept rows
     follow the same rule: a bucket of 1,024 is gathered, a larger one
     compacted at the probe's capacity and sliced to it."""
     assert NARROW_SHARE == 32
@@ -232,7 +235,9 @@ def test_the_share_rule_picks_the_gather_or_the_capacity(how, live):
     narrowed = live <= 1024
     assert build["narrowed_capacity"] == (1024 if narrowed else CAP)
     assert spans["join.probe"][0]["sorted_slots"] == \
-        CAP + build["narrowed_capacity"]
+        (0 if narrowed else CAP + build["narrowed_capacity"])
+    assert spans["join.probe"][0]["path"] == \
+        ("compare" if narrowed else "select")
     size, = spans["join.size"]
     out_cap = min(bucket_for(size["total"], DEFAULT_ROW_BUCKETS), CAP)
     assert size["out_capacity"] == out_cap == \
